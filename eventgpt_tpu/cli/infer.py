@@ -5,12 +5,15 @@ load-prep-generate-decode flow mirrors ``inference.py:28-66`` with the TPU
 pipeline underneath (jit CLIP encode, pjit-able LLaMA, HBM KV cache).
 
 Usage:
-  python -m eventgpt_tpu.cli.infer --model_path <hf_ckpt_dir|tiny-random> \\
+  python -m eventgpt_tpu.cli.infer \\
+      --model_path <hf_ckpt_dir|tiny-random|eventgpt-7b-random> \\
       --event_frame samples/sample1.npy --query "What is happening?"
 
 ``--model_path tiny-random`` runs the full pipeline with tiny random weights
 and the offline byte tokenizer (no checkpoint/network needed) — useful as a
-smoke test of the end-to-end path.
+smoke test of the end-to-end path. ``eventgpt-7b-random`` is the same at
+EventGPT-7B widths, from a seed (``models/synthetic.py``; use ``--quant
+int8`` on one 16 GB chip).
 """
 
 from __future__ import annotations
@@ -101,29 +104,52 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
-def load_model(model_path: str, dtype: str, attn_impl=None, tokenizer_path=None):
+def model_config_and_tokenizer(model_path: str, attn_impl=None,
+                               tokenizer_path=None):
+    """(config, tokenizer) of a ``--model_path`` without its weights: the
+    two checkpoint-free spellings (``tiny-random``, ``eventgpt-7b-random``
+    — offline byte tokenizer) or an HF checkpoint directory.
+    ``attn_impl=None`` on a checkpoint resolves per platform, which
+    initialises the backend (``config.default_attn_impl``)."""
+    from eventgpt_tpu.models.synthetic import SYNTHETIC_7B
+
+    if model_path == "tiny-random":
+        return EventChatConfig.tiny(), load_tokenizer("byte")
+    if model_path == SYNTHETIC_7B:
+        return EventChatConfig.eventgpt_7b(), load_tokenizer("byte")
+    with open(os.path.join(model_path, "config.json")) as f:
+        hf_cfg = json.load(f)
+    cfg = from_hf_config(hf_cfg, attn_impl=attn_impl)
+    return cfg, load_tokenizer(tokenizer_path or model_path)
+
+
+def load_model(model_path: str, dtype: str, attn_impl=None, tokenizer_path=None,
+               quant: str = "none", fuse: bool = False):
     """Returns (config, host-or-device params, tokenizer).
 
     HF-checkpoint params stay host-resident (numpy) so downstream transforms
     (embedding resize, int8 quantization) run before anything hits HBM —
     quantizing a 7B tree on-device would need bf16 + int8 + f32 temps
     simultaneously. ``place_params`` does the final device put.
+
+    ``quant`` / ``fuse`` matter to ``eventgpt-7b-random`` only: its seeded
+    host tree is built directly at the fused / quantized shapes
+    (``models/synthetic.py``), and ``prepare_model`` leaves it as it is.
     """
     import jax.numpy as jnp
 
-    jdt = jnp.bfloat16 if dtype == "bfloat16" else jnp.float32
-    if model_path == "tiny-random":
-        cfg = EventChatConfig.tiny()
-        params = eventchat.init_eventchat_params(cfg, jax.random.PRNGKey(0), jdt)
-        tokenizer = load_tokenizer("byte")
-        return cfg, params, tokenizer
+    from eventgpt_tpu.models import synthetic
 
-    with open(os.path.join(model_path, "config.json")) as f:
-        hf_cfg = json.load(f)
-    cfg = from_hf_config(hf_cfg, attn_impl=attn_impl)
-    sd = convert.load_state_dict(model_path)
-    params = convert.eventchat_params_from_hf(sd, cfg)
-    tokenizer = load_tokenizer(tokenizer_path or model_path)
+    jdt = jnp.bfloat16 if dtype == "bfloat16" else jnp.float32
+    cfg, tokenizer = model_config_and_tokenizer(
+        model_path, attn_impl, tokenizer_path)
+    if model_path == "tiny-random":
+        params = eventchat.init_eventchat_params(cfg, jax.random.PRNGKey(0), jdt)
+    elif model_path == synthetic.SYNTHETIC_7B:
+        params = synthetic.random_eventchat_params(cfg, jdt, quant, fuse)
+    else:
+        sd = convert.load_state_dict(model_path)
+        params = convert.eventchat_params_from_hf(sd, cfg)
     return cfg, params, tokenizer
 
 
@@ -221,13 +247,17 @@ def prepare_model(cfg, params, tokenizer, args, mesh=None):
         )
     if len(tokenizer) > cfg.llama.vocab_size:
         params["llama"] = resize_token_embeddings(params["llama"], len(tokenizer))
-    if getattr(args, "fuse_params", False):
+    # The full-width synthetic tree (models/synthetic.py) arrives fused /
+    # quantized already; the tree itself says so.
+    fused = "qkv" in params["llama"]["layers"]["attn"]
+    quantized = isinstance(params["llama"]["lm_head"], dict)
+    if getattr(args, "fuse_params", False) and not fused:
         from eventgpt_tpu.models.llama import fuse_llama_params
 
         # Fuse BEFORE quantization so scales are computed on (and stream
         # with) the fused tensors (models/llama.py:fuse_llama_params).
         params["llama"] = fuse_llama_params(params["llama"])
-    if args.quant in ("int8", "int4"):
+    if args.quant in ("int8", "int4") and not quantized:
         from eventgpt_tpu.ops.quant import quantize_llama_params
 
         params["llama"] = quantize_llama_params(
@@ -287,7 +317,8 @@ def main(argv=None) -> str:
 
     t0 = time.perf_counter()
     cfg, params, tokenizer = load_model(
-        args.model_path, args.dtype, args.attn_impl, args.tokenizer_path
+        args.model_path, args.dtype, args.attn_impl, args.tokenizer_path,
+        quant=args.quant, fuse=args.fuse_params,
     )
     # One mesh per run: params, activations, and the KV cache must all be
     # placed against the same Mesh object.

@@ -1194,8 +1194,12 @@ def make_handler(engine: ServingEngine, cfg, event_root=None,
                     # Flight recorder (ISSUE 10): {"debug": true} rides
                     # the request's own response with its full timeline
                     # + phase decomposition — no second round trip to
-                    # /request?rid=N needed while debugging a client.
+                    # /request?rid=N needed while debugging a client. The
+                    # raw ids ride along: a tokenizer that cannot render
+                    # an id (the byte tokenizer over a 32000-row head)
+                    # drops it from "answer".
                     obj["debug"] = engine.journey(rid)
+                    obj["token_ids"] = [int(t) for t in toks]
                 # Forced finishes map to structured HTTP errors (the
                 # partial answer rides along): deadline -> 504,
                 # cancel -> 499 (client asked), NaN quarantine -> 500,
@@ -1409,6 +1413,8 @@ def build_engine(args, force_single: bool = False):
     into spawning its own fleet)."""
     from eventgpt_tpu.utils.compile_cache import enable_compile_cache
 
+    # Initialises no backend: the --proc_fleet coordinator passes through
+    # here and must leave the chip to its workers.
     enable_compile_cache()
     # Telemetry arming (ISSUE 3): metrics are on unless --no_telemetry;
     # the span tracer keeps a bounded ring (0 disarms); --profile_dir
@@ -1457,7 +1463,7 @@ def build_engine(args, force_single: bool = False):
         # failure domains, the whole point). It only needs the config
         # (pixel preprocessing in the handler) and a tokenizer (submit
         # + routing key).
-        from eventgpt_tpu.data.tokenizer import load_tokenizer
+        from eventgpt_tpu.cli.infer import model_config_and_tokenizer
         from eventgpt_tpu.fleet_proc import ProcFleet
 
         if (getattr(args, "proc_fleet_roles", None)
@@ -1468,22 +1474,11 @@ def build_engine(args, force_single: bool = False):
             raise ValueError(
                 "--proc_fleet_roles requires --kv_layout paged (the "
                 "prefill->decode handoff ships paged-KV block runs)")
-        if args.model_path == "tiny-random":
-            from eventgpt_tpu.config import EventChatConfig
-
-            cfg = EventChatConfig.tiny()
-            tokenizer = load_tokenizer("byte")
-        else:
-            import json as _json
-            import os as _os
-
-            from eventgpt_tpu.models.convert import from_hf_config
-
-            with open(_os.path.join(args.model_path,
-                                    "config.json")) as f:
-                cfg = from_hf_config(_json.load(f))
-            tokenizer = load_tokenizer(
-                getattr(args, "tokenizer_path", None) or args.model_path)
+        # An explicit attn_impl: the coordinator reads the event-pipeline
+        # envelope only, and resolving the platform default would
+        # initialise a backend — the chip belongs to the workers.
+        cfg, tokenizer = model_config_and_tokenizer(
+            args.model_path, "dense", getattr(args, "tokenizer_path", None))
         engine = ProcFleet(
             _worker_argv(args), n_proc,
             tokenizer=tokenizer, conv_mode=args.conv_mode,
@@ -1514,9 +1509,14 @@ def build_engine(args, force_single: bool = False):
     from eventgpt_tpu.cli.infer import load_model, prepare_model
     from eventgpt_tpu.parallel.serving import build_serving_mesh
     from eventgpt_tpu.serve import ContinuousBatcher
+    from eventgpt_tpu.utils.platform import backend_platform
 
+    # This process serves (a single engine, or one --worker): it takes
+    # the device here, and refuses a silent fall back to the CPU.
+    backend_platform()
     cfg, params, tokenizer = load_model(
-        args.model_path, args.dtype, None, args.tokenizer_path
+        args.model_path, args.dtype, None, args.tokenizer_path,
+        quant=args.quant, fuse=getattr(args, "fuse_params", False),
     )
     # prepare_model places the host tree straight onto the mesh — a
     # post-hoc reshard would first materialize the full unsharded tree in

@@ -284,14 +284,12 @@ def load_config(path: str) -> EventChatConfig:
 
 
 def default_attn_impl() -> str:
-    """Flash prefill on TPU; dense elsewhere (the Pallas kernel only runs in
-    slow interpret mode off-TPU)."""
-    try:
-        import jax
+    """Flash prefill on TPU; dense where the CPU was asked for (the Pallas
+    kernel only runs in slow interpret mode there). A backend that fails
+    to initialise raises — it must not select the reference attention."""
+    from eventgpt_tpu.utils.platform import backend_platform
 
-        return "flash" if jax.devices()[0].platform == "tpu" else "dense"
-    except Exception:
-        return "dense"
+    return "flash" if backend_platform() == "tpu" else "dense"
 
 
 def from_hf_config(hf: dict, attn_impl: Optional[str] = None) -> EventChatConfig:

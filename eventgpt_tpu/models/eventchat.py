@@ -265,8 +265,10 @@ def _get_sharded_prefill(cfg: EventChatConfig, flat_sh, treedef, logits_sh,
     )
 
 
-def _prefill_sharded(params, cfg: EventChatConfig, embeds, mask, cache, mesh,
-                     return_hidden=False):
+def _sharded_prefill_fn(cfg: EventChatConfig, embeds, cache, mesh,
+                        return_hidden=False):
+    """The pinned serving-mesh prefill jit for these argument layouts:
+    ``fn(params, embeds, mask, cache)``."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from eventgpt_tpu.parallel.serving import serving_batch_axes
@@ -283,8 +285,13 @@ def _prefill_sharded(params, cfg: EventChatConfig, embeds, mask, cache, mesh,
     )
     logits_sh = NamedSharding(mesh, P(bspec, vocab_ax))
     hidden_sh = NamedSharding(mesh, P(bspec, None)) if return_hidden else None
-    fn = _get_sharded_prefill(cfg, tuple(flat), treedef, logits_sh, mesh,
-                              hidden_sh)
+    return _get_sharded_prefill(cfg, tuple(flat), treedef, logits_sh, mesh,
+                                hidden_sh)
+
+
+def _prefill_sharded(params, cfg: EventChatConfig, embeds, mask, cache, mesh,
+                     return_hidden=False):
+    fn = _sharded_prefill_fn(cfg, embeds, cache, mesh, return_hidden)
     return fn(params, embeds, mask, cache)
 
 
@@ -947,24 +954,10 @@ def generate(
 
     serving = None
     if mesh is not None:
-        import dataclasses
-
         from eventgpt_tpu.parallel import serving as serving_mod
 
         serving = serving_mod
         serving._require_serving_mesh(mesh)
-        model_n = mesh.shape.get("model", 1)
-        if (cfg.llama.attn_impl == "flash"
-                and cfg.llama.num_heads % model_n != 0):
-            # Flash under a serving mesh runs per-shard via shard_map
-            # (``serving_flash_shard_map`` — heads over model, batch over
-            # data/fsdp). That requires the head count to divide the model
-            # axis; otherwise dense scores (which GSPMD partitions freely)
-            # are the safe prefill fallback — one-shot, off the decode hot
-            # path.
-            cfg = dataclasses.replace(
-                cfg, llama=dataclasses.replace(cfg.llama, attn_impl="dense")
-            )
         pixel_values_batch = serving.shard_batch_array(
             pixel_values_batch, mesh, compute_dtype
         )

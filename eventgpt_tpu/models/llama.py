@@ -469,9 +469,12 @@ def prefill(
         # Serving mesh (context=1): flash runs per-shard under shard_map —
         # batch over (data, fsdp), heads over model (the bare Pallas call is
         # opaque to GSPMD and would all-gather every operand).
-        from eventgpt_tpu.parallel.serving import serving_flash_shard_map
+        from eventgpt_tpu.parallel.serving import (
+            require_flash_heads_divide, serving_flash_shard_map,
+        )
 
-        flash_fn = serving_flash_shard_map(mesh, b, num_heads=cfg.num_heads)
+        require_flash_heads_divide(cfg, mesh)
+        flash_fn = serving_flash_shard_map(mesh, b)
     use_flash = cfg.attn_impl == "flash" and flash_fn is None
     if use_flash or ring_fn is not None or flash_fn is not None:
         mask = None  # causal + padding masks applied inline

@@ -55,7 +55,7 @@ from typing import Any, Dict, Optional, Tuple
 from eventgpt_tpu.obs import metrics as obs_metrics
 from eventgpt_tpu.obs import trace as obs_trace
 
-# The component taxonomy (OBSERVABILITY.md "Memory ledger"). A CLOSED
+# The component catalogue (OBSERVABILITY.md "Memory ledger"). A CLOSED
 # set on purpose: component names become the egpt_mem_component_bytes
 # label values (METRIC_LABELS enum, lint rule 5 — bounded cardinality).
 COMPONENTS = ("weights", "kv_cache", "kv_pool", "kv_block_table", "logits",
@@ -111,7 +111,7 @@ class MemoryLedger:
         if component not in COMPONENTS:
             raise ValueError(
                 f"unknown memory component {component!r}: one of "
-                f"{COMPONENTS} (the taxonomy is a closed metric-label "
+                f"{COMPONENTS} (the catalogue is a closed metric-label "
                 f"enum — extend COMPONENTS + METRIC_LABELS together)")
         nbytes = int(nbytes)
         with self._lock:
@@ -448,15 +448,12 @@ def compiled_stats(jitted, *args, **kwargs) -> Dict[str, Any]:
 
 
 def device_capacity_bytes() -> int:
-    """Best-effort device memory limit (``memory_stats()`` of device 0;
-    TPU/GPU report ``bytes_limit``). 0 = unknown (CPU) — the headroom
-    guard is inert without an explicit ``--mem_capacity_mb``."""
-    try:
-        import jax
+    """Device memory limit (``memory_stats()`` of device 0; the TPU reports
+    ``bytes_limit``). 0 = the backend reports none (CPU) — the headroom
+    guard is inert there without an explicit ``--mem_capacity_mb``. A
+    failing ``memory_stats()`` raises: swallowing it would disarm the
+    guard on the chip."""
+    import jax
 
-        stats = jax.local_devices()[0].memory_stats()
-        if stats:
-            return int(stats.get("bytes_limit", 0) or 0)
-    except Exception:
-        pass
-    return 0
+    stats = jax.local_devices()[0].memory_stats() or {}
+    return int(stats.get("bytes_limit", 0))

@@ -81,7 +81,7 @@ METRIC_LABELS = {
         "kind": ("fail", "delay"),
     },
     "egpt_mem_component_bytes": {
-        # The memory ledger's component taxonomy (obs/memory.py
+        # The memory ledger's component catalogue (obs/memory.py
         # COMPONENTS — keep the two literals identical; the ledger
         # validates at register time, this enum at observe time).
         # kv_pool / kv_block_table are the paged-layout split of

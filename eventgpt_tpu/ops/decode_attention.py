@@ -25,8 +25,8 @@ Grid: (B, KV); each cell computes (G, hd) of output from one row's one KV
 head: dequantized (S, hd) K/V tiles live only in VMEM. S is padded to a
 lane multiple by the caller (cache lengths are bucket-aligned already).
 
-On non-TPU backends the kernel runs in interpreter mode (CPU-mesh tests),
-like ``ops/flash_attention.py``.
+Where the CPU was asked for the kernel runs in interpreter mode (CPU-mesh
+tests), like ``ops/flash_attention.py`` (``utils/platform.pallas_interpret``).
 """
 
 from __future__ import annotations
@@ -39,12 +39,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = float(jnp.finfo(jnp.float32).min)
+from eventgpt_tpu.utils.platform import pallas_interpret
 
-# jax 0.4.x ships the Mosaic compile options as TPUCompilerParams; newer
-# releases renamed it to CompilerParams. Same fields either way — the
-# shim lives in eventgpt_tpu/compat.py with the other version shims.
-from eventgpt_tpu.compat import pallas_compiler_params as _CompilerParams
+NEG_INF = float(jnp.finfo(jnp.float32).min)
 
 
 def _decode_attn_kernel(li_ref, nv_ref, q_ref, kq_ref, ks_ref, vq_ref,
@@ -107,7 +104,7 @@ def decode_attention_int8(
     b, kv, g, hd = q.shape
     _, _, s, _, _ = k_q.shape
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        interpret = pallas_interpret()
     scale = 1.0 / math.sqrt(hd)
     # KV-head group per grid cell: last-two block-dim tiling wants the KV
     # block divisible by 8 (or the full axis); 8 keeps VMEM per cell at
@@ -139,7 +136,7 @@ def decode_attention_int8(
         interpret=interpret,
         # Double-buffered int8 blocks + per-head cast temps exceed the 16 MB
         # default scoped-VMEM budget at S ~ 1200; v5e has 128 MB VMEM.
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024
         ),
     )(jnp.asarray(li, jnp.int32).reshape(1), jnp.asarray(n_valid, jnp.int32),
@@ -248,7 +245,7 @@ def decode_attention_int8_paged(
     _, _, bs, _, _ = k_q.shape
     n_bpr = block_tables.shape[1]
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        interpret = pallas_interpret()
     scale = 1.0 / math.sqrt(hd)
     block_kv = 8 if kv % 8 == 0 else kv
 
@@ -286,7 +283,7 @@ def decode_attention_int8_paged(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), q.dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024
         ),
     )(jnp.asarray(li, jnp.int32).reshape(1),

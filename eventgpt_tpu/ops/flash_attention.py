@@ -9,8 +9,9 @@ inline. Softmax statistics live in registers; the MXU sees one
 (BLOCK_Q, hd) x (hd, BLOCK_K) and one (BLOCK_Q, BLOCK_K) x (BLOCK_K, hd)
 matmul per step.
 
-On non-TPU backends the kernel runs in interpreter mode (tests on the CPU
-mesh); the dense path in ``models/llama.py`` remains the default until the
+Where the CPU was asked for the kernel runs in interpreter mode (tests on
+the CPU mesh; ``utils/platform.pallas_interpret`` decides, for every
+kernel in ``ops/``); the dense path in ``models/llama.py`` remains the default until the
 config opts in (``LlamaConfig.attn_impl = "flash"``).
 """
 
@@ -22,6 +23,8 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from eventgpt_tpu.utils.platform import pallas_interpret
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
@@ -180,7 +183,7 @@ def _flash_forward(
 ) -> jnp.ndarray:
     b, s, h, hd = q.shape
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        interpret = pallas_interpret()
 
     # Pad to a common multiple so both the q-grid and the kv loop tile S
     # exactly (max() alone under-covers when neither block divides the other).

@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from eventgpt_tpu.utils.platform import pallas_interpret
+
 BLOCK_N = 256
 BLOCK_KP = 128  # packed rows per step = 256 logical contraction rows
 
@@ -97,7 +99,7 @@ def int4_matmul(x: jnp.ndarray, q4: jnp.ndarray, s: jnp.ndarray,
     q4: (K/2, N) uint8, s: (Gc, N) f32 — the ``quantize_tensor4`` layout.
     """
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        interpret = pallas_interpret()
     b, k = x.shape
     hk, n = q4.shape
     gc = s.shape[0]

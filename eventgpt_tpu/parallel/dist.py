@@ -29,14 +29,25 @@ log = logging.getLogger("eventgpt_tpu.dist")
 
 _INITIALIZED = False
 
-# Presence of any of these means a cloud/pod launcher will feed
-# jax.distributed.initialize its coordination parameters. Exported so test
-# harnesses that simulate standalone hosts scrub exactly this set
-# (parallel/multiproc.py) — a private copy would drift.
+# These are how a cloud/pod launcher feeds jax.distributed.initialize its
+# coordination parameters. Exported so test harnesses that simulate
+# standalone hosts scrub exactly this set (parallel/multiproc.py) — a
+# private copy would drift.
 POD_AUTODETECT_VARS = (
     "TPU_WORKER_HOSTNAMES", "TPU_SKYLARK_HOSTS",
     "MEGASCALE_COORDINATOR_ADDRESS",
 )
+
+
+def _pod_launch() -> bool:
+    """True when the environment describes a multi-host launch. A
+    single-host TPU VM exports ``TPU_WORKER_HOSTNAMES=localhost`` too (the
+    v5e host of PR 21 does): one worker is not a pod, and sending that run
+    into ``jax.distributed.initialize()`` with no arguments ends in
+    "coordinator_address should be defined"."""
+    workers = os.environ.get("TPU_WORKER_HOSTNAMES", "")
+    return any(v in os.environ for v in POD_AUTODETECT_VARS
+               if v != "TPU_WORKER_HOSTNAMES") or "," in workers.strip(",")
 
 
 def initialize_distributed(
@@ -62,7 +73,7 @@ def initialize_distributed(
         process_id = int(os.environ["EGPT_PROCESS_ID"])
 
     explicit = coordinator_address is not None
-    autodetectable = any(v in os.environ for v in POD_AUTODETECT_VARS)
+    autodetectable = _pod_launch()
     if not explicit and not autodetectable:
         if num_processes is not None or process_id is not None:
             # Half-configured launch: running on silently would give N

@@ -75,14 +75,11 @@ def _ring_attention_local(
         return o_new, m_new, l_new, k_nxt, v_nxt, kvv_nxt
 
     # Fresh zeros are "unvarying" under shard_map's manual-axes typing while
-    # the loop outputs vary per device; pcast marks them explicitly
-    # (pvary's replacement — it was deprecated in jax 0.9; compat.pvary
-    # no-ops on 0.4.x, which has no varying-axes typing at all).
-    from eventgpt_tpu.compat import pvary
+    # the loop outputs vary per device; pcast marks them explicitly.
     from eventgpt_tpu.parallel.mesh import AXES
 
     def _vary(x):
-        return pvary(x, AXES)
+        return lax.pcast(x, AXES, to="varying")
 
     o0 = _vary(jnp.zeros((b, sq, h, hd), jnp.float32))
     m0 = _vary(jnp.full((b, h, sq), neg, jnp.float32))
@@ -122,13 +119,10 @@ def ring_attention_shard_map(mesh: Mesh, causal: bool = True,
     """Un-jitted shard_map over the ring body: ``f(q, k, v, q_valid,
     kv_valid) -> out``. This is the form model code calls *inside* its own
     jit (``models/llama.py`` when ``attn_impl == 'ring'``); shard_map
-    composes with the surrounding GSPMD partitioning. Goes through
-    ``compat.shard_map`` so 0.4.x builds (no ``jax.shard_map``) fall back
-    to the experimental home instead of failing at call time."""
-    from eventgpt_tpu.compat import shard_map
+    composes with the surrounding GSPMD partitioning."""
     from eventgpt_tpu.parallel.sp_common import SP_QKV_SPEC, SP_VALID_SPEC
 
-    return shard_map(
+    return jax.shard_map(
         functools.partial(_ring_attention_local, axis_name=axis_name, causal=causal),
         mesh=mesh,
         in_specs=(SP_QKV_SPEC, SP_QKV_SPEC, SP_QKV_SPEC,

@@ -283,7 +283,24 @@ def shard_kv_cache(cache: Any, cfg, mesh: Mesh) -> Any:
     }
 
 
-def serving_flash_shard_map(mesh: Mesh, batch: int, num_heads: Optional[int] = None):
+def require_flash_heads_divide(llama_cfg, mesh: Mesh) -> None:
+    """Flash under a serving mesh runs per-shard with heads over ``model``
+    (``serving_flash_shard_map``), so the head count must divide that
+    axis. The kernel does not give way to the dense reference quietly: a
+    configuration it cannot serve is refused — by ``llama.prefill`` for
+    every caller, and by the server already when it is built — with the
+    choice left to the caller."""
+    model_n = mesh.shape.get("model", 1)
+    if llama_cfg.attn_impl == "flash" and llama_cfg.num_heads % model_n:
+        raise ValueError(
+            f"attn_impl='flash' under a serving mesh shards heads over "
+            f"model: num_heads={llama_cfg.num_heads} must divide by "
+            f"model={model_n}; choose another mesh, or set "
+            f"attn_impl='dense' explicitly"
+        )
+
+
+def serving_flash_shard_map(mesh: Mesh, batch: int):
     """Pallas flash prefill under a serving mesh.
 
     The flash kernel is an opaque custom call to the SPMD partitioner, so a
@@ -294,7 +311,7 @@ def serving_flash_shard_map(mesh: Mesh, batch: int, num_heads: Optional[int] = N
     the boundary and sharded prefill keeps flash's O(S) memory instead of
     falling back to dense (B, H, T, T) scores. Sequence stays unsharded
     (serving meshes have context=1, ``_require_serving_mesh``); causality is
-    therefore purely local. Caller guarantees num_heads %% model == 0.
+    therefore purely local. Callers hold ``require_flash_heads_divide``.
 
     Returns ``f(q, k, v, valid) -> out`` with q/k/v (B, S, H, hd) post-GQA
     repeat and valid (B, S) bool.
@@ -303,16 +320,6 @@ def serving_flash_shard_map(mesh: Mesh, batch: int, num_heads: Optional[int] = N
 
     from eventgpt_tpu.ops.flash_attention import flash_attention
 
-    model_n = mesh.shape.get("model", 1)
-    if num_heads is not None and num_heads % model_n:
-        # Validate at the mechanism layer (every caller), not just at
-        # generate()'s downgrade site — otherwise the failure is an opaque
-        # shard_map divisibility trace.
-        raise ValueError(
-            f"flash under a serving mesh shards heads over model: "
-            f"num_heads={num_heads} must divide by model={model_n} "
-            f"(use dense attention otherwise)"
-        )
     baxes = serving_batch_axes(mesh, batch)
     bspec = baxes if baxes else None
     head_ax = "model" if mesh.shape.get("model", 1) > 1 else None
@@ -324,11 +331,8 @@ def serving_flash_shard_map(mesh: Mesh, batch: int, num_heads: Optional[int] = N
 
     # check_vma=False: the pallas_call's out ShapeDtypeStruct carries no
     # varying-mesh-axes annotation, and the kernel is purely local anyway
-    # (no collectives inside). compat.shard_map falls back to the 0.4.x
-    # experimental home (check_rep) on builds without jax.shard_map.
-    from eventgpt_tpu.compat import shard_map
-
-    return shard_map(
+    # (no collectives inside).
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, valid_spec),
         out_specs=qkv_spec,

@@ -63,9 +63,7 @@ def _ulysses_attention_local(
     f32 score matrix (the long-context regime is this mode's whole
     purpose). ``inner="dense"`` keeps the materialized form.
     """
-    from eventgpt_tpu.compat import axis_size
-
-    ctx = axis_size(axis_name)
+    ctx = lax.axis_size(axis_name)
     rep = q.shape[2] // k.shape[2]
     post_repeat = (
         rep > 1 and k.shape[2] % ctx == 0 and (q.shape[2] // ctx) % rep == 0
@@ -123,10 +121,9 @@ def ulysses_attention_shard_map(mesh: Mesh, causal: bool = True,
     K/V may be passed with their native (un-repeated) GQA head count —
     ``accepts_unrepeated_kv`` advertises this to the caller; the repeat
     happens after the all-to-all (ICI bytes scale with KV, not H)."""
-    from eventgpt_tpu.compat import shard_map
     from eventgpt_tpu.parallel.sp_common import SP_QKV_SPEC, SP_VALID_SPEC
 
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ulysses_attention_local, axis_name=axis_name,
                           causal=causal, inner=inner),
         mesh=mesh,

@@ -259,7 +259,12 @@ class Trainer:
         trainable = jax.tree_util.tree_map(
             lambda x: jnp.asarray(x, jnp.float32), trainable
         )
-        frozen = jax.tree_util.tree_map(lambda x: jnp.asarray(x, dtype), frozen)
+        # A host (numpy) leaf is cast on the host, so that shard_params
+        # below places each shard straight from it: jnp.asarray would first
+        # land the whole unsharded frozen tree — 14 GB at 7B — on device 0.
+        frozen = jax.tree_util.tree_map(
+            lambda x: (x.astype(dtype) if isinstance(x, np.ndarray)
+                       else jnp.asarray(x, dtype)), frozen)
         base_combine = self.combine
 
         def cast_combine(tr, fz, step=None, _base=base_combine, _dt=dtype):

@@ -381,6 +381,9 @@ def test_http_request_requests_trace_and_debug_block(tiny, tmp_path):
         assert dbg["rid"] == rid and dbg["finished"]
         assert sum(dbg["phases"].values()) == pytest.approx(
             dbg["e2e_s"], abs=1e-9)
+        # ... and the raw ids (the byte tokenizer drops what it cannot
+        # render from "answer").
+        assert len(out["token_ids"]) == out["tokens"]
         # /requests index lists it with its cause.
         idx = _get(url + "/requests")
         assert idx["enabled"] is True

@@ -82,7 +82,7 @@ def test_ledger_rejects_unknown_component():
         led.register("hbm_misc", "x", 1)
 
 
-def test_components_taxonomy_matches_metric_label_enum():
+def test_components_match_metric_label_enum():
     """The ledger validates at register time, the metric class at
     observe time — the two literals must stay identical or a legal
     component would raise at gauge export."""
