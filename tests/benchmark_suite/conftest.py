@@ -1,0 +1,9 @@
+"""Tests of the benchmark's own files (BENCHMARK.json ``paths``). They run on
+the CPU; nothing here loads the TPU's library."""
+
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
