@@ -1,0 +1,14 @@
+"""Median duration of the ``http.host_prep`` spans (the ``obs/trace`` ring)
+that began in the window: base64, raster, CLIP preprocess and tokenization of
+one request in its handler thread. Nothing where the program records no such
+span."""
+
+from benchmark.measure import percentile
+
+
+def read(run):
+    lo, hi = run.t0 * 1e6, run.t1 * 1e6
+    v = [e["dur"] / 1e3 for e in run.ring
+         if e.get("name") == "host_prep" and e.get("ph") == "X"
+         and lo <= e.get("ts", 0) < hi]
+    return percentile(v, 50) if v else None

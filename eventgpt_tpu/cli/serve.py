@@ -217,7 +217,8 @@ class ServingEngine:
         prefix-affinity key)."""
         if self.breaker_open() or self._dead:
             raise RuntimeError(f"serving engine is down: {self.fault}")
-        with self._lock:
+        with obs_trace.span("lock_wait", "engine") as wait, self._lock:
+            wait.close()  # the lock is held: what follows is its hold
             # Re-check under the lock: a breaker trip (or kill) while
             # the caller prepared the request has already swept _done —
             # an event registered after the sweep would burn its
@@ -231,6 +232,7 @@ class ServingEngine:
                 self._streams[rid] = queue.Queue()
                 self._sent[rid] = 0
             self.n_requests += 1
+        wait.set(rid=rid)
         self._wake.set()
         return rid
 
@@ -556,20 +558,7 @@ class ServingEngine:
                                  or any(r is not None
                                         for r in self.batcher.rows)))
                     if busy:
-                        self.batcher.step()
-                        self._push_stream_deltas_locked()
-                        self._harvest_locked()
-                        self._n_steps += 1
-                        if self._consec_faults:
-                            # A clean step closes the breaker: the fault
-                            # streak is over and /health returns to ok.
-                            self._consec_faults = 0
-                            self.fault = None
-                            obs_metrics.SERVE_BREAKER_OPEN.set(0)
-                        # Snapshot only when state moved (idle polls would
-                        # rebuild 10x/s for nothing); submits wake the
-                        # loop, so queue growth shows within one pass.
-                        self._snapshot = self._build_snapshot_locked()
+                        self._step_locked()
             except Exception as e:  # scheduler death must be LOUD
                 self._on_fault(e)
                 if not self._stop:
@@ -584,12 +573,39 @@ class ServingEngine:
                         target=self._loop, daemon=True)
                     self._thread.start()
                 return
+            self._maybe_beat()
             if not busy:
-                self._maybe_beat()
-                self._wake.wait(timeout=0.1)
+                with obs_trace.span("idle_wait", "engine"):
+                    self._wake.wait(timeout=0.1)
                 self._wake.clear()
-            else:
-                self._maybe_beat()
+
+    def _step_locked(self) -> None:
+        """One hold of the lock by the scheduler thread: the batcher's
+        step, the streams' new tokens, the finished answers, the
+        lock-free snapshot."""
+        b = self.batcher
+        with obs_trace.span(
+                "step", "engine", queued=len(b.queue),
+                live=sum(r is not None for r in b.rows)) as step:
+            b.step()
+            with obs_trace.span("stream_push", "engine") as push:
+                rids = self._push_stream_deltas_locked()
+                finished = self._harvest_locked()
+                push.set(finished=len(finished), rids=rids + finished)
+            self._n_steps += 1
+            if self._consec_faults:
+                # A clean step closes the breaker: the fault
+                # streak is over and /health returns to ok.
+                self._consec_faults = 0
+                self.fault = None
+                obs_metrics.SERVE_BREAKER_OPEN.set(0)
+            # Snapshot only when state moved (idle polls would
+            # rebuild 10x/s for nothing); submits wake the
+            # loop, so queue growth shows within one pass.
+            self._snapshot = self._build_snapshot_locked()
+            if obs_trace.enabled():
+                step.set(rids=[r.rid for r in b.rows if r is not None]
+                         + finished)
 
     def _maybe_beat(self) -> None:
         """Serving liveness beat (same file format + staleness predicate
@@ -702,7 +718,9 @@ class ServingEngine:
                 self._abandoned.discard(rid)
             self._snapshot = self._build_snapshot_locked()
 
-    def _push_stream_deltas_locked(self) -> None:
+    def _push_stream_deltas_locked(self) -> List[int]:
+        """Returns the rids whose streams got new tokens."""
+        pushed = []
         for req in self.batcher.rows:
             if req is None or req.rid not in self._streams:
                 continue
@@ -710,10 +728,13 @@ class ServingEngine:
             if n > self._sent[req.rid]:
                 self._streams[req.rid].put(list(req.tokens[:n]))
                 self._sent[req.rid] = n
+                pushed.append(req.rid)
+        return pushed
 
-    def _harvest_locked(self) -> None:
+    def _harvest_locked(self) -> List[int]:
+        """Returns the rids that finished."""
         if not self.batcher.finished:
-            return
+            return []
         done, self.batcher.finished = self.batcher.finished, {}
         for rid, toks in done.items():
             status = self.batcher.finish_status.pop(rid, "ok")
@@ -745,6 +766,7 @@ class ServingEngine:
             self._answers[rid] = toks
             if rid in self._done:
                 self._done[rid].set()
+        return list(done)
 
 
 def _decode_pixels(payload: Dict[str, Any], cfg, event_root=None):
@@ -887,9 +909,11 @@ def make_handler(engine: ServingEngine, cfg, event_root=None,
                 if "rid" in query:
                     # ?rid=N filters the ring to one request's spans
                     # (ISSUE 10 satellite): the async lifecycle events
-                    # carry the rid as their Chrome-trace id, and
-                    # rid-stamped args match too — the device-level
-                    # half of a flight-recorder timeline.
+                    # carry the rid as their Chrome-trace id, a span that
+                    # worked for one request carries args.rid, one that
+                    # worked for several (a wave, a dispatch, a step)
+                    # args.rids — the device-level half of a
+                    # flight-recorder timeline.
                     try:
                         rid = int(query["rid"][0])
                     except (ValueError, IndexError):
@@ -897,7 +921,8 @@ def make_handler(engine: ServingEngine, cfg, event_root=None,
                         return
                     evs = [e for e in evs
                            if e.get("id") == rid
-                           or (e.get("args") or {}).get("rid") == rid]
+                           or (e.get("args") or {}).get("rid") == rid
+                           or rid in (e.get("args") or {}).get("rids", ())]
                 self._json(200, {"traceEvents": evs,
                                  "droppedEvents": tracer.dropped()})
                 return
@@ -1061,11 +1086,14 @@ def make_handler(engine: ServingEngine, cfg, event_root=None,
                                  "entries": st.get("n_entries", 0),
                                  "bytes": st.get("bytes", 0)})
                 return
+            from eventgpt_tpu.data.conversation import prepare_event_prompt
+            from eventgpt_tpu.data.tokenizer import tokenize_with_event
             from eventgpt_tpu.fleet import FleetShedError, retry_after_s
             from eventgpt_tpu.serve import QueueFullError
 
             try:
-                payload = json.loads(self.rfile.read(n) or b"{}")
+                with obs_trace.span("http_read", "http", bytes=n) as read:
+                    payload = json.loads(self.rfile.read(n) or b"{}")
                 query = payload["query"]
                 budget = int(payload.get("max_new_tokens", default_budget))
                 deadline = payload.get("deadline_s", default_deadline_s)
@@ -1090,15 +1118,21 @@ def make_handler(engine: ServingEngine, cfg, event_root=None,
                         import dataclasses
 
                         slo = dataclasses.replace(slo, **overrides)
-                pixels = _decode_pixels(payload, cfg, event_root)
+                with obs_trace.span("host_prep", "http") as prep:
+                    pixels = _decode_pixels(payload, cfg, event_root)
+                    ids = tokenize_with_event(
+                        prepare_event_prompt(query, engine.conv_mode),
+                        engine.tokenizer)
             except Exception as e:  # bad request, not a server fault
                 self._json(400, {"error": str(e)})
                 return
             stream = bool(payload.get("stream", False))
             t0 = time.perf_counter()
             try:
-                rid = engine.submit(query, pixels, budget, stream=stream,
-                                    deadline_s=deadline, slo=slo)
+                rid = engine.submit_ids(ids, pixels, budget, stream=stream,
+                                        deadline_s=deadline, slo=slo)
+                read.set(rid=rid)
+                prep.set(rid=rid)
             except (QueueFullError, FleetShedError) as e:
                 # Backpressure, not failure: tell the client to come
                 # back (bounded admission queue — ISSUE 1; fleet shed —

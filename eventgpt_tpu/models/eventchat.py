@@ -61,14 +61,19 @@ def _encode_feats(params: Params, cfg: EventChatConfig, frames: jnp.ndarray,
     projector stack (``model/EventChatModel.py:185-191``). ``pin``:
     optional batch-sharding constraint threaded through the CLIP layer
     scan and applied after each projector stage (see ``clip_encode``)."""
-    feats = clip_mod.clip_encode(params["clip"], cfg.vision, frames, pin=pin)
-    feats = jax.lax.stop_gradient(feats)
-    feats = proj_mod.apply_projector(params["projector"], feats)
-    if pin is not None:
-        feats = pin(feats)
-    feats = proj_mod.apply_adaptor(params["projector"], feats)
-    if pin is not None:
-        feats = pin(feats)
+    # named_scope: metadata only (the scope path an operation carries on a
+    # device trace); no program's arithmetic or name changes with it.
+    with jax.named_scope("tower"):
+        feats = clip_mod.clip_encode(params["clip"], cfg.vision, frames,
+                                     pin=pin)
+        feats = jax.lax.stop_gradient(feats)
+    with jax.named_scope("projector"):
+        feats = proj_mod.apply_projector(params["projector"], feats)
+        if pin is not None:
+            feats = pin(feats)
+        feats = proj_mod.apply_adaptor(params["projector"], feats)
+        if pin is not None:
+            feats = pin(feats)
     return feats
 
 
@@ -151,13 +156,14 @@ def splice_embeddings(
         )
     embed_dtype = params["llama"]["embed_tokens"].dtype
     parts: List[jnp.ndarray] = []
-    for kind, val in _interleave_segments(segments):
-        if kind == "text":
-            ids = jnp.asarray(np.asarray(val, dtype=np.int32))
-            parts.append(llama_mod.embed_tokens(params["llama"], ids))
-        else:
-            parts.append(event_tokens[val].astype(embed_dtype))
-    out = jnp.concatenate(parts, axis=0)
+    with jax.named_scope("splice"):
+        for kind, val in _interleave_segments(segments):
+            if kind == "text":
+                ids = jnp.asarray(np.asarray(val, dtype=np.int32))
+                parts.append(llama_mod.embed_tokens(params["llama"], ids))
+            else:
+                parts.append(event_tokens[val].astype(embed_dtype))
+        out = jnp.concatenate(parts, axis=0)
     limit = cfg.llama.max_seq_len if max_context is None else min(cfg.llama.max_seq_len, max_context)
     if out.shape[0] > limit:
         # Text overflow truncates silently (reference parity, model/

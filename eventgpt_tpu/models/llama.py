@@ -209,7 +209,8 @@ def _attn_block(cfg: LlamaConfig, q_proj: jnp.ndarray, layer: Params,
                 valid: Optional[jnp.ndarray] = None,
                 use_flash: bool = False,
                 ring_fn=None,
-                flash_fn=None) -> jnp.ndarray:
+                flash_fn=None,
+                scope: str = "attn") -> jnp.ndarray:
     """Shared attention plumbing (RoPE on the precomputed q projection + GQA
     repeat + o proj) with a score-computation switch: dense additive ``mask``
     (B,1,Q,S), the Pallas flash kernel with a (B,S) ``valid`` padding mask
@@ -217,7 +218,9 @@ def _attn_block(cfg: LlamaConfig, q_proj: jnp.ndarray, layer: Params,
     parallelism over the ``context`` mesh axis, or a serving-mesh flash
     shard_map ``flash_fn`` (``parallel/serving.py:serving_flash_shard_map``).
     q_proj: (B,Q,H*hd) from ``_project_qkv`` (possibly a fused-qkv slice);
-    k/v_full: (B,S,KV,hd)."""
+    k/v_full: (B,S,KV,hd). ``scope`` names the GQA repeat, scores, softmax
+    and value product on a device trace (``prefill_attn`` /
+    ``decode_attn``; metadata only)."""
     b, q_len, _ = q_proj.shape
     h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim()
 
@@ -228,35 +231,44 @@ def _attn_block(cfg: LlamaConfig, q_proj: jnp.ndarray, layer: Params,
         # moves KV-count bytes, not H-count (ADVICE r2).
         ctx = ring_fn(q, k_full, v_full, valid, valid).reshape(b, q_len, h * hd)
         return _mm(ctx, layer["attn"]["o"])
-    k = _repeat_kv(k_full, h // kvh)
-    v = _repeat_kv(v_full, h // kvh)
+    with jax.named_scope(scope):
+        k = _repeat_kv(k_full, h // kvh)
+        v = _repeat_kv(v_full, h // kvh)
 
-    if ring_fn is not None:
-        ctx = ring_fn(q, k, v, valid, valid).reshape(b, q_len, h * hd)
-    elif flash_fn is not None:
-        ctx = flash_fn(q, k, v, valid).reshape(b, q_len, h * hd)
-    elif use_flash:
-        from eventgpt_tpu.ops.flash_attention import flash_attention
+        if ring_fn is not None:
+            ctx = ring_fn(q, k, v, valid, valid).reshape(b, q_len, h * hd)
+        elif flash_fn is not None:
+            ctx = flash_fn(q, k, v, valid).reshape(b, q_len, h * hd)
+        elif use_flash:
+            from eventgpt_tpu.ops.flash_attention import flash_attention
 
-        ctx = flash_attention(q, k, v, valid=valid, causal=True)
-        ctx = ctx.reshape(b, q_len, h * hd)
-    else:
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
-        scores = scores * (1.0 / math.sqrt(hd)) + mask
-        probs = jax.nn.softmax(scores, axis=-1).astype(q_proj.dtype)
-        ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v).reshape(b, q_len, h * hd)
+            ctx = flash_attention(q, k, v, valid=valid, causal=True)
+            ctx = ctx.reshape(b, q_len, h * hd)
+        else:
+            scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                                preferred_element_type=jnp.float32)
+            scores = scores * (1.0 / math.sqrt(hd)) + mask
+            probs = jax.nn.softmax(scores, axis=-1).astype(q_proj.dtype)
+            ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v).reshape(
+                b, q_len, h * hd)
     return _mm(ctx, layer["attn"]["o"])
 
 
 def _mlp_block(x: jnp.ndarray, layer: Params) -> jnp.ndarray:
     mlp = layer["mlp"]
-    if "gate_up" in mlp:
-        gu = _mm(x, mlp["gate_up"])
-        i = gu.shape[-1] // 2
-        gate, up = gu[..., :i], gu[..., i:]
-    else:
-        gate, up = _mm(x, mlp["gate"]), _mm(x, mlp["up"])
-    return _mm(jax.nn.silu(gate) * up, mlp["down"])
+    with jax.named_scope("mlp"):
+        if "gate_up" in mlp:
+            gu = _mm(x, mlp["gate_up"])
+            i = gu.shape[-1] // 2
+            gate, up = gu[..., :i], gu[..., i:]
+        else:
+            gate, up = _mm(x, mlp["gate"]), _mm(x, mlp["up"])
+        return _mm(jax.nn.silu(gate) * up, mlp["down"])
+
+
+def _lm_head(params: Params, x: jnp.ndarray) -> jnp.ndarray:
+    with jax.named_scope("lm_head"):
+        return _mm_f32(x, params["lm_head"])
 
 
 def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int, dtype=jnp.bfloat16,
@@ -494,7 +506,7 @@ def prefill(
         h_mid = h_in + _attn_block(cfg, q_proj, layer, cos, sin, k, v,
                                    mask=mask, valid=attention_mask,
                                    use_flash=use_flash, ring_fn=ring_fn,
-                                   flash_fn=flash_fn)
+                                   flash_fn=flash_fn, scope="prefill_attn")
         y2 = rms_norm(h_mid, layer["post_norm"], cfg.rms_norm_eps)
         h_out = h_mid + _mlp_block(y2, layer)
         return h_out, (k, v)
@@ -531,9 +543,9 @@ def prefill(
             x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1
         )[:, 0]  # (B, D)
         if return_hidden:
-            return _mm_f32(last, params["lm_head"]), last, new_cache
-        return _mm_f32(last, params["lm_head"]), new_cache
-    logits = _mm_f32(x, params["lm_head"])
+            return _lm_head(params, last), last, new_cache
+        return _lm_head(params, last), new_cache
+    logits = _lm_head(params, x)
     if return_hidden:
         return logits, x, new_cache
     return logits, new_cache
@@ -584,7 +596,7 @@ def decode_step(
                                                      quant, bt=bt),
                                    _cache_read_layer(v_buf, li, h_in.dtype,
                                                      quant, bt=bt),
-                                   mask)
+                                   mask, scope="decode_attn")
         y2 = rms_norm(h_mid, layer["post_norm"], cfg.rms_norm_eps)
         h_out = h_mid + _mlp_block(y2, layer)
         return (h_out, k_buf, v_buf), None
@@ -597,7 +609,7 @@ def decode_step(
     if bt is not None:
         new_cache["bt"] = bt
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    logits = _mm_f32(x[:, 0], params["lm_head"])
+    logits = _lm_head(params, x[:, 0])
     return logits, new_cache
 
 
@@ -653,7 +665,7 @@ def decode_kstep(
                                                      quant, bt=bt),
                                    _cache_read_layer(v_buf, li, h_in.dtype,
                                                      quant, bt=bt),
-                                   mask)
+                                   mask, scope="decode_attn")
         y2 = rms_norm(h_mid, layer["post_norm"], cfg.rms_norm_eps)
         h_out = h_mid + _mlp_block(y2, layer)
         return (h_out, k_buf, v_buf), None
@@ -666,7 +678,7 @@ def decode_kstep(
     if bt is not None:
         new_cache["bt"] = bt
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    logits = _mm_f32(x, params["lm_head"])  # (B, K, V)
+    logits = _lm_head(params, x)  # (B, K, V)
     if return_hidden:
         # Per-window-position final-norm hidden: the Medusa draft path
         # selects the correction position's hidden to seed the next

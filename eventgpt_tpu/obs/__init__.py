@@ -6,13 +6,15 @@ Three pillars, one package (OBSERVABILITY.md is the operator doc):
     log2-bucket histograms, exposed as Prometheus text (``GET /metrics``
     on the serving front end) and merged into ``/stats``; the trainer
     writes the same registry to a per-step ``telemetry.jsonl``.
-  * ``obs.trace``     — ring-buffered ``perf_counter`` span API recording
-    request lifecycles and scheduler dispatch/harvest overlap, exported
-    as Chrome trace events (``--trace_out``, ``GET /trace``) loadable in
-    Perfetto / chrome://tracing.
-  * ``obs.profiling`` — ``jax.profiler`` hooks: step/trace annotations
-    around train steps and decode segments plus an on-demand capture
-    window (``POST /profile``).
+  * ``obs.trace``     — the one span probe: a ring-buffered
+    ``perf_counter`` interval at every layer boundary (with its parent
+    and its request ids), exported as Chrome trace events
+    (``--trace_out``, ``GET /trace``) loadable in Perfetto /
+    chrome://tracing, and the same interval as a
+    ``jax.profiler.TraceAnnotation`` while the profiler is armed.
+  * ``obs.profiling`` — ``jax.profiler`` hooks: arming, step annotations
+    around train steps plus an on-demand capture window
+    (``POST /profile``).
 
 Design rules shared by all three (the ``faults.py`` discipline):
 stdlib-only at import (``metrics``/``trace`` never import jax, so they

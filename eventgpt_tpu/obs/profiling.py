@@ -1,19 +1,22 @@
-"""``jax.profiler`` hooks: annotations + an on-demand capture window.
+"""``jax.profiler`` hooks: arming, step annotations, an on-demand capture.
 
-``utils/profiling.py`` keeps the low-level pieces (``profile_trace``
-context manager, fenced ``timed``); this module is the ARMED-GATED layer
-the runtime wires through, so un-profiled serving/training pays one
-module-global check per step:
+The ARMED-GATED layer the runtime wires through, so un-profiled
+serving/training pays one module-global check per step:
 
-  * ``step_annotation(n)`` / ``annotation(name)`` — thin wrappers over
-    ``jax.profiler.StepTraceAnnotation`` / ``TraceAnnotation`` that
-    no-op unless profiling is armed. The trainer wraps each micro-step,
-    the serving scheduler wraps each decode/spec segment dispatch — so
-    a capture shows host steps aligned against device activity.
-  * ``capture(seconds, logdir)`` — the ``POST /profile {"seconds": N}``
+  * ``armed()``: a profile destination exists (``configure(dir)``, the
+    ``--profile_dir`` flags) or a ``capture`` window is open. While it
+    holds, every ``obs.trace.span`` also holds a
+    ``jax.profiler.TraceAnnotation`` named ``<cat>.<name>``, so a capture
+    shows the program's spans as host events on the clock of the
+    device's operations. That is the only way a host region is named on
+    a profile: there is no second probe.
+  * ``step_annotation(n)``: ``jax.profiler.StepTraceAnnotation`` when
+    armed, else a no-op; the trainer wraps each micro-step, which gives
+    XProf/TensorBoard its per-step grouping.
+  * ``capture(seconds, logdir)``: the ``POST /profile {"seconds": N}``
     window: start a ``jax.profiler`` trace, arm annotations for the
     window, sleep, stop. One capture at a time (``CaptureBusyError``).
-  * ``start_trace``/``stop_trace`` — manual bracket for the trainer's
+  * ``start_trace``/``stop_trace``: manual bracket for the trainer's
     ``--profile_dir`` step window.
 
 Arming is process-wide (``configure(dir)``) because the profiler itself
@@ -72,16 +75,6 @@ def step_annotation(step_num: int, name: str = "step"):
     import jax
 
     return jax.profiler.StepTraceAnnotation(name, step_num=step_num)
-
-
-def annotation(name: str):
-    """``jax.profiler.TraceAnnotation`` when armed, else a no-op — names
-    a host region (e.g. one decode-segment dispatch) on the trace."""
-    if not armed():
-        return _NULL
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)
 
 
 def start_trace(logdir: Optional[str] = None) -> str:

@@ -1,29 +1,43 @@
 """Ring-buffered request/step tracing in Chrome trace-event format.
 
-A span is one host-observed interval (``perf_counter`` at enter/exit);
-the tracer keeps the newest ``capacity`` events in a ring so a
-long-lived server holds a bounded, always-current window that
-``GET /trace`` snapshots on demand and ``--trace_out`` dumps at
-shutdown. Events follow the Chrome trace-event format, so a capture
-loads directly in Perfetto (ui.perfetto.dev) or chrome://tracing:
+``span(name, cat, **args)`` is the one probe the serving and training
+paths mark an interval with. It records one host-observed interval
+(``perf_counter`` at enter/exit) in a ring that keeps the newest
+``capacity`` events, so a long-lived server holds a bounded,
+always-current window that ``GET /trace`` snapshots on demand and
+``--trace_out`` dumps at shutdown. While the profiler is armed
+(``obs.profiling.armed()``: ``--profile_dir`` or a ``POST /profile``
+window) the same span also holds a ``jax.profiler.TraceAnnotation``
+named ``<cat>.<name>`` over the same interval, so every program span of
+a captured window is a host event of the ``.xplane.pb``, on the clock of
+the device's operations. Events follow the Chrome trace-event format, so
+a capture loads directly in Perfetto (ui.perfetto.dev) or
+chrome://tracing:
 
-  * ``X`` complete events — scheduler phases (dispatch, harvest,
-    admission, batch_to_device);
-  * ``b``/``e`` async events keyed by request id — each request's
+  * ``X`` complete events: the spans of ``SPANS`` below (one row a span:
+    name, category, layer, thread, what it brackets). ``args.parent`` is
+    the name of the span open on the same thread when this one began
+    (absent at top level); ``args.rid`` / ``args.rids`` name the request
+    or requests the interval worked for. ``Span.set(**args)`` completes
+    a span's args at any time (a handler's spans open before the request
+    has an id);
+  * ``b``/``e`` async events keyed by request id: each request's
     lifecycle (``queued`` -> ``active`` -> end with a ``status`` arg),
     which is how a single request's timeline reads across overlapping
     scheduler spans;
-  * ``i`` instants — point happenings (faults, breaker trips).
+  * ``i`` instants: point happenings (faults, breaker trips).
 
 Disarmed (the default) every probe is one module-global ``is None``
-check — the ``faults.py`` discipline; no timestamps are read and no
+check, the ``faults.py`` discipline; no timestamps are read and no
 objects allocated, so the hot path pays nothing. Armed, a span is two
-``perf_counter`` calls plus one dict append under a lock. Tracing reads
-clocks only — never jax values — so chains are byte-identical armed or
-disarmed (tests/test_obs.py::test_chain_neutrality).
+``perf_counter`` calls plus one dict append under a lock (and one
+``TraceAnnotation`` while the profiler is armed; none is made
+otherwise). Tracing reads clocks only, never jax values, so chains are
+byte-identical armed or disarmed
+(tests/test_obs.py::test_chain_neutrality).
 
 File format (``write()``): the Chrome JSON Array Format, one event per
-line — a ``[`` line, then ``{event},`` lines. The spec makes the
+line: a ``[`` line, then ``{event},`` lines. The spec makes the
 closing ``]`` optional precisely so producers can append and crash
 safely; Perfetto and chrome://tracing both load it. ``load_trace()``
 reads it back (round-trip tested).
@@ -35,9 +49,86 @@ import json
 import os
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, NamedTuple, Optional
+
+from eventgpt_tpu.obs import profiling as obs_profiling
 
 _US = 1e6
+
+
+class SpanDef(NamedTuple):
+    name: str
+    cat: str
+    layer: str     # PERF.md's list of layers
+    thread: str
+    brackets: str
+
+
+_FRONT = "HTTP front end and engine thread"
+_SCHED = "scheduler"
+_STEPS = "model steps"
+
+# Every interval the program marks, by the one probe below. The documents
+# (OBSERVABILITY.md "Tracing", PERF.md section 3) list these rows and
+# tests/test_spans.py holds both and every call site to them.
+SPANS = (
+    SpanDef("http_read", "http", _FRONT, "handler",
+            "do_POST /v1/generate: rfile.read + json.loads (bytes, rid)"),
+    SpanDef("host_prep", "http", _FRONT, "handler",
+            "_decode_pixels (base64, raster, CLIP preprocess) and "
+            "tokenization (rid)"),
+    SpanDef("lock_wait", "engine", _FRONT, "handler",
+            "submit_ids: from asking for ServingEngine._lock to holding "
+            "it (rid)"),
+    SpanDef("step", "engine", _FRONT, "engine",
+            "one hold of ServingEngine._lock by _loop: batcher.step(), "
+            "stream push, harvest, snapshot (queued, live at entry)"),
+    SpanDef("idle_wait", "engine", _FRONT, "engine",
+            "_wake.wait when there is nothing to do"),
+    SpanDef("stream_push", "engine", _FRONT, "engine",
+            "_push_stream_deltas_locked + _harvest_locked (finished, rids)"),
+    SpanDef("admit", "sched", _SCHED, "engine",
+            "ContinuousBatcher._admit, recorded when it did admission "
+            "work (rids, n, path)"),
+    SpanDef("dispatch", "sched", _SCHED, "engine",
+            "_dispatch_segment: enqueue one decode / speculation segment "
+            "(chunk, live, rows, lanes, rids)"),
+    SpanDef("segment_fetch", "sched", _SCHED, "engine",
+            "_harvest_segment: the blocked fetch of a segment's outputs "
+            "(wait_s, rids)"),
+    SpanDef("harvest", "sched", _SCHED, "engine",
+            "_harvest_segment: the host bookkeeping after the fetch "
+            "(tokens, rids)"),
+    SpanDef("prefix_lookup", "sched", _SCHED, "engine",
+            "_admit: the prefix-KV trie probe (hit, rid)"),
+    SpanDef("prefix_copy", "sched", _SCHED, "engine",
+            "suffix admission: entry copy + suffix prefill dispatch "
+            "(plen, suffix or wave; rid or rids)"),
+    SpanDef("upload", "admit", _STEPS, "engine",
+            "pixels host to device: jnp.asarray / jnp.stack / "
+            "shard_batch_array (bytes, n, rid or rids)"),
+    SpanDef("encode", "admit", _STEPS, "engine",
+            "encode_events_batch + splice + pad (n, rid or rids)"),
+    SpanDef("prefill", "admit", _STEPS, "engine",
+            "the _prefill_jit / _prefill_sharded / chunk / suffix call "
+            "(n, positions, rid or rids)"),
+    SpanDef("scatter", "admit", _STEPS, "engine",
+            "_scatter_wave / _finish_admission: the logits readback (NaN "
+            "quarantine), prefix insertion, scatter, activation (n, rid or "
+            "rids)"),
+    SpanDef("batch_to_device", "train", "trainer", "trainer",
+            "train/steps.py batch_to_device: host batch to device"),
+)
+
+
+def span_table_markdown() -> str:
+    """``SPANS`` as the table OBSERVABILITY.md "Tracing" holds
+    (tests/test_spans.py keeps the two equal)."""
+    rows = ["| Span (`cat.name`) | Thread | Layer | Brackets (args) |",
+            "| --- | --- | --- | --- |"]
+    rows += [f"| `{d.cat}.{d.name}` | {d.thread} | {d.layer} | {d.brackets} |"
+             for d in SPANS]
+    return "\n".join(rows)
 
 
 class _NullSpan:
@@ -49,28 +140,82 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **args) -> None:
+        pass
+
+    def drop(self) -> None:
+        pass
+
+    def close(self) -> None:
+        pass
+
 
 _NULL = _NullSpan()
+_open = threading.local()   # .stack: the spans open on this thread
 
 
-class _Span:
-    __slots__ = ("_tr", "_name", "_cat", "_args", "_t0")
+class Span:
+    """One armed interval: an ``X`` event in the ring when it closes and,
+    while the profiler is armed, a ``TraceAnnotation`` over the same
+    interval."""
 
-    def __init__(self, tr: "Tracer", name: str, cat: str, args):
+    __slots__ = ("_tr", "name", "cat", "args", "_t0", "_ann", "_keep",
+                 "_closed")
+
+    def __init__(self, tr: "Tracer", name: str, cat: str, args: dict):
         self._tr = tr
-        self._name = name
-        self._cat = cat
-        self._args = args
+        self.name = name
+        self.cat = cat
+        self.args = args
         self._t0 = 0.0
+        self._ann = None
+        self._keep = True
+        self._closed = False
+
+    def set(self, **args) -> None:
+        """Complete the span's args: before it closes, or after (the
+        ring's event shares them)."""
+        with self._tr._lock:
+            self.args.update(args)
+
+    def drop(self) -> None:
+        """Leave this interval out of the ring (a probe that found
+        nothing to do)."""
+        self._keep = False
 
     def __enter__(self):
+        stack = getattr(_open, "stack", None)
+        if stack is None:
+            stack = _open.stack = []
+        if stack:
+            self.args["parent"] = stack[-1].name
+        stack.append(self)
+        if obs_profiling.armed():
+            import jax
+
+            self._ann = jax.profiler.TraceAnnotation(
+                f"{self.cat}.{self.name}", **self.args)
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
-    def __exit__(self, *exc):
+    def close(self) -> None:
+        """End the interval now; leaving the ``with`` block later adds
+        nothing (``with span(...) as wait, lock: wait.close()`` times the
+        wait for the lock and not its hold)."""
+        if self._closed:
+            return
+        self._closed = True
         t1 = time.perf_counter()
-        self._tr.complete(self._name, self._t0, t1, cat=self._cat,
-                          args=self._args)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        _open.stack.remove(self)
+        if self._keep:
+            self._tr.complete(self.name, self._t0, t1, cat=self.cat,
+                              args=self.args)
+
+    def __exit__(self, *exc):
+        self.close()
         return False
 
 
@@ -99,8 +244,8 @@ class Tracer:
         ev = {"name": name, "ph": "X", "cat": cat,
               "ts": t0 * _US, "dur": max(t1 - t0, 0.0) * _US,
               "pid": self._pid, "tid": threading.get_ident()}
-        if args:
-            ev["args"] = args
+        if args is not None:
+            ev["args"] = args  # a Span's own dict: Span.set reaches it
         self._add(ev)
 
     def instant(self, name: str, cat: str = "serve",
@@ -140,7 +285,8 @@ class Tracer:
                 out = [e for e in self._buf[: self._head]]
             else:
                 out = self._buf[self._head:] + self._buf[: self._head]
-            return [dict(e) for e in out if e is not None]
+            return [{**e, "args": dict(e["args"])} if "args" in e
+                    else dict(e) for e in out if e is not None]
 
     def dropped(self) -> int:
         """Events the ring has overwritten (0 until it wraps)."""
@@ -211,7 +357,7 @@ def span(name: str, cat: str = "serve", **args):
     t = _tracer
     if t is None:
         return _NULL
-    return _Span(t, name, cat, args or None)
+    return Span(t, name, cat, args)
 
 
 def instant(name: str, cat: str = "serve", **args) -> None:

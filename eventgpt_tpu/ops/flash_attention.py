@@ -219,6 +219,9 @@ def _flash_forward(
         out_specs=pl.BlockSpec((None, block_q, hd), lambda bh, qb: (bh, qb, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, s_pad, hd), q.dtype),
         interpret=interpret,
+        # The kernel's name on a device trace (benchmark/programs.json
+        # finds it by "flash").
+        name="flash_forward",
     )(qh, kh, vh, valid_i)
 
     out = out.reshape(b, h, s_pad, hd).transpose(0, 2, 1, 3)[:, :s]

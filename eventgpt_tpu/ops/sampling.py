@@ -39,9 +39,10 @@ def sample(
     top_p: float = 1.0,
 ) -> jnp.ndarray:
     """(B, V) logits -> (B,) sampled ids. temperature <= 0 means greedy."""
-    if temperature <= 0.0:
-        return greedy(logits)
-    scaled = logits.astype(jnp.float32) / temperature
-    if top_p < 1.0:
-        scaled = top_p_filter(scaled, top_p)
-    return jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
+    with jax.named_scope("sample"):
+        if temperature <= 0.0:
+            return greedy(logits)
+        scaled = logits.astype(jnp.float32) / temperature
+        if top_p < 1.0:
+            scaled = top_p_filter(scaled, top_p)
+        return jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)

@@ -106,7 +106,6 @@ from eventgpt_tpu.config import EventChatConfig
 from eventgpt_tpu.obs import journey as obs_journey
 from eventgpt_tpu.obs import memory as obs_memory
 from eventgpt_tpu.obs import metrics as obs_metrics
-from eventgpt_tpu.obs import profiling as obs_profiling
 from eventgpt_tpu.obs import series as obs_series
 from eventgpt_tpu.obs import trace as obs_trace
 from eventgpt_tpu.constants import SEQ_BUCKET
@@ -2527,7 +2526,7 @@ class ContinuousBatcher:
         return suf_len, prompt_len, chunk, s1
 
     def _suffix_embed(self, entry: _PrefixEntry, pixel_values, suffix_ids,
-                      chunk: int, suf_len: int):
+                      chunk: int, suf_len: int, rid: Optional[int] = None):
         """(1, chunk, D) padded suffix embeddings for one admission: a
         through-event entry's suffix is plain text (no CLIP); a text
         entry's suffix carries the sentinel and pays its own encode."""
@@ -2539,13 +2538,12 @@ class ContinuousBatcher:
                 self.params["llama"], jnp.asarray([suffix_ids], jnp.int32)
             )
         else:
-            pv = jnp.asarray(pixel_values, self._dtype)[None]
-            if self.mesh is not None:
-                pv = self._serving.shard_batch_array(pv, self.mesh)
-            ev = eventchat.encode_events_batch(self.params, self.cfg, pv)
-            emb = splice_embeddings(
-                self.params, self.cfg, split_at_event(suffix_ids), ev[0]
-            )[None]
+            pv = self._upload_pixels([pixel_values], [rid])
+            with obs_trace.span("encode", "admit", n=1, rid=rid):
+                ev = eventchat.encode_events_batch(self.params, self.cfg, pv)
+                emb = splice_embeddings(
+                    self.params, self.cfg, split_at_event(suffix_ids), ev[0]
+                )[None]
         assert emb.shape[1] == suf_len, (emb.shape, suf_len)
         return jnp.pad(emb, ((0, 0), (0, chunk - suf_len), (0, 0)))
 
@@ -2562,18 +2560,18 @@ class ContinuousBatcher:
                 NamedSharding(self.mesh, P(bspec, None)))
 
     def _prefix_admit(self, entry: _PrefixEntry, pixel_values, suffix_ids,
-                      record: bool = True):
+                      record: bool = True, rid: Optional[int] = None):
         """Suffix-only admission against one cached prefix-KV entry.
         Returns (row_cache, row_logits, row_hidden, prompt_len), or None
         when ``_prefix_fit`` rejects (fall back to full prefill).
         ``record=False`` (warmup) skips the ``serve.prefix_copy`` fault
-        probe and the dispatch/trace telemetry."""
+        probe and the dispatch counter."""
         fit = self._prefix_fit(entry, suffix_ids)
         if fit is None:
             return None
         suf_len, prompt_len, chunk, s1 = fit
         emb = self._suffix_embed(entry, pixel_values, suffix_ids, chunk,
-                                 suf_len)
+                                 suf_len, rid)
         if record:
             # The copy boundary is its own fault site (ISSUE 4 satellite):
             # a fault HERE lands with a row reserved and an entry about to
@@ -2581,39 +2579,39 @@ class ContinuousBatcher:
             # entry's never-donated KV must survive.
             faults.maybe_fail("serve.prefix_copy")
             faults.maybe_delay("serve.prefix_copy")
-        t0 = time.perf_counter()
-        row_cache = self._new_row_cache(s1)
-        new_len = jnp.asarray([prompt_len], jnp.int32)
-        last_idx = jnp.asarray(suf_len - 1, jnp.int32)
-        plen_arr = jnp.asarray([entry.length], jnp.int32)
-        ekv = self._entry_kv(entry)
-        if self.mesh is not None:
-            emb = self._serving.shard_batch_array(emb, self.mesh)
-            row_sh = jax.tree_util.tree_map(lambda x: x.sharding, row_cache)
-            flat, treedef = jax.tree_util.tree_flatten(row_sh)
-            from jax.sharding import PartitionSpec as P
+        with obs_trace.span("prefix_copy", "sched", plen=entry.length,
+                            suffix=suf_len, rid=rid):
+            row_cache = self._new_row_cache(s1)
+            new_len = jnp.asarray([prompt_len], jnp.int32)
+            last_idx = jnp.asarray(suf_len - 1, jnp.int32)
+            plen_arr = jnp.asarray([entry.length], jnp.int32)
+            ekv = self._entry_kv(entry)
+            with obs_trace.span("prefill", "admit", n=1, positions=chunk,
+                                rid=rid):
+                if self.mesh is not None:
+                    emb = self._serving.shard_batch_array(emb, self.mesh)
+                    row_sh = jax.tree_util.tree_map(
+                        lambda x: x.sharding, row_cache)
+                    flat, treedef = jax.tree_util.tree_flatten(row_sh)
+                    from jax.sharding import PartitionSpec as P
 
-            hidden_sh = jax.sharding.NamedSharding(self.mesh, P(None, None))
-            fn = _get_sharded_prefix_prefill(
-                self.cfg, tuple(flat), treedef, self._row_logits_sh,
-                hidden_sh,
-            )
-            last, hidden, row_cache = fn(
-                self.params, ekv["k"], ekv["v"], plen_arr,
-                row_cache, emb, new_len, last_idx,
-            )
-        else:
-            last, hidden, row_cache = _prefix_prefill_jit(
-                self.params, self.cfg, ekv["k"], ekv["v"],
-                plen_arr, row_cache, emb, new_len, last_idx,
-            )
+                    hidden_sh = jax.sharding.NamedSharding(
+                        self.mesh, P(None, None))
+                    fn = _get_sharded_prefix_prefill(
+                        self.cfg, tuple(flat), treedef, self._row_logits_sh,
+                        hidden_sh,
+                    )
+                    last, hidden, row_cache = fn(
+                        self.params, ekv["k"], ekv["v"], plen_arr,
+                        row_cache, emb, new_len, last_idx,
+                    )
+                else:
+                    last, hidden, row_cache = _prefix_prefill_jit(
+                        self.params, self.cfg, ekv["k"], ekv["v"],
+                        plen_arr, row_cache, emb, new_len, last_idx,
+                    )
         if record:
             obs_metrics.SERVE_PREFILL_DISPATCHES.inc(kind="suffix")
-            tr = obs_trace.active()
-            if tr is not None:
-                tr.complete("prefix_copy", t0, time.perf_counter(),
-                            cat="sched", args={"plen": entry.length,
-                                               "suffix": suf_len})
         return row_cache, last, hidden, prompt_len
 
     def _admit_suffix_wave(self, members: List[tuple]) -> None:
@@ -2632,69 +2630,68 @@ class ContinuousBatcher:
             self._prefix_cache.count_hit(entry)
         faults.maybe_fail("serve.prefix_copy")
         faults.maybe_delay("serve.prefix_copy")
-        t0 = time.perf_counter()
-        s_pre = max(m[2].bucket for m in members)
+        rids = [m[0].rid for m in members]
+        with obs_trace.span("prefix_copy", "sched", wave=n, rids=rids):
+            s_pre = max(m[2].bucket for m in members)
 
-        def pad_block(buf, width):
-            if isinstance(buf, dict):
-                return {"q": pad_block(buf["q"], width),
-                        "s": pad_block(buf["s"], width)}
-            return jnp.pad(buf, ((0, 0), (0, 0), (0, width - buf.shape[2]))
-                           + ((0, 0),) * (buf.ndim - 3))
+            def pad_block(buf, width):
+                if isinstance(buf, dict):
+                    return {"q": pad_block(buf["q"], width),
+                            "s": pad_block(buf["s"], width)}
+                return jnp.pad(buf, ((0, 0), (0, 0), (0, width - buf.shape[2]))
+                               + ((0, 0),) * (buf.ndim - 3))
 
-        def cat_blocks(blocks):
-            if isinstance(blocks[0], dict):
-                return {"q": jnp.concatenate([b["q"] for b in blocks], 1),
-                        "s": jnp.concatenate([b["s"] for b in blocks], 1)}
-            return jnp.concatenate(blocks, axis=1)
+            def cat_blocks(blocks):
+                if isinstance(blocks[0], dict):
+                    return {"q": jnp.concatenate([b["q"] for b in blocks], 1),
+                            "s": jnp.concatenate([b["s"] for b in blocks], 1)}
+                return jnp.concatenate(blocks, axis=1)
 
-        ekvs = [self._entry_kv(m[2]) for m in members]
-        pks = [pad_block(kv["k"], s_pre) for kv in ekvs]
-        pvs = [pad_block(kv["v"], s_pre) for kv in ekvs]
-        if nb > n:
-            # Pad slots reuse the first member's block (their rows scatter
-            # out of bounds and their length is pinned to 1 below).
-            pks += [pks[0]] * (nb - n)
-            pvs += [pvs[0]] * (nb - n)
-        wave_pk, wave_pv = cat_blocks(pks), cat_blocks(pvs)
-        embs = [self._suffix_embed(m[2], m[0].pixel_values, m[3], chunk,
-                                   m[4][0])
-                for m in members]
-        emb = jnp.concatenate(
-            embs + [jnp.zeros_like(embs[0])] * (nb - n), axis=0)
-        plen_arr = jnp.asarray(
-            [m[2].length for m in members] + [1] * (nb - n), jnp.int32)
-        new_len = jnp.asarray(
-            [m[4][1] for m in members] + [1] * (nb - n), jnp.int32)
-        last_idx = jnp.asarray(
-            [m[4][0] - 1 for m in members] + [0] * (nb - n), jnp.int32)
-        prompt_lens = [m[4][1] for m in members]
-        row_cache = llama_mod.init_kv_cache(
-            self.cfg.llama, nb, s1, dtype=self._dtype, quant=self.kv_quant)
-        if self.mesh is not None:
-            emb = self._serving.shard_batch_array(emb, self.mesh)
-            row_cache = self._serving.shard_kv_cache(
-                row_cache, self.cfg.llama, self.mesh)
-            row_sh = jax.tree_util.tree_map(lambda x: x.sharding, row_cache)
-            flat, treedef = jax.tree_util.tree_flatten(row_sh)
-            last_sh, hidden_sh = self._suffix_wave_sh(nb)
-            fn = _get_sharded_prefix_prefill(
-                self.cfg, tuple(flat), treedef, last_sh, hidden_sh,
-            )
-            last, hidden, row_cache = fn(
-                self.params, wave_pk, wave_pv, plen_arr, row_cache, emb,
-                new_len, last_idx,
-            )
-        else:
-            last, hidden, row_cache = _prefix_prefill_jit(
-                self.params, self.cfg, wave_pk, wave_pv, plen_arr,
-                row_cache, emb, new_len, last_idx,
-            )
+            ekvs = [self._entry_kv(m[2]) for m in members]
+            pks = [pad_block(kv["k"], s_pre) for kv in ekvs]
+            pvs = [pad_block(kv["v"], s_pre) for kv in ekvs]
+            if nb > n:
+                # Pad slots reuse the first member's block (their rows scatter
+                # out of bounds and their length is pinned to 1 below).
+                pks += [pks[0]] * (nb - n)
+                pvs += [pvs[0]] * (nb - n)
+            wave_pk, wave_pv = cat_blocks(pks), cat_blocks(pvs)
+            embs = [self._suffix_embed(m[2], m[0].pixel_values, m[3], chunk,
+                                       m[4][0], m[0].rid)
+                    for m in members]
+            emb = jnp.concatenate(
+                embs + [jnp.zeros_like(embs[0])] * (nb - n), axis=0)
+            plen_arr = jnp.asarray(
+                [m[2].length for m in members] + [1] * (nb - n), jnp.int32)
+            new_len = jnp.asarray(
+                [m[4][1] for m in members] + [1] * (nb - n), jnp.int32)
+            last_idx = jnp.asarray(
+                [m[4][0] - 1 for m in members] + [0] * (nb - n), jnp.int32)
+            prompt_lens = [m[4][1] for m in members]
+            row_cache = llama_mod.init_kv_cache(
+                self.cfg.llama, nb, s1, dtype=self._dtype, quant=self.kv_quant)
+            with obs_trace.span("prefill", "admit", n=n, positions=chunk,
+                                rids=rids):
+                if self.mesh is not None:
+                    emb = self._serving.shard_batch_array(emb, self.mesh)
+                    row_cache = self._serving.shard_kv_cache(
+                        row_cache, self.cfg.llama, self.mesh)
+                    row_sh = jax.tree_util.tree_map(lambda x: x.sharding, row_cache)
+                    flat, treedef = jax.tree_util.tree_flatten(row_sh)
+                    last_sh, hidden_sh = self._suffix_wave_sh(nb)
+                    fn = _get_sharded_prefix_prefill(
+                        self.cfg, tuple(flat), treedef, last_sh, hidden_sh,
+                    )
+                    last, hidden, row_cache = fn(
+                        self.params, wave_pk, wave_pv, plen_arr, row_cache, emb,
+                        new_len, last_idx,
+                    )
+                else:
+                    last, hidden, row_cache = _prefix_prefill_jit(
+                        self.params, self.cfg, wave_pk, wave_pv, plen_arr,
+                        row_cache, emb, new_len, last_idx,
+                    )
         obs_metrics.SERVE_PREFILL_DISPATCHES.inc(kind="suffix_wave")
-        tr = obs_trace.active()
-        if tr is not None:
-            tr.complete("prefix_copy", t0, time.perf_counter(),
-                        cat="sched", args={"wave": n})
         self._scatter_wave(
             [(m[0], m[1]) for m in members], row_cache, last,
             hidden if self.draft_head is not None else None, prompt_lens,
@@ -3274,9 +3271,6 @@ class ContinuousBatcher:
             # advanced a pending chunked prefill) are observed — no-op
             # probes would drown the stall distribution in microseconds.
             obs_metrics.SERVE_ADMISSION.observe(dt_admit)
-            tr = obs_trace.active()
-            if tr is not None:
-                tr.complete("admit", t0, t0 + dt_admit, cat="sched")
         if self.role == "prefill":
             # Prefill role: admission IS the job. Activated rows never
             # decode here — the sweep gathers each one's block run into
@@ -3556,181 +3550,183 @@ class ContinuousBatcher:
             obs_metrics.SERVE_SEGMENTS.inc()
             obs_metrics.SERVE_OCCUPANCY.observe(
                 int(self.max_batch - int(self.frozen.sum())))
-        t_disp0 = time.perf_counter()
-        _ann = obs_profiling.annotation("serve.segment_dispatch")
-        _ann.__enter__()
-        lane_out = None
-        if self.speculative:
-            n_iters = max(1, chunk // spec_w)
-            history = (jnp.asarray(self._history.astype(np.int32))
-                       if self._history is not None else None)
-            if self.mesh is not None:
-                if history is not None:
-                    history = self._serving.replicate(history, self.mesh)
-                if mixed:
-                    last_sh, hidden_sh = self._suffix_wave_sh(self._lane_cap)
-                    fn = _get_sharded_mixed_spec_segment(
-                        self.cfg, n_iters, spec_w,
-                        self._lane_chunk, int(self.eos),
-                        self.temperature, self.top_p,
-                        self._cache_flat_sh, self._cache_treedef,
-                        self._ids_sh, self._b_sh, self._key_sh,
-                        self._drafts_sh, self._lane_flat_sh,
-                        self._lane_treedef, self._lane_emb_sh,
-                        last_sh, hidden_sh,
-                    )
+        # live: the rows this segment decodes for, by the host's mirror
+        # (which a pipelined carry may be one segment ahead of); rows:
+        # those it pays for.
+        live = ([req.rid for r, req in enumerate(self.rows)
+                 if req is not None and not self.frozen[r]]
+                if obs_trace.enabled() else ())
+        with obs_trace.span("dispatch", "sched", chunk=chunk, live=len(live),
+                            rows=self.max_batch,
+                            lanes=len(self._lanes) if mixed else 0,
+                            rids=live):
+            lane_out = None
+            if self.speculative:
+                n_iters = max(1, chunk // spec_w)
+                history = (jnp.asarray(self._history.astype(np.int32))
+                           if self._history is not None else None)
+                if self.mesh is not None:
+                    if history is not None:
+                        history = self._serving.replicate(history, self.mesh)
+                    if mixed:
+                        last_sh, hidden_sh = self._suffix_wave_sh(self._lane_cap)
+                        fn = _get_sharded_mixed_spec_segment(
+                            self.cfg, n_iters, spec_w,
+                            self._lane_chunk, int(self.eos),
+                            self.temperature, self.top_p,
+                            self._cache_flat_sh, self._cache_treedef,
+                            self._ids_sh, self._b_sh, self._key_sh,
+                            self._drafts_sh, self._lane_flat_sh,
+                            self._lane_treedef, self._lane_emb_sh,
+                            last_sh, hidden_sh,
+                        )
+                        (self.ids_buf, n_new, done, self.cache, self.key,
+                         self.spec_drafts, it, frozen_out, n_rem_out,
+                         base_pos_out, row_acc, row_off, pos_acc, pos_off,
+                         *lane_out) = fn(
+                            self.params, self.cache, self.key, self.ids_buf,
+                            base_pos, frozen, n_rem, history, self.draft_head,
+                            self.spec_drafts, self._lane_embeds,
+                            self._lane_cache, lane_start, lane_new_len,
+                            lane_last_idx, spec_depth,
+                        )
+                    else:
+                        fn = _get_sharded_spec_segment(
+                            self.cfg, n_iters, spec_w, int(self.eos),
+                            self.temperature, self.top_p,
+                            self._cache_flat_sh, self._cache_treedef,
+                            self._ids_sh, self._b_sh, self._key_sh,
+                            self._drafts_sh,
+                        )
+                        (self.ids_buf, n_new, done, self.cache, self.key,
+                         self.spec_drafts, it, frozen_out, n_rem_out,
+                         base_pos_out, row_acc, row_off, pos_acc,
+                         pos_off) = fn(
+                            self.params, self.cache, self.key, self.ids_buf,
+                            base_pos, frozen, n_rem, history, self.draft_head,
+                            self.spec_drafts, spec_depth,
+                        )
+                elif mixed:
                     (self.ids_buf, n_new, done, self.cache, self.key,
                      self.spec_drafts, it, frozen_out, n_rem_out,
                      base_pos_out, row_acc, row_off, pos_acc, pos_off,
-                     *lane_out) = fn(
-                        self.params, self.cache, self.key, self.ids_buf,
-                        base_pos, frozen, n_rem, history, self.draft_head,
-                        self.spec_drafts, self._lane_embeds,
-                        self._lane_cache, lane_start, lane_new_len,
-                        lane_last_idx, spec_depth,
+                     *lane_out) = (
+                        _mixed_spec_segment_jit(
+                            self.params, self.cfg, self.cache, self.key,
+                            self.ids_buf, base_pos, frozen, n_rem,
+                            self._lane_embeds, self._lane_cache, lane_start,
+                            lane_new_len, lane_last_idx, n_iters,
+                            spec_w, self._lane_chunk,
+                            int(self.eos), self.temperature, self.top_p,
+                            history=history, medusa=self.draft_head,
+                            drafts=self.spec_drafts, depth=spec_depth,
+                        )
                     )
                 else:
-                    fn = _get_sharded_spec_segment(
-                        self.cfg, n_iters, spec_w, int(self.eos),
-                        self.temperature, self.top_p,
-                        self._cache_flat_sh, self._cache_treedef,
-                        self._ids_sh, self._b_sh, self._key_sh,
-                        self._drafts_sh,
-                    )
                     (self.ids_buf, n_new, done, self.cache, self.key,
                      self.spec_drafts, it, frozen_out, n_rem_out,
-                     base_pos_out, row_acc, row_off, pos_acc,
-                     pos_off) = fn(
-                        self.params, self.cache, self.key, self.ids_buf,
-                        base_pos, frozen, n_rem, history, self.draft_head,
-                        self.spec_drafts, spec_depth,
+                     base_pos_out, row_acc, row_off, pos_acc, pos_off) = (
+                        _spec_segment_jit(
+                            self.params, self.cfg, self.cache, self.key,
+                            self.ids_buf, base_pos,
+                            frozen, n_rem, n_iters, spec_w,
+                            int(self.eos), self.temperature, self.top_p,
+                            history=history, medusa=self.draft_head,
+                            drafts=self.spec_drafts, depth=spec_depth,
+                        )
                     )
-            elif mixed:
-                (self.ids_buf, n_new, done, self.cache, self.key,
-                 self.spec_drafts, it, frozen_out, n_rem_out,
-                 base_pos_out, row_acc, row_off, pos_acc, pos_off,
-                 *lane_out) = (
-                    _mixed_spec_segment_jit(
-                        self.params, self.cfg, self.cache, self.key,
-                        self.ids_buf, base_pos, frozen, n_rem,
-                        self._lane_embeds, self._lane_cache, lane_start,
-                        lane_new_len, lane_last_idx, n_iters,
-                        spec_w, self._lane_chunk,
-                        int(self.eos), self.temperature, self.top_p,
-                        history=history, medusa=self.draft_head,
-                        drafts=self.spec_drafts, depth=spec_depth,
-                    )
+                # Read back only the window a segment could have written
+                # (n_iters * window <= max(chunk, window) slots per row), not
+                # the whole (B, max_len) buffer. The gather runs on the
+                # OUTPUT ids_buf at the PRE-segment base — enqueued now, so
+                # the harvest is one device_get with no extra dispatch.
+                width = max(chunk, spec_w)
+                rec.update(
+                    gather=_gather_new_jit(self.ids_buf, base_pos, width),
+                    it=it, n_new=n_new, done=done, window=spec_w,
+                    row_acc=row_acc, row_off=row_off,
+                    pos_acc=pos_acc, pos_off=pos_off,
                 )
             else:
-                (self.ids_buf, n_new, done, self.cache, self.key,
-                 self.spec_drafts, it, frozen_out, n_rem_out,
-                 base_pos_out, row_acc, row_off, pos_acc, pos_off) = (
-                    _spec_segment_jit(
-                        self.params, self.cfg, self.cache, self.key,
-                        self.ids_buf, base_pos,
-                        frozen, n_rem, n_iters, spec_w,
-                        int(self.eos), self.temperature, self.top_p,
-                        history=history, medusa=self.draft_head,
-                        drafts=self.spec_drafts, depth=spec_depth,
-                    )
-                )
-            # Read back only the window a segment could have written
-            # (n_iters * window <= max(chunk, window) slots per row), not
-            # the whole (B, max_len) buffer. The gather runs on the
-            # OUTPUT ids_buf at the PRE-segment base — enqueued now, so
-            # the harvest is one device_get with no extra dispatch.
-            width = max(chunk, spec_w)
-            rec.update(
-                gather=_gather_new_jit(self.ids_buf, base_pos, width),
-                it=it, n_new=n_new, done=done, window=spec_w,
-                row_acc=row_acc, row_off=row_off,
-                pos_acc=pos_acc, pos_off=pos_off,
-            )
-        else:
-            if self.mesh is not None:
-                if mixed:
-                    last_sh, hidden_sh = self._suffix_wave_sh(self._lane_cap)
-                    fn = _get_sharded_mixed_decode_segment(
-                        self.cfg, chunk, self._lane_chunk, int(self.eos),
-                        self.temperature, self.top_p, self.nan_check,
-                        self._cache_flat_sh, self._cache_treedef,
-                        self._logits_sh, self._toks_sh, self._b_sh,
-                        self._key_sh, self._lane_flat_sh,
-                        self._lane_treedef, self._lane_emb_sh,
-                        last_sh, hidden_sh,
-                    )
+                if self.mesh is not None:
+                    if mixed:
+                        last_sh, hidden_sh = self._suffix_wave_sh(self._lane_cap)
+                        fn = _get_sharded_mixed_decode_segment(
+                            self.cfg, chunk, self._lane_chunk, int(self.eos),
+                            self.temperature, self.top_p, self.nan_check,
+                            self._cache_flat_sh, self._cache_treedef,
+                            self._logits_sh, self._toks_sh, self._b_sh,
+                            self._key_sh, self._lane_flat_sh,
+                            self._lane_treedef, self._lane_emb_sh,
+                            last_sh, hidden_sh,
+                        )
+                        (tokens, n_new, done, fin, self.logits, self.cache,
+                         self.key, frozen_out, n_rem_out, *lane_out) = fn(
+                            self.params, self.logits, self.cache, self.key,
+                            frozen, n_rem, self._lane_embeds,
+                            self._lane_cache, lane_start, lane_new_len,
+                            lane_last_idx,
+                        )
+                    else:
+                        fn = _get_sharded_decode_segment(
+                            self.cfg, chunk, int(self.eos),
+                            self.temperature, self.top_p, self.nan_check,
+                            self._cache_flat_sh, self._cache_treedef,
+                            self._logits_sh, self._toks_sh, self._b_sh,
+                            self._key_sh,
+                        )
+                        (tokens, n_new, done, fin, self.logits, self.cache,
+                         self.key, frozen_out, n_rem_out) = fn(
+                            self.params, self.logits, self.cache, self.key,
+                            frozen, n_rem,
+                        )
+                elif mixed:
                     (tokens, n_new, done, fin, self.logits, self.cache,
-                     self.key, frozen_out, n_rem_out, *lane_out) = fn(
-                        self.params, self.logits, self.cache, self.key,
-                        frozen, n_rem, self._lane_embeds,
-                        self._lane_cache, lane_start, lane_new_len,
-                        lane_last_idx,
+                     self.key, frozen_out, n_rem_out, *lane_out) = (
+                        _mixed_decode_segment_jit(
+                            self.params, self.cfg, self.logits, self.cache,
+                            self.key, frozen, n_rem, self._lane_embeds,
+                            self._lane_cache, lane_start, lane_new_len,
+                            lane_last_idx, chunk, self._lane_chunk,
+                            int(self.eos), self.temperature, self.top_p,
+                            self.nan_check,
+                        )
                     )
                 else:
-                    fn = _get_sharded_decode_segment(
-                        self.cfg, chunk, int(self.eos),
-                        self.temperature, self.top_p, self.nan_check,
-                        self._cache_flat_sh, self._cache_treedef,
-                        self._logits_sh, self._toks_sh, self._b_sh,
-                        self._key_sh,
-                    )
                     (tokens, n_new, done, fin, self.logits, self.cache,
-                     self.key, frozen_out, n_rem_out) = fn(
-                        self.params, self.logits, self.cache, self.key,
-                        frozen, n_rem,
+                     self.key, frozen_out, n_rem_out) = (
+                        _decode_segment_jit(
+                            self.params, self.cfg, self.logits, self.cache,
+                            self.key, frozen, n_rem, chunk, int(self.eos),
+                            self.temperature, self.top_p, self.nan_check,
+                        )
                     )
-            elif mixed:
-                (tokens, n_new, done, fin, self.logits, self.cache,
-                 self.key, frozen_out, n_rem_out, *lane_out) = (
-                    _mixed_decode_segment_jit(
-                        self.params, self.cfg, self.logits, self.cache,
-                        self.key, frozen, n_rem, self._lane_embeds,
-                        self._lane_cache, lane_start, lane_new_len,
-                        lane_last_idx, chunk, self._lane_chunk,
-                        int(self.eos), self.temperature, self.top_p,
-                        self.nan_check,
-                    )
-                )
-            else:
-                (tokens, n_new, done, fin, self.logits, self.cache,
-                 self.key, frozen_out, n_rem_out) = (
-                    _decode_segment_jit(
-                        self.params, self.cfg, self.logits, self.cache,
-                        self.key, frozen, n_rem, chunk, int(self.eos),
-                        self.temperature, self.top_p, self.nan_check,
-                    )
-                )
-            base_pos_out = None
-            rec.update(tokens=tokens, n_new=n_new, done=done, fin=fin)
-        if lane_out is not None:
-            # Lane bookkeeping happens at DISPATCH (not harvest): the
-            # advance is deterministic, so the pipelined scheduler can
-            # build the NEXT boundary's lane args before this segment's
-            # outputs are fetched. A lane that just covered its prompt
-            # keeps its final-chunk logits/hidden as futures — sliced and
-            # fetched only when the (drained) finish path runs.
-            lane_last, lane_hidden, self._lane_cache = lane_out
-            for l, end in lane_adv:
-                l.filled = end
-                if l.filled >= l.prompt_len:
-                    l.last_logits = lane_last[l.slot: l.slot + 1]
-                    l.last_hidden = lane_hidden[l.slot: l.slot + 1]
-            if record_carry and lane_adv:
-                self.mixed_prefill_tokens += lane_tok
-                obs_metrics.SERVE_MIXED_SEGMENTS.inc()
-                obs_metrics.SERVE_MIXED_LANES.observe(len(lane_adv))
-                obs_metrics.SERVE_MIXED_PREFILL_TOKENS.inc(lane_tok)
-                obs_metrics.SERVE_PREFILL_DISPATCHES.inc(kind="piggyback")
-                rec["n_lanes"] = len(lane_adv)
-        if record_carry:
-            self._dev_carry = (frozen_out, n_rem_out, base_pos_out)
-            self.seg_count += 1
-        _ann.__exit__(None, None, None)
+                base_pos_out = None
+                rec.update(tokens=tokens, n_new=n_new, done=done, fin=fin)
+            if lane_out is not None:
+                # Lane bookkeeping happens at DISPATCH (not harvest): the
+                # advance is deterministic, so the pipelined scheduler can
+                # build the NEXT boundary's lane args before this segment's
+                # outputs are fetched. A lane that just covered its prompt
+                # keeps its final-chunk logits/hidden as futures — sliced and
+                # fetched only when the (drained) finish path runs.
+                lane_last, lane_hidden, self._lane_cache = lane_out
+                for l, end in lane_adv:
+                    l.filled = end
+                    if l.filled >= l.prompt_len:
+                        l.last_logits = lane_last[l.slot: l.slot + 1]
+                        l.last_hidden = lane_hidden[l.slot: l.slot + 1]
+                if record_carry and lane_adv:
+                    self.mixed_prefill_tokens += lane_tok
+                    obs_metrics.SERVE_MIXED_SEGMENTS.inc()
+                    obs_metrics.SERVE_MIXED_LANES.observe(len(lane_adv))
+                    obs_metrics.SERVE_MIXED_PREFILL_TOKENS.inc(lane_tok)
+                    obs_metrics.SERVE_PREFILL_DISPATCHES.inc(kind="piggyback")
+                    rec["n_lanes"] = len(lane_adv)
+            if record_carry:
+                self._dev_carry = (frozen_out, n_rem_out, base_pos_out)
+                self.seg_count += 1
         rec["t_dispatch"] = time.perf_counter()
-        tr = obs_trace.active()
-        if tr is not None:
-            tr.complete("dispatch", t_disp0, rec["t_dispatch"], cat="sched",
-                        args={"chunk": chunk})
         return rec
 
     # egpt-check: harvest -- THE designed blocking point: fetches a settled segment; downstream runs on harvested host state
@@ -3745,29 +3741,38 @@ class ContinuousBatcher:
             gap = t_fetch - self._t_prev_fetch_end
             self.host_gap_s += gap
             obs_metrics.SERVE_HOST_GAP.inc(gap)
-        if self.speculative:
-            (new_np, it_v, n_new, done, frozen_in, row_acc, row_off,
-             pos_acc, pos_off) = jax.device_get(
-                (rec["gather"], rec["it"], rec["n_new"], rec["done"],
-                 rec["frozen_in"], rec["row_acc"], rec["row_off"],
-                 rec["pos_acc"], rec["pos_off"])
-            )
-            new_np = np.asarray(new_np)
-            tokens = None
-            finite = None
-        else:
-            # The quarantine mask is computed in-graph and rides the same
-            # device_get as the segment outputs — no extra dispatch or
-            # round trip on the hot path.
-            tokens, n_new, done, finite, frozen_in = jax.device_get(
-                (rec["tokens"], rec["n_new"], rec["done"], rec["fin"],
-                 rec["frozen_in"])
-            )
-            finite = np.asarray(finite) if self.nan_check else None
-            tokens = np.asarray(tokens)
-            new_np = None
-        t_end = time.perf_counter()
-        wait = t_end - t_fetch
+        # The fetch block IS the visible device time: one span per
+        # segment, so Perfetto shows the un-hidden device share against
+        # the dispatch/harvest host spans.
+        with obs_trace.span("segment_fetch", "sched") as fetch:
+            if self.speculative:
+                (new_np, it_v, n_new, done, frozen_in, row_acc, row_off,
+                 pos_acc, pos_off) = jax.device_get(
+                    (rec["gather"], rec["it"], rec["n_new"], rec["done"],
+                     rec["frozen_in"], rec["row_acc"], rec["row_off"],
+                     rec["pos_acc"], rec["pos_off"])
+                )
+                new_np = np.asarray(new_np)
+                tokens = None
+                finite = None
+            else:
+                # The quarantine mask is computed in-graph and rides the
+                # same device_get as the segment outputs — no extra
+                # dispatch or round trip on the hot path.
+                tokens, n_new, done, finite, frozen_in = jax.device_get(
+                    (rec["tokens"], rec["n_new"], rec["done"], rec["fin"],
+                     rec["frozen_in"])
+                )
+                finite = np.asarray(finite) if self.nan_check else None
+                tokens = np.asarray(tokens)
+                new_np = None
+            t_end = time.perf_counter()
+            wait = t_end - t_fetch
+            # The rows this segment decoded for (its input freeze mask).
+            rids = ([req.rid for r, req in enumerate(self.rows)
+                     if req is not None and not frozen_in[r]]
+                    if obs_trace.enabled() else ())
+            fetch.set(wait_s=round(wait, 6), rids=rids)
         if wait > 1e-4:
             # The device was still busy when the host arrived: everything
             # the host did since this segment's dispatch — minus any time
@@ -3779,91 +3784,88 @@ class ContinuousBatcher:
             obs_metrics.SERVE_OVERLAP_HIDDEN.inc(hidden)
         self.device_segment_s += wait
         obs_metrics.SERVE_SEGMENT.observe(wait)
-        tr = obs_trace.active()
-        if tr is not None:
-            # The fetch block IS the visible device time: one span per
-            # segment, so Perfetto shows the un-hidden device share
-            # against the dispatch/harvest host spans.
-            tr.complete("segment_fetch", t_fetch, t_end, cat="sched",
-                        args={"wait_s": round(wait, 6)})
         self._t_prev_fetch_end = t_end
-        if self.speculative:
-            self.spec_iterations += int(it_v)
-            self.spec_tokens += int(n_new.sum())
-            if self._spec_ctl is not None:
-                # Feed the controller the segment's UNCAPPED acceptance
-                # (per-row and per-position) — the depth policy for the
-                # NEXT boundary; in pipelined mode one boundary of lag,
-                # deterministically (the choice for N+1 was already made
-                # at its dispatch).
-                r_acc = np.asarray(row_acc)
-                r_off = np.asarray(row_off)
-                f_in = np.asarray(frozen_in)
-                self._spec_ctl.observe(
-                    [(req.rid, int(r_acc[r]), int(r_off[r]))
-                     for r, req in enumerate(self.rows)
-                     if req is not None and not f_in[r]],
-                    [int(x) for x in np.asarray(pos_acc)],
-                    [int(x) for x in np.asarray(pos_off)],
-                )
-                obs_metrics.SERVE_SPEC_ACCEPT.set(
-                    self._spec_ctl.accept_ema or 0.0)
-        n_new = np.asarray(n_new)
-        done = np.asarray(done)
-        frozen_in = np.asarray(frozen_in)
-        if rec.get("n_lanes"):
-            # Stall-free evidence (ISSUE 5): this segment carried live
-            # piggyback lanes. If decode rows were live too, they must
-            # have committed tokens in the SAME dispatch — a zero-token
-            # harvest here would be exactly the stall class the mixed
-            # segment exists to remove.
-            live = ~frozen_in
-            if live.any():
-                self.mixed_boundaries += 1
-                if int(n_new[live].sum()) == 0:
-                    self.mixed_zero_harvests += 1
-        now = time.perf_counter()
-        for r, req in enumerate(self.rows):
-            # frozen_in is the segment's INPUT freeze mask (the host
-            # mirror may already be one segment ahead of this harvest):
-            # rows frozen at dispatch produced nothing here.
-            if req is None or frozen_in[r]:
-                continue
-            if finite is not None and not finite[r]:
-                # Non-finite logits poison only this ROW: its segment
-                # tokens (sampled from NaN/inf logits) are discarded, the
-                # row is frozen and the request fails with a structured
-                # status — the batch and the engine keep running. (The
-                # in-graph carry froze it the same way: nan_gate mirrors
-                # nan_check.)
-                self._finish_row(r, status=STATUS_NAN, stale_carry=False)
-                continue
+        with obs_trace.span("harvest", "sched", rids=rids) as sp:
             if self.speculative:
-                new = new_np[r, : n_new[r]]
-                self.base_pos[r] += int(n_new[r])
-            else:
-                new = tokens[r, : n_new[r]]
-            if len(new):
-                obs_journey.event(self._journey_owner, req.rid,
-                                  "segment", t=now, tokens=len(new))
-                if req.t_first is None:
-                    req.t_first = now
-                elif req.t_last is not None:
-                    # Inter-token latency: tokens land in harvest-sized
-                    # groups, so the observable per-token gap is the mean
-                    # over this harvest interval, weighted by its token
-                    # count. A row's FIRST harvest is excluded — those
-                    # gaps live inside TTFT.
-                    obs_metrics.SERVE_ITL.observe(
-                        (now - req.t_last) / len(new), n=len(new))
-                req.t_last = now
-                obs_metrics.SERVE_TOKENS.inc(len(new))
-            req.tokens.extend(int(t) for t in new)
-            self.n_rem[r] -= int(n_new[r])
-            if done[r] or self.n_rem[r] <= 0:
-                # The device carry already froze this row in-graph — the
-                # harvest only mirrors it, so the carry stays valid.
-                self._finish_row(r, stale_carry=False)
+                self.spec_iterations += int(it_v)
+                self.spec_tokens += int(n_new.sum())
+                if self._spec_ctl is not None:
+                    # Feed the controller the segment's UNCAPPED acceptance
+                    # (per-row and per-position) — the depth policy for the
+                    # NEXT boundary; in pipelined mode one boundary of lag,
+                    # deterministically (the choice for N+1 was already made
+                    # at its dispatch).
+                    r_acc = np.asarray(row_acc)
+                    r_off = np.asarray(row_off)
+                    f_in = np.asarray(frozen_in)
+                    self._spec_ctl.observe(
+                        [(req.rid, int(r_acc[r]), int(r_off[r]))
+                         for r, req in enumerate(self.rows)
+                         if req is not None and not f_in[r]],
+                        [int(x) for x in np.asarray(pos_acc)],
+                        [int(x) for x in np.asarray(pos_off)],
+                    )
+                    obs_metrics.SERVE_SPEC_ACCEPT.set(
+                        self._spec_ctl.accept_ema or 0.0)
+            n_new = np.asarray(n_new)
+            done = np.asarray(done)
+            frozen_in = np.asarray(frozen_in)
+            if rec.get("n_lanes"):
+                # Stall-free evidence (ISSUE 5): this segment carried live
+                # piggyback lanes. If decode rows were live too, they must
+                # have committed tokens in the SAME dispatch — a zero-token
+                # harvest here would be exactly the stall class the mixed
+                # segment exists to remove.
+                live = ~frozen_in
+                if live.any():
+                    self.mixed_boundaries += 1
+                    if int(n_new[live].sum()) == 0:
+                        self.mixed_zero_harvests += 1
+            now = time.perf_counter()
+            committed = 0
+            for r, req in enumerate(self.rows):
+                # frozen_in is the segment's INPUT freeze mask (the host
+                # mirror may already be one segment ahead of this harvest):
+                # rows frozen at dispatch produced nothing here.
+                if req is None or frozen_in[r]:
+                    continue
+                if finite is not None and not finite[r]:
+                    # Non-finite logits poison only this ROW: its segment
+                    # tokens (sampled from NaN/inf logits) are discarded, the
+                    # row is frozen and the request fails with a structured
+                    # status — the batch and the engine keep running. (The
+                    # in-graph carry froze it the same way: nan_gate mirrors
+                    # nan_check.)
+                    self._finish_row(r, status=STATUS_NAN, stale_carry=False)
+                    continue
+                if self.speculative:
+                    new = new_np[r, : n_new[r]]
+                    self.base_pos[r] += int(n_new[r])
+                else:
+                    new = tokens[r, : n_new[r]]
+                if len(new):
+                    committed += len(new)
+                    obs_journey.event(self._journey_owner, req.rid,
+                                      "segment", t=now, tokens=len(new))
+                    if req.t_first is None:
+                        req.t_first = now
+                    elif req.t_last is not None:
+                        # Inter-token latency: tokens land in harvest-sized
+                        # groups, so the observable per-token gap is the mean
+                        # over this harvest interval, weighted by its token
+                        # count. A row's FIRST harvest is excluded — those
+                        # gaps live inside TTFT.
+                        obs_metrics.SERVE_ITL.observe(
+                            (now - req.t_last) / len(new), n=len(new))
+                    req.t_last = now
+                    obs_metrics.SERVE_TOKENS.inc(len(new))
+                req.tokens.extend(int(t) for t in new)
+                self.n_rem[r] -= int(n_new[r])
+                if done[r] or self.n_rem[r] <= 0:
+                    # The device carry already froze this row in-graph — the
+                    # harvest only mirrors it, so the carry stays valid.
+                    self._finish_row(r, stale_carry=False)
+            sp.set(tokens=committed)
 
     def _finish_row(self, r: int, status: str = STATUS_OK,
                     stale_carry: bool = True) -> None:
@@ -4118,31 +4120,28 @@ class ContinuousBatcher:
         # overlay) and its seed blocks must stay un-recycled for the
         # lane's whole pendency; every lane-termination path drains it.
         entry.pins += 1
-        t0 = time.perf_counter()
-        self._ensure_lane_buffers(max(s1, entry.bucket))
-        slot = self._lane_free.pop()
-        slot_arr = jnp.asarray(slot, jnp.int32)
-        if self.mesh is not None:
-            seed = _get_sharded_lane_seed(
-                self._lane_flat_sh, self._lane_treedef)
-        else:
-            seed = _lane_seed_jit
-        ekv = self._entry_kv(entry)
-        self._lane_cache = seed(
-            self._lane_cache, slot_arr, ekv["k"], ekv["v"])
-        emb = self._suffix_embed(entry, req.pixel_values, suffix_ids,
-                                 suf_len, suf_len)
-        plen = entry.length
-        self._lane_embeds = self._lane_embeds.at[
-            slot, plen: plen + suf_len].set(emb[0])
-        if self.mesh is not None:
-            self._lane_embeds = jax.device_put(
-                self._lane_embeds, self._lane_emb_sh)
-        tr = obs_trace.active()
-        if tr is not None:
-            tr.complete("prefix_copy", t0, time.perf_counter(),
-                        cat="sched", args={"plen": plen, "suffix": suf_len,
-                                           "lane": slot})
+        with obs_trace.span("prefix_copy", "sched", plen=entry.length,
+                            suffix=suf_len, rid=req.rid) as copy:
+            self._ensure_lane_buffers(max(s1, entry.bucket))
+            slot = self._lane_free.pop()
+            slot_arr = jnp.asarray(slot, jnp.int32)
+            if self.mesh is not None:
+                seed = _get_sharded_lane_seed(
+                    self._lane_flat_sh, self._lane_treedef)
+            else:
+                seed = _lane_seed_jit
+            ekv = self._entry_kv(entry)
+            self._lane_cache = seed(
+                self._lane_cache, slot_arr, ekv["k"], ekv["v"])
+            emb = self._suffix_embed(entry, req.pixel_values, suffix_ids,
+                                     suf_len, suf_len, req.rid)
+            plen = entry.length
+            self._lane_embeds = self._lane_embeds.at[
+                slot, plen: plen + suf_len].set(emb[0])
+            if self.mesh is not None:
+                self._lane_embeds = jax.device_put(
+                    self._lane_embeds, self._lane_emb_sh)
+            copy.set(lane=slot)
         self._lanes.append(_PendingLane(
             req, row, slot, prompt_len, filled=plen, entry=entry))
         obs_journey.event(self._journey_owner, req.rid, "lane_join",
@@ -4941,9 +4940,24 @@ class ContinuousBatcher:
         return {"k": k, "v": v}
 
     def _admit(self) -> bool:
+        """``_admit_queue`` under the ``sched.admit`` span, which is
+        recorded when the step did admission work: with the requests it
+        worked for and the paths they took."""
+        took: List[tuple] = []  # (rid, path)
+        with obs_trace.span("admit", "sched") as sp:
+            did_work = self._admit_queue(took)
+            if did_work:
+                sp.set(rids=[rid for rid, _ in took], n=len(took),
+                       path="+".join(sorted({p for _, p in took})))
+            else:
+                sp.drop()
+        return did_work
+
+    def _admit_queue(self, took: List[tuple]) -> bool:
         """Returns True when this step did admission work (advanced a
         pending chunked prefill or popped the queue) — the telemetry
-        gate for the admission-stall histogram.
+        gate for the admission-stall histogram. ``took`` receives one
+        (rid, path) per request this call worked for.
 
         Admission policy per popped request (ISSUE 5): with a
         ``prefill_budget`` armed AND rows actively decoding (or lanes
@@ -4965,9 +4979,12 @@ class ContinuousBatcher:
         if self._lanes:
             # step() drained the pipeline when any lane was ready, so
             # the activations below apply against settled state.
+            took += [(l.req.rid, "lane") for l in self._lanes
+                     if l.filled >= l.prompt_len]
             did_work |= self._finish_ready_lanes()
         if self._pending is not None:
             did_work = True
+            took.append((self._pending.req.rid, "chunk"))
             self._advance_pending()
         # Piggyback is the per-boundary choice only while something is
         # decoding (or lanes are mid-flight — join them); with every row
@@ -5015,6 +5032,7 @@ class ContinuousBatcher:
                 # (ISSUE 16). The gate pre-checked the same reservation
                 # arithmetic, so failure here is only an eviction race.
                 if self._paged_restore(req, row):
+                    took.append((req.rid, "restore"))
                     continue
                 self._paged_requeue(req, row)
                 break
@@ -5026,17 +5044,16 @@ class ContinuousBatcher:
                 # reservation arithmetic, so failure is only an
                 # allocation race.
                 if self._handoff_splice(req, row):
+                    took.append((req.rid, "handoff"))
                     continue
                 self._paged_requeue(req, row)
                 break
             hit = None
             if self._prefix_cache is not None:
-                t0 = time.perf_counter()
-                hit = self._prefix_lookup(req)
-                tr = obs_trace.active()
-                if tr is not None:
-                    tr.complete("prefix_lookup", t0, time.perf_counter(),
-                                cat="sched", args={"hit": hit is not None})
+                with obs_trace.span("prefix_lookup", "sched",
+                                    rid=req.rid) as sp:
+                    hit = self._prefix_lookup(req)
+                    sp.set(hit=hit is not None)
             if hit is not None:
                 entry, suffix_ids = hit
                 fit = self._prefix_fit(entry, suffix_ids)
@@ -5055,6 +5072,7 @@ class ContinuousBatcher:
                     if piggy:
                         self._start_suffix_lane(req, row, entry,
                                                 suffix_ids, fit)
+                        took.append((req.rid, "lane"))
                         continue
                     # SELECTION pin: the entry must survive (and a paged
                     # entry's blocks must stay un-recycled) until this
@@ -5076,6 +5094,7 @@ class ContinuousBatcher:
                     break
             if piggy:
                 self._start_full_lane(req, row)
+                took.append((req.rid, "lane"))
                 continue
             if self.prefill_chunk and not bool(self.frozen.all()):
                 # Active rows are decoding: chunked admission. The row is
@@ -5087,6 +5106,7 @@ class ContinuousBatcher:
                 self._pending = _PendingAdmission(
                     req, row, padded, prompt_len, row_cache
                 )
+                took.append((req.rid, "chunk"))
                 self._advance_pending()
                 break
             wave.append((req, row))
@@ -5099,12 +5119,14 @@ class ContinuousBatcher:
             groups.setdefault((h[4][2], h[4][3]), []).append(h)
         for (_, _), members in sorted(groups.items()):
             obs_metrics.SERVE_ADMISSION_WAVE.observe(len(members))
+            took += [(m[0].rid, "suffix" if len(members) == 1
+                      else "suffix_wave") for m in members]
             if len(members) == 1:
                 req, row, entry, suffix_ids, fit = members[0]
                 try:
                     pre_admit = self._prefix_admit(entry,
                                                    req.pixel_values,
-                                                   suffix_ids)
+                                                   suffix_ids, rid=req.rid)
                     if pre_admit is None:  # unreachable: fit pre-checked
                         wave.append((req, row))
                         continue
@@ -5132,6 +5154,8 @@ class ContinuousBatcher:
         if not wave:
             return did_work
         obs_metrics.SERVE_ADMISSION_WAVE.observe(len(wave))
+        took += [(req.rid, "wave" if len(wave) > 1 else "row")
+                 for req, _ in wave]
         if len(wave) > 1:
             self._admit_wave(wave)
             return True
@@ -5140,19 +5164,21 @@ class ContinuousBatcher:
         # last hidden to seed the row's first draft window.
         req, row = wave[0]
         padded, mask, prompt_len = self._prep_request(req)
-        row_cache = self._new_row_cache(padded.shape[1])
         want_hidden = self.draft_head is not None
         row_hidden = None
-        if self.mesh is not None:
-            pre = _prefill_sharded(
-                self.params, self.cfg, padded, mask, row_cache,
-                self.mesh, return_hidden=want_hidden,
-            )
-        else:
-            pre = _prefill_jit(
-                self.params, self.cfg, padded, mask, row_cache, True,
-                return_hidden=want_hidden,
-            )
+        with obs_trace.span("prefill", "admit", n=1,
+                            positions=int(padded.shape[1]), rid=req.rid):
+            row_cache = self._new_row_cache(padded.shape[1])
+            if self.mesh is not None:
+                pre = _prefill_sharded(
+                    self.params, self.cfg, padded, mask, row_cache,
+                    self.mesh, return_hidden=want_hidden,
+                )
+            else:
+                pre = _prefill_jit(
+                    self.params, self.cfg, padded, mask, row_cache, True,
+                    return_hidden=want_hidden,
+                )
         obs_metrics.SERVE_PREFILL_DISPATCHES.inc(kind="full")
         if want_hidden:
             row_logits, row_hidden, row_cache = pre
@@ -5250,23 +5276,45 @@ class ContinuousBatcher:
         from eventgpt_tpu.data.tokenizer import split_at_event
         from eventgpt_tpu.models.eventchat import _pad_batch, splice_embeddings
 
-        pv = jnp.asarray(req.pixel_values, self._dtype)[None]
-        if self.mesh is not None:
-            pv = self._serving.shard_batch_array(pv, self.mesh)
-        ev = eventchat.encode_events_batch(self.params, self.cfg, pv)
-        embeds = [splice_embeddings(
-            self.params, self.cfg, split_at_event(req.input_ids), ev[0]
-        )]
-        padded, mask, lens = _pad_batch(embeds)
-        prompt_len = int(lens[0])
-        bucket = 2 * SEQ_BUCKET
-        s1 = min(((prompt_len + bucket - 1) // bucket) * bucket, self.max_len)
-        padded = jnp.pad(padded, ((0, 0), (0, s1 - prompt_len), (0, 0)))
-        mask = jnp.pad(mask, ((0, 0), (0, s1 - prompt_len)))
-        if self.mesh is not None:
-            padded = self._serving.shard_batch_array(padded, self.mesh)
-            mask = self._serving.shard_batch_array(mask, self.mesh)
+        pv = self._upload_pixels([req.pixel_values], [req.rid])
+        with obs_trace.span("encode", "admit", n=1, rid=req.rid):
+            ev = eventchat.encode_events_batch(self.params, self.cfg, pv)
+            embeds = [splice_embeddings(
+                self.params, self.cfg, split_at_event(req.input_ids), ev[0]
+            )]
+            padded, mask, lens = _pad_batch(embeds)
+            prompt_len = int(lens[0])
+            bucket = 2 * SEQ_BUCKET
+            s1 = min(((prompt_len + bucket - 1) // bucket) * bucket,
+                     self.max_len)
+            padded = jnp.pad(padded, ((0, 0), (0, s1 - prompt_len), (0, 0)))
+            mask = jnp.pad(mask, ((0, 0), (0, s1 - prompt_len)))
+            if self.mesh is not None:
+                padded = self._serving.shard_batch_array(padded, self.mesh)
+                mask = self._serving.shard_batch_array(mask, self.mesh)
         return padded, mask, prompt_len
+
+    def _upload_pixels(self, pixels: list, rids: list, pad_to: int = 0):
+        """The requests' pixel frames, host to device, as one
+        (max(len(pixels), pad_to), frames, 3, H, W) batch in the compute
+        dtype (zero rows pad), batch-sharded under a mesh."""
+        n = len(pixels)
+        with obs_trace.span(
+                "upload", "admit", n=n,
+                bytes=sum(int(getattr(px, "nbytes", 0)) for px in pixels),
+                **({"rid": rids[0]} if n == 1 else {"rids": rids})):
+            if n == 1:
+                pv = jnp.asarray(pixels[0], self._dtype)[None]
+            else:
+                pv = jnp.stack([jnp.asarray(px, self._dtype)
+                                for px in pixels])
+            if pad_to > n:
+                pv = jnp.concatenate(
+                    [pv, jnp.zeros((pad_to - n,) + pv.shape[1:],
+                                   self._dtype)])
+            if self.mesh is not None:
+                pv = self._serving.shard_batch_array(pv, self.mesh)
+        return pv
 
     def _new_row_cache(self, s1: int):
         row_cache = llama_mod.init_kv_cache(
@@ -5299,27 +5347,30 @@ class ContinuousBatcher:
         last_idx = jnp.asarray(
             max(0, min(p.prompt_len - 1 - start, c - 1)), jnp.int32
         )
-        if self.mesh is not None:
-            from jax.sharding import PartitionSpec as P
+        with obs_trace.span("prefill", "admit", n=1, positions=end - start,
+                            rid=p.req.rid):
+            if self.mesh is not None:
+                from jax.sharding import PartitionSpec as P
 
-            row_sh = jax.tree_util.tree_map(
-                lambda x: x.sharding, p.row_cache
-            )
-            flat, treedef = jax.tree_util.tree_flatten(row_sh)
-            hidden_sh = jax.sharding.NamedSharding(self.mesh, P(None, None))
-            fn = _get_sharded_chunk_prefill(
-                self.cfg, c, tuple(flat), treedef, self._row_logits_sh,
-                hidden_sh,
-            )
-            last, last_hidden, p.row_cache = fn(
-                self.params, p.embeds, p.row_cache, start_arr, new_len,
-                last_idx,
-            )
-        else:
-            last, last_hidden, p.row_cache = _chunk_prefill_jit(
-                self.params, self.cfg, p.embeds, p.row_cache,
-                start_arr, new_len, last_idx, c,
-            )
+                row_sh = jax.tree_util.tree_map(
+                    lambda x: x.sharding, p.row_cache
+                )
+                flat, treedef = jax.tree_util.tree_flatten(row_sh)
+                hidden_sh = jax.sharding.NamedSharding(
+                    self.mesh, P(None, None))
+                fn = _get_sharded_chunk_prefill(
+                    self.cfg, c, tuple(flat), treedef, self._row_logits_sh,
+                    hidden_sh,
+                )
+                last, last_hidden, p.row_cache = fn(
+                    self.params, p.embeds, p.row_cache, start_arr, new_len,
+                    last_idx,
+                )
+            else:
+                last, last_hidden, p.row_cache = _chunk_prefill_jit(
+                    self.params, self.cfg, p.embeds, p.row_cache,
+                    start_arr, new_len, last_idx, c,
+                )
         obs_metrics.SERVE_PREFILL_DISPATCHES.inc(kind="chunk")
         p.filled = end
         p.last_logits = last
@@ -5354,46 +5405,47 @@ class ContinuousBatcher:
 
         n = len(wave)
         nb = 1 << (n - 1).bit_length()
-        pv = jnp.stack([jnp.asarray(req.pixel_values, self._dtype)
-                        for req, _ in wave])
-        if nb > n:
-            pv = jnp.concatenate(
-                [pv, jnp.zeros((nb - n,) + pv.shape[1:], self._dtype)])
-        if self.mesh is not None:
-            pv = self._serving.shard_batch_array(pv, self.mesh)
-        ev = eventchat.encode_events_batch(self.params, self.cfg, pv)
-        embeds = [splice_embeddings(self.params, self.cfg,
-                                    split_at_event(req.input_ids), ev[i])
-                  for i, (req, _) in enumerate(wave)]
-        padded, mask, lens = _pad_batch(embeds)
-        prompt_lens = [int(x) for x in lens]
-        grain = 2 * SEQ_BUCKET
-        s1 = min(((max(prompt_lens) + grain - 1) // grain) * grain,
-                 self.max_len)
-        padded = jnp.pad(
-            padded, ((0, nb - n), (0, s1 - padded.shape[1]), (0, 0)))
-        mask = jnp.pad(mask, ((0, nb - n), (0, s1 - mask.shape[1])))
-        if nb > n:
-            # Pad rows keep ONE real position: their (dropped) garbage KV
-            # stays finite instead of feeding an all-masked softmax.
-            mask = mask.at[n:, 0].set(True)
-        wave_cache = llama_mod.init_kv_cache(
-            self.cfg.llama, nb, s1, dtype=self._dtype, quant=self.kv_quant)
+        rids = [req.rid for req, _ in wave]
+        pv = self._upload_pixels([req.pixel_values for req, _ in wave], rids,
+                                 pad_to=nb)
+        with obs_trace.span("encode", "admit", n=n, rids=rids):
+            ev = eventchat.encode_events_batch(self.params, self.cfg, pv)
+            embeds = [splice_embeddings(self.params, self.cfg,
+                                        split_at_event(req.input_ids), ev[i])
+                      for i, (req, _) in enumerate(wave)]
+            padded, mask, lens = _pad_batch(embeds)
+            prompt_lens = [int(x) for x in lens]
+            grain = 2 * SEQ_BUCKET
+            s1 = min(((max(prompt_lens) + grain - 1) // grain) * grain,
+                     self.max_len)
+            padded = jnp.pad(
+                padded, ((0, nb - n), (0, s1 - padded.shape[1]), (0, 0)))
+            mask = jnp.pad(mask, ((0, nb - n), (0, s1 - mask.shape[1])))
+            if nb > n:
+                # Pad rows keep ONE real position: their (dropped) garbage
+                # KV stays finite instead of feeding an all-masked softmax.
+                mask = mask.at[n:, 0].set(True)
+            if self.mesh is not None:
+                padded = self._serving.shard_batch_array(padded, self.mesh)
+                mask = self._serving.shard_batch_array(mask, self.mesh)
         want_hidden = self.draft_head is not None
-        if self.mesh is not None:
-            padded = self._serving.shard_batch_array(padded, self.mesh)
-            mask = self._serving.shard_batch_array(mask, self.mesh)
-            wave_cache = self._serving.shard_kv_cache(
-                wave_cache, self.cfg.llama, self.mesh)
-            pre = _prefill_sharded(
-                self.params, self.cfg, padded, mask, wave_cache, self.mesh,
-                return_hidden=want_hidden,
-            )
-        else:
-            pre = _prefill_jit(
-                self.params, self.cfg, padded, mask, wave_cache, True,
-                return_hidden=want_hidden,
-            )
+        with obs_trace.span("prefill", "admit", n=n, positions=s1,
+                            rids=rids):
+            wave_cache = llama_mod.init_kv_cache(
+                self.cfg.llama, nb, s1, dtype=self._dtype,
+                quant=self.kv_quant)
+            if self.mesh is not None:
+                wave_cache = self._serving.shard_kv_cache(
+                    wave_cache, self.cfg.llama, self.mesh)
+                pre = _prefill_sharded(
+                    self.params, self.cfg, padded, mask, wave_cache,
+                    self.mesh, return_hidden=want_hidden,
+                )
+            else:
+                pre = _prefill_jit(
+                    self.params, self.cfg, padded, mask, wave_cache, True,
+                    return_hidden=want_hidden,
+                )
         obs_metrics.SERVE_PREFILL_DISPATCHES.inc(kind="wave")
         if want_hidden:
             wave_logits, wave_hidden, wave_cache = pre
@@ -5413,80 +5465,82 @@ class ContinuousBatcher:
         activation. ``members`` are (req, row) pairs; quarantined and
         pow2-pad slots keep row index ``max_batch`` (dropped by the
         scatter's out-of-bounds rule)."""
-        n = len(members)
-        nb = (wave_cache["k"]["q"] if isinstance(wave_cache["k"], dict)
-              else wave_cache["k"]).shape[1]
-        rows = np.full((nb,), self.max_batch, np.int32)  # OOB = dropped
-        good = []
-        finite = None
-        if self.nan_check:
-            finite = np.isfinite(
-                np.asarray(jax.device_get(wave_logits))[:n]).all(axis=-1)
-        for i, (req, row) in enumerate(members):
-            if finite is not None and not finite[i]:
-                # Same per-request quarantine as the batch-1 path: the
-                # poisoned member never touches the shared cache (its
-                # wave slot scatters out of bounds); siblings admit.
-                self.rows[row] = None
-                self.frozen[row] = True
-                self._finish_forced(req, STATUS_NAN)
-                continue
-            self._insert_prefix_on_prefill(req, wave_cache, src_row=i)
-            rows[i] = row
-            good.append((i, req, row))
-        rows_arr = jnp.asarray(rows)
-        if self._paged:
-            wk = wave_cache["k"]
-            s1 = (wk["q"] if isinstance(wk, dict) else wk).shape[2]
-            oob = self._pool.n_blocks
-            n_src = s1 // self._kv_block_size
-            dst = np.full((nb, n_src), oob, np.int32)
-            bt_rows = np.full((nb, self._nbpr),
-                              serve_blocks.SCRATCH_BLOCK, np.int32)
+        with obs_trace.span("scatter", "admit", n=len(members),
+                            rids=[req.rid for req, _ in members]):
+            n = len(members)
+            nb = (wave_cache["k"]["q"] if isinstance(wave_cache["k"], dict)
+                  else wave_cache["k"]).shape[1]
+            rows = np.full((nb,), self.max_batch, np.int32)  # OOB = dropped
+            good = []
+            finite = None
+            if self.nan_check:
+                finite = np.isfinite(
+                    np.asarray(jax.device_get(wave_logits))[:n]).all(axis=-1)
+            for i, (req, row) in enumerate(members):
+                if finite is not None and not finite[i]:
+                    # Same per-request quarantine as the batch-1 path: the
+                    # poisoned member never touches the shared cache (its
+                    # wave slot scatters out of bounds); siblings admit.
+                    self.rows[row] = None
+                    self.frozen[row] = True
+                    self._finish_forced(req, STATUS_NAN)
+                    continue
+                self._insert_prefix_on_prefill(req, wave_cache, src_row=i)
+                rows[i] = row
+                good.append((i, req, row))
+            rows_arr = jnp.asarray(rows)
+            if self._paged:
+                wk = wave_cache["k"]
+                s1 = (wk["q"] if isinstance(wk, dict) else wk).shape[2]
+                oob = self._pool.n_blocks
+                n_src = s1 // self._kv_block_size
+                dst = np.full((nb, n_src), oob, np.int32)
+                bt_rows = np.full((nb, self._nbpr),
+                                  serve_blocks.SCRATCH_BLOCK, np.int32)
+                for i, req, row in good:
+                    # Quarantined/pad slots keep all-OOB rows: their wave KV
+                    # never touches the pool (their reservations were freed
+                    # by _record_finish before this scatter was built).
+                    dst[i] = self._paged_dst_blocks(req, s1)
+                    bt_rows[i] = self._paged_bt_row(req)
+                    req.kv_bt_written = True
+                dst_arr, bt_arr = jnp.asarray(dst), jnp.asarray(bt_rows)
+                if self.mesh is not None:
+                    rows_arr = self._serving.replicate(rows_arr, self.mesh)
+                    dst_arr = self._serving.replicate(dst_arr, self.mesh)
+                    bt_arr = self._serving.replicate(bt_arr, self.mesh)
+                    admit = _get_sharded_admit_wave_paged(
+                        self._cache_flat_sh, self._cache_treedef,
+                        self._logits_sh
+                    )
+                else:
+                    admit = _admit_wave_paged_jit
+                self.cache, self.logits = admit(
+                    self.cache, self.logits, rows_arr, dst_arr, bt_arr,
+                    wave_cache["k"], wave_cache["v"], wave_cache["length"],
+                    wave_logits,
+                )
+            else:
+                if self.mesh is not None:
+                    rows_arr = self._serving.replicate(rows_arr, self.mesh)
+                    admit = _get_sharded_admit_wave(
+                        self._cache_flat_sh, self._cache_treedef,
+                        self._logits_sh
+                    )
+                else:
+                    admit = _admit_wave_jit
+                self.cache, self.logits = admit(
+                    self.cache, self.logits, rows_arr, wave_cache["k"],
+                    wave_cache["v"], wave_cache["length"], wave_logits,
+                )
             for i, req, row in good:
-                # Quarantined/pad slots keep all-OOB rows: their wave KV
-                # never touches the pool (their reservations were freed
-                # by _record_finish before this scatter was built).
-                dst[i] = self._paged_dst_blocks(req, s1)
-                bt_rows[i] = self._paged_bt_row(req)
-                req.kv_bt_written = True
-            dst_arr, bt_arr = jnp.asarray(dst), jnp.asarray(bt_rows)
-            if self.mesh is not None:
-                rows_arr = self._serving.replicate(rows_arr, self.mesh)
-                dst_arr = self._serving.replicate(dst_arr, self.mesh)
-                bt_arr = self._serving.replicate(bt_arr, self.mesh)
-                admit = _get_sharded_admit_wave_paged(
-                    self._cache_flat_sh, self._cache_treedef,
-                    self._logits_sh
-                )
-            else:
-                admit = _admit_wave_paged_jit
-            self.cache, self.logits = admit(
-                self.cache, self.logits, rows_arr, dst_arr, bt_arr,
-                wave_cache["k"], wave_cache["v"], wave_cache["length"],
-                wave_logits,
-            )
-        else:
-            if self.mesh is not None:
-                rows_arr = self._serving.replicate(rows_arr, self.mesh)
-                admit = _get_sharded_admit_wave(
-                    self._cache_flat_sh, self._cache_treedef,
-                    self._logits_sh
-                )
-            else:
-                admit = _admit_wave_jit
-            self.cache, self.logits = admit(
-                self.cache, self.logits, rows_arr, wave_cache["k"],
-                wave_cache["v"], wave_cache["length"], wave_logits,
-            )
-        for i, req, row in good:
-            row_hidden = (wave_hidden[i:i + 1]
-                          if wave_hidden is not None else None)
-            obs_journey.event(self._journey_owner, req.rid, "admit",
-                              path=path, row=row)
-            self._activate_row(req, row, prompt_lens[i],
-                               wave_logits[i:i + 1], row_hidden,
-                               entries[i] if entries is not None else None)
+                row_hidden = (wave_hidden[i:i + 1]
+                              if wave_hidden is not None else None)
+                obs_journey.event(self._journey_owner, req.rid, "admit",
+                                  path=path, row=row)
+                self._activate_row(req, row, prompt_lens[i],
+                                   wave_logits[i:i + 1], row_hidden,
+                                   entries[i] if entries is not None else None)
 
     def _insert_prefix_on_prefill(self, req, row_cache,
                                   src_row: int = 0) -> None:
@@ -5569,50 +5623,51 @@ class ContinuousBatcher:
                           row_logits, row_hidden=None,
                           prefix_entry=None, path: str = "full") -> None:
         """Insert the prefilled row into the shared cache + activate it."""
-        if self.nan_check and not bool(
-                np.isfinite(np.asarray(jax.device_get(row_logits))).all()):
-            # Prefill produced non-finite logits: quarantine the REQUEST
-            # before it touches the shared cache (the speculative path's
-            # only NaN gate — it commits the prefill sample at admission
-            # and carries no per-segment logits to check).
-            self.rows[row] = None
-            self.frozen[row] = True
-            self._finish_forced(req, STATUS_NAN)
-            return
-        self._insert_prefix_on_prefill(req, row_cache)
-        if self._paged:
-            rk = row_cache["k"]
-            s1 = (rk["q"] if isinstance(rk, dict) else rk).shape[2]
-            dst = jnp.asarray(self._paged_dst_blocks(req, s1))
-            btr = jnp.asarray(self._paged_bt_row(req))
-            if self.mesh is not None:
-                dst = self._serving.replicate(dst, self.mesh)
-                btr = self._serving.replicate(btr, self.mesh)
-                admit = _get_sharded_admit_paged(
-                    self._cache_flat_sh, self._cache_treedef,
-                    self._logits_sh)
-            else:
-                admit = _admit_row_paged_jit
-            self.cache, self.logits = admit(
-                self.cache, self.logits, row, dst, btr, row_cache,
-                row_logits
-            )
-            req.kv_bt_written = True
-        else:
-            if self.mesh is not None:
-                admit = _get_sharded_admit(
-                    self._cache_flat_sh, self._cache_treedef,
-                    self._logits_sh
+        with obs_trace.span("scatter", "admit", n=1, rid=req.rid):
+            if self.nan_check and not bool(
+                    np.isfinite(np.asarray(jax.device_get(row_logits))).all()):
+                # Prefill produced non-finite logits: quarantine the REQUEST
+                # before it touches the shared cache (the speculative path's
+                # only NaN gate — it commits the prefill sample at admission
+                # and carries no per-segment logits to check).
+                self.rows[row] = None
+                self.frozen[row] = True
+                self._finish_forced(req, STATUS_NAN)
+                return
+            self._insert_prefix_on_prefill(req, row_cache)
+            if self._paged:
+                rk = row_cache["k"]
+                s1 = (rk["q"] if isinstance(rk, dict) else rk).shape[2]
+                dst = jnp.asarray(self._paged_dst_blocks(req, s1))
+                btr = jnp.asarray(self._paged_bt_row(req))
+                if self.mesh is not None:
+                    dst = self._serving.replicate(dst, self.mesh)
+                    btr = self._serving.replicate(btr, self.mesh)
+                    admit = _get_sharded_admit_paged(
+                        self._cache_flat_sh, self._cache_treedef,
+                        self._logits_sh)
+                else:
+                    admit = _admit_row_paged_jit
+                self.cache, self.logits = admit(
+                    self.cache, self.logits, row, dst, btr, row_cache,
+                    row_logits
                 )
+                req.kv_bt_written = True
             else:
-                admit = _admit_row_jit
-            self.cache, self.logits = admit(
-                self.cache, self.logits, row, row_cache, row_logits
-            )
-        obs_journey.event(self._journey_owner, req.rid, "admit",
-                          path=path, row=row)
-        self._activate_row(req, row, prompt_len, row_logits, row_hidden,
-                           prefix_entry)
+                if self.mesh is not None:
+                    admit = _get_sharded_admit(
+                        self._cache_flat_sh, self._cache_treedef,
+                        self._logits_sh
+                    )
+                else:
+                    admit = _admit_row_jit
+                self.cache, self.logits = admit(
+                    self.cache, self.logits, row, row_cache, row_logits
+                )
+            obs_journey.event(self._journey_owner, req.rid, "admit",
+                              path=path, row=row)
+            self._activate_row(req, row, prompt_len, row_logits, row_hidden,
+                               prefix_entry)
 
     def _activate_row(self, req, row, prompt_len, row_logits,
                       row_hidden=None, prefix_entry=None) -> None:

@@ -33,7 +33,6 @@ import optax
 from eventgpt_tpu.config import EventChatConfig
 from eventgpt_tpu.constants import IGNORE_INDEX
 from eventgpt_tpu.models import eventchat, llama as llama_mod
-from eventgpt_tpu.obs import profiling as obs_profiling
 from eventgpt_tpu.obs import trace as obs_trace
 from eventgpt_tpu.train.lora import LoraConfig, apply_lora
 
@@ -289,12 +288,11 @@ def split_stage2(
 def batch_to_device(batch: Dict[str, Any], mesh=None) -> Batch:
     """Host batch -> device, sharded over (data, fsdp) when a mesh is given.
 
-    Wrapped in a telemetry span + profiler annotation (both no-ops when
-    disarmed): the host-to-device transfer is the second half of the
-    trainer's data-wait split, and naming it on a profile separates it
-    from genuine device compute."""
-    with obs_trace.span("batch_to_device", cat="train"), \
-            obs_profiling.annotation("batch_to_device"):
+    Wrapped in a telemetry span (a no-op when disarmed; a profiler
+    annotation too while the profiler is armed): the host-to-device
+    transfer is the second half of the trainer's data-wait split, and
+    naming it on a profile separates it from genuine device compute."""
+    with obs_trace.span("batch_to_device", "train"):
         return _batch_to_device(batch, mesh)
 
 

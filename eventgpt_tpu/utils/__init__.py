@@ -1,3 +1,1 @@
-"""Utilities: profiling, structured metrics."""
-
-from eventgpt_tpu.utils.profiling import profile_trace, timed  # noqa: F401
+"""Utilities: platform, paths, compile cache, structured metrics."""

@@ -397,6 +397,7 @@ def test_http_request_requests_trace_and_debug_block(tiny, tmp_path):
         assert tr["traceEvents"], "rid filter dropped everything"
         assert all(e.get("id") == rid
                    or (e.get("args") or {}).get("rid") == rid
+                   or rid in (e.get("args") or {}).get("rids", ())
                    for e in tr["traceEvents"])
         full = _get(url + "/trace")
         assert len(full["traceEvents"]) > len(tr["traceEvents"])

@@ -205,8 +205,9 @@ def test_profile_capture_smoke(tmp_path):
     assert not obs_profiling.armed()
     obs_profiling.configure(d)
     assert obs_profiling.armed()
+    obs_trace.configure(16)
     with obs_profiling.step_annotation(3):
-        with obs_profiling.annotation("unit"):
+        with obs_trace.span("unit", "test"):  # holds its TraceAnnotation
             pass
     obs_profiling.configure(None)
     assert not obs_profiling.armed()
