@@ -195,7 +195,11 @@ def _project_qkv(cfg: LlamaConfig, y: jnp.ndarray, layer: Params):
 
 
 def _repeat_kv(x: jnp.ndarray, n_rep: int) -> jnp.ndarray:
-    """(B, S, KV, hd) -> (B, S, KV*n_rep, hd), GQA head replication."""
+    """(B, S, KV, hd) -> (B, S, KV*n_rep, hd), GQA head replication (head
+    ``g * n_rep + r`` reads KV head ``g``). Only for callees that take
+    repeated heads: the flash kernel, the serving-mesh flash shard_map and
+    the ring in ``_attn_block``, and Ulysses after its all-to-all
+    (``parallel/ulysses.py``). Dense attention never repeats."""
     if n_rep == 1:
         return x
     b, s, kv, hd = x.shape
@@ -211,16 +215,19 @@ def _attn_block(cfg: LlamaConfig, q_proj: jnp.ndarray, layer: Params,
                 ring_fn=None,
                 flash_fn=None,
                 scope: str = "attn") -> jnp.ndarray:
-    """Shared attention plumbing (RoPE on the precomputed q projection + GQA
-    repeat + o proj) with a score-computation switch: dense additive ``mask``
+    """Shared attention plumbing (RoPE on the precomputed q projection + o
+    proj) with a score-computation switch: dense additive ``mask``
     (B,1,Q,S), the Pallas flash kernel with a (B,S) ``valid`` padding mask
     (causal implied), a ring-attention shard_map ``ring_fn`` for sequence
     parallelism over the ``context`` mesh axis, or a serving-mesh flash
     shard_map ``flash_fn`` (``parallel/serving.py:serving_flash_shard_map``).
     q_proj: (B,Q,H*hd) from ``_project_qkv`` (possibly a fused-qkv slice);
-    k/v_full: (B,S,KV,hd). ``scope`` names the GQA repeat, scores, softmax
-    and value product on a device trace (``prefill_attn`` /
-    ``decode_attn``; metadata only)."""
+    k/v_full: (B,S,KV,hd). The dense branch (every decode step, and prefill
+    under ``attn_impl="dense"``) groups the query's heads per KV head and
+    reads K / V as they are; the kernels and the ring take K / V repeated to
+    H heads (``_repeat_kv``). ``scope`` names scores, softmax and value
+    product (and a kernel's GQA repeat) on a device trace (``prefill_attn``
+    / ``decode_attn``; metadata only)."""
     b, q_len, _ = q_proj.shape
     h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim()
 
@@ -231,27 +238,30 @@ def _attn_block(cfg: LlamaConfig, q_proj: jnp.ndarray, layer: Params,
         # moves KV-count bytes, not H-count (ADVICE r2).
         ctx = ring_fn(q, k_full, v_full, valid, valid).reshape(b, q_len, h * hd)
         return _mm(ctx, layer["attn"]["o"])
+    rep = h // kvh
     with jax.named_scope(scope):
-        k = _repeat_kv(k_full, h // kvh)
-        v = _repeat_kv(v_full, h // kvh)
+        if ring_fn is not None or flash_fn is not None or use_flash:
+            k, v = _repeat_kv(k_full, rep), _repeat_kv(v_full, rep)
 
         if ring_fn is not None:
-            ctx = ring_fn(q, k, v, valid, valid).reshape(b, q_len, h * hd)
+            ctx = ring_fn(q, k, v, valid, valid)
         elif flash_fn is not None:
-            ctx = flash_fn(q, k, v, valid).reshape(b, q_len, h * hd)
+            ctx = flash_fn(q, k, v, valid)
         elif use_flash:
             from eventgpt_tpu.ops.flash_attention import flash_attention
 
             ctx = flash_attention(q, k, v, valid=valid, causal=True)
-            ctx = ctx.reshape(b, q_len, h * hd)
         else:
-            scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+            # Queries regrouped per KV head (h = g * rep + r, _repeat_kv's
+            # order) and contracted against K / V as stored: M = rep * Q
+            # rows per (b, g) product, no repeated and no f32 copy of K / V.
+            qg = q.reshape(b, q_len, kvh, rep, hd)
+            scores = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k_full,
                                 preferred_element_type=jnp.float32)
-            scores = scores * (1.0 / math.sqrt(hd)) + mask
+            scores = scores * (1.0 / math.sqrt(hd)) + mask[:, :, None]
             probs = jax.nn.softmax(scores, axis=-1).astype(q_proj.dtype)
-            ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v).reshape(
-                b, q_len, h * hd)
-    return _mm(ctx, layer["attn"]["o"])
+            ctx = jnp.einsum("bgrqk,bkgd->bqgrd", probs, v_full)
+    return _mm(ctx.reshape(b, q_len, h * hd), layer["attn"]["o"])
 
 
 def _mlp_block(x: jnp.ndarray, layer: Params) -> jnp.ndarray:
@@ -389,10 +399,10 @@ def _cache_read_layer(buf, li, dtype, quant: bool, bt=None):
     dense path reads (S = blocks_per_row × block_size), so the attention
     math downstream is untouched and bitwise identical (a gather is a
     copy). The view is a per-layer TEMPORARY — 1/L of the dense cache's
-    residency — not a resident buffer; the paged Pallas kernel
-    (``ops/decode_attention.decode_attention_int8_paged``) computes
-    attention block-by-block without materializing it at all, and is the
-    TPU wiring for this seam."""
+    residency — not a resident buffer. This gather is the only paged read
+    the program has: the Pallas kernels of ``ops/decode_attention.py``
+    (int8 caches only) would compute attention block by block without the
+    view, but nothing calls them (ROADMAP D6)."""
     if bt is not None:
         b, nbpr = bt.shape
         if quant:

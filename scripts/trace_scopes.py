@@ -18,9 +18,12 @@ before PR 25). Read scopes off a capture whose programs this build compiled.
 
 Usage:
   python scripts/trace_scopes.py <file.xplane.pb | profile dir> [--top 40]
+      [--ops <regex of program names>]
 
 prints, for the first device plane, milliseconds of self time (an operation's
-time less the operations nested in it) by program and scope.
+time less the operations nested in it) by program and scope; with ``--ops``,
+by operation of the programs the regex matches (the operation list that shows
+whether a tensor the size of a cache layer is still written).
 """
 
 from __future__ import annotations
@@ -182,10 +185,12 @@ def device_plane(raw: bytes) -> Optional[dict]:
     return out
 
 
-def seconds_by_scope(path: str) -> Dict[Tuple[str, str], float]:
-    """(program, scope) -> seconds of device self time. The program is the
-    XLA module's name without its fingerprint; the scope is the first part of
-    the operation's ``tf_op`` that is one of ``SCOPES``, or ``-``."""
+def seconds_by_scope(path: str, by_op: bool = False
+                     ) -> Dict[Tuple[str, ...], float]:
+    """(program, scope) -> seconds of device self time; with ``by_op``,
+    (program, scope, operation). The program is the XLA module's name
+    without its fingerprint; the scope is the first part of the operation's
+    ``tf_op`` that is one of ``SCOPES``, or ``-``."""
     with open(path, "rb") as f:
         dev = device_plane(f.read())
     if dev is None or OPS_LINE not in dev:
@@ -196,12 +201,14 @@ def seconds_by_scope(path: str) -> Dict[Tuple[str, str], float]:
         if m:
             programs[int(m.group(2))] = m.group(1)
     rows = dev[OPS_LINE]
-    out: Dict[Tuple[str, str], float] = collections.defaultdict(float)
+    out: Dict[Tuple[str, ...], float] = collections.defaultdict(float)
     for (mid, _, _), ns in zip(rows, self_ns(rows[:, 1:3])):
-        stats = dev["meta"].get(int(mid), {}).get("stats", {})
+        rec = dev["meta"].get(int(mid), {})
+        stats = rec.get("stats", {})
         parts = (stats.get("tf_op") or "").split("/")
         scope = next((p for p in parts if p in SCOPES), "-")
-        out[(programs.get(stats.get("program_id"), "?"), scope)] += ns / 1e9
+        key = (programs.get(stats.get("program_id"), "?"), scope)
+        out[key + (rec.get("name", ""),) if by_op else key] += ns / 1e9
     return dict(out)
 
 
@@ -209,6 +216,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("path")
     ap.add_argument("--top", type=int, default=40)
+    ap.add_argument("--ops", default="",
+                    help="list operations of the programs this regex matches")
     args = ap.parse_args(argv)
     path = args.path
     if os.path.isdir(path):
@@ -218,6 +227,14 @@ def main(argv=None) -> int:
             sys.stderr.write(f"no .xplane.pb under {path}\n")
             return 2
         path = found[-1]
+    if args.ops:
+        table = {k: s for k, s in seconds_by_scope(path, by_op=True).items()
+                 if re.search(args.ops, k[0])}
+        print(f"{path}: device self time by operation of /{args.ops}/")
+        for (_, scope, op), s in sorted(
+                table.items(), key=lambda kv: -kv[1])[:args.top]:
+            print(f"  {s * 1e3:11.3f} ms  {scope:13s} {op[:150]}")
+        return 0
     table = seconds_by_scope(path)
     by_program = collections.defaultdict(float)
     for (prog, _), s in table.items():

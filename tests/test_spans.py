@@ -489,19 +489,25 @@ def test_a_prefix_hit_records_its_lookup_and_copy_with_the_request():
 # -- names on the device ----------------------------------------------------------------
 
 
-def test_scopes_are_read_off_a_recorded_tpu_trace():
-    """``scripts/trace_scopes.py`` on the small trace recorded on a TPU v5
-    lite for the benchmark's tests: the scope path is the metadata's
-    ``tf_op``, a fusion carries its root's, and self times by scope sum to
-    the programs' device time."""
+def _trace_scopes_on_recorded_trace():
+    """``scripts/trace_scopes.py`` as a module, and the small trace recorded
+    on a TPU v5 lite for the benchmark's tests."""
     import importlib.util
 
     spec = importlib.util.spec_from_file_location(
         "trace_scopes", os.path.join(ROOT, "scripts", "trace_scopes.py"))
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    path = os.path.join(ROOT, "tests", "benchmark_suite", "data",
-                        "small_spans_tpu.xplane.pb")
+    return mod, os.path.join(ROOT, "tests", "benchmark_suite", "data",
+                             "small_spans_tpu.xplane.pb")
+
+
+def test_scopes_are_read_off_a_recorded_tpu_trace():
+    """``scripts/trace_scopes.py`` on the small trace recorded on a TPU v5
+    lite for the benchmark's tests: the scope path is the metadata's
+    ``tf_op``, a fusion carries its root's, and self times by scope sum to
+    the programs' device time."""
+    mod, path = _trace_scopes_on_recorded_trace()
     with open(path, "rb") as f:
         dev = mod.device_plane(f.read())
     paths = {r["stats"].get("tf_op") for r in dev["meta"].values()}
@@ -519,3 +525,20 @@ def test_scopes_are_read_off_a_recorded_tpu_trace():
     assert sum(table.values()) * 1e9 == pytest.approx(
         float(mod.self_ns(rows[:, 1:3]).sum()))
     assert len(merged) == len(rows) > 20
+
+
+def test_scopes_list_a_programs_operations(capsys):
+    """``--ops``: the same self times, by operation of the programs a regex
+    matches; they sum to the programs' time by scope."""
+    mod, path = _trace_scopes_on_recorded_trace()
+    by_scope = mod.seconds_by_scope(path)
+    by_op = mod.seconds_by_scope(path, by_op=True)
+    assert len(by_op) > len(by_scope)
+    for key, secs in by_scope.items():
+        assert secs == pytest.approx(
+            sum(s for k, s in by_op.items() if k[:2] == key))
+    assert mod.main([path, "--ops", "decode_segment", "--top", "5"]) == 0
+    lines = capsys.readouterr().out.splitlines()
+    assert "decode_segment" in lines[0] and len(lines) == 6
+    assert all(" ms " in ln for ln in lines[1:])
+    assert any("decode_attn" in ln for ln in lines[1:])
