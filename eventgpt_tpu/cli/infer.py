@@ -171,6 +171,28 @@ def place_params(tree, jdt):
     return jnp.asarray(tree, jdt)
 
 
+def _fuse_and_quantize(llama, args):
+    """The dense decoder's subtree as ``--fuse_params`` / ``--quant`` ask.
+    The full-width synthetic tree (models/synthetic.py) arrives fused /
+    quantized already; the tree itself says so."""
+    fused = "qkv" in llama["layers"]["attn"]
+    quantized = isinstance(llama["lm_head"], dict)
+    if getattr(args, "fuse_params", False) and not fused:
+        from eventgpt_tpu.models.llama import fuse_llama_params
+
+        # Fuse BEFORE quantization so scales are computed on (and stream
+        # with) the fused tensors (models/llama.py:fuse_llama_params).
+        llama = fuse_llama_params(llama)
+    if args.quant in ("int8", "int4") and not quantized:
+        from eventgpt_tpu.ops.quant import quantize_llama_params
+
+        llama = quantize_llama_params(
+            jax.tree_util.tree_map(np.asarray, llama), host=True,
+            bits=4 if args.quant == "int4" else 8,
+        )
+    return llama
+
+
 def prepare_model(cfg, params, tokenizer, args, mesh=None):
     """Shared post-load preparation for the infer/eval CLIs: optional
     spatio-temporal / Q-Former config gating, special-token registration
@@ -247,23 +269,15 @@ def prepare_model(cfg, params, tokenizer, args, mesh=None):
         )
     if len(tokenizer) > cfg.llama.vocab_size:
         params["llama"] = resize_token_embeddings(params["llama"], len(tokenizer))
-    # The full-width synthetic tree (models/synthetic.py) arrives fused /
-    # quantized already; the tree itself says so.
-    fused = "qkv" in params["llama"]["layers"]["attn"]
-    quantized = isinstance(params["llama"]["lm_head"], dict)
-    if getattr(args, "fuse_params", False) and not fused:
-        from eventgpt_tpu.models.llama import fuse_llama_params
+    from eventgpt_tpu.models import eventchat, llama as llama_mod
 
-        # Fuse BEFORE quantization so scales are computed on (and stream
-        # with) the fused tensors (models/llama.py:fuse_llama_params).
-        params["llama"] = fuse_llama_params(params["llama"])
-    if args.quant in ("int8", "int4") and not quantized:
-        from eventgpt_tpu.ops.quant import quantize_llama_params
-
-        params["llama"] = quantize_llama_params(
-            jax.tree_util.tree_map(np.asarray, params["llama"]), host=True,
-            bits=4 if args.quant == "int4" else 8,
-        )
+    if eventchat.decoder_of(cfg) is not llama_mod:
+        # Fusing and quantization are the dense decoder's transforms.
+        eventchat.refuse_without_recurrent_state(**{
+            "--quant": args.quant != "none",
+            "--fuse_params": getattr(args, "fuse_params", False)})
+    else:
+        params["llama"] = _fuse_and_quantize(params["llama"], args)
     import jax.numpy as jnp
 
     jdt = jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32

@@ -50,6 +50,7 @@ from __future__ import annotations
 
 import argparse
 import base64
+import contextlib
 import itertools
 import json
 import math
@@ -120,6 +121,8 @@ class ServingEngine:
         "n_faults": "_lock/w",
         "n_restarts": "_lock/w",
         "n_requests": "_lock/w",
+        # threads asking for _lock in turn (a Condition the scheduler waits on)
+        "_asking": "_turn",
     }
 
     def __init__(self, batcher, tokenizer, conv_mode: str = "eventgpt_v1",
@@ -135,6 +138,15 @@ class ServingEngine:
         self.tokenizer = tokenizer
         self.conv_mode = conv_mode
         self._lock = threading.Lock()
+        # ``threading.Lock`` is not fair: the scheduler thread, which asks
+        # for ``_lock`` again right after releasing it, would get it back
+        # before a woken submitter runs, and submitters would get in only
+        # when the engine idles. They (and the fleet supervisor's calls:
+        # ``_in_turn``) count themselves here, the last one to leave
+        # notifies, and the scheduler thread waits for that between two
+        # steps (``_let_submitters_in``).
+        self._asking = 0
+        self._turn = threading.Condition()
         self._wake = threading.Event()
         self._stop = False
         self._done: Dict[int, threading.Event] = {}
@@ -217,7 +229,8 @@ class ServingEngine:
         prefix-affinity key)."""
         if self.breaker_open() or self._dead:
             raise RuntimeError(f"serving engine is down: {self.fault}")
-        with obs_trace.span("lock_wait", "engine") as wait, self._lock:
+        with self._in_turn(), \
+                obs_trace.span("lock_wait", "engine") as wait, self._lock:
             wait.close()  # the lock is held: what follows is its hold
             # Re-check under the lock: a breaker trip (or kill) while
             # the caller prepared the request has already swept _done —
@@ -298,7 +311,7 @@ class ServingEngine:
         supervisor's cue to fail it over) — else ``None`` (still
         running). Consuming: a delivered answer is popped, like
         ``result``."""
-        with self._lock:
+        with self._in_turn(), self._lock:
             if rid in self._answers:
                 self._done.pop(rid, None)
                 return self._answers.pop(rid), self._status.get(rid, "ok")
@@ -312,7 +325,7 @@ class ServingEngine:
         delivered through the stream queue (answers never reach
         ``_answers`` there), else None — the supervisor's stream-side
         counterpart of ``try_result``."""
-        with self._lock:
+        with self._in_turn(), self._lock:
             st = self._status.get(rid)
             if st is not None and rid not in self._streams:
                 return st
@@ -326,7 +339,7 @@ class ServingEngine:
         to survivors. The scheduler loop parks and submits are refused
         until ``revive()``. Engine-side waiter state for the exported
         rids is dropped: the fleet owns those clients now."""
-        with self._lock:
+        with self._in_turn(), self._lock:
             self._dead = True
             # Finished-but-uncollected answers are real results — hand
             # them to try_result instead of re-running them elsewhere.
@@ -573,11 +586,42 @@ class ServingEngine:
                         target=self._loop, daemon=True)
                     self._thread.start()
                 return
+            self._let_submitters_in()
             self._maybe_beat()
             if not busy:
                 with obs_trace.span("idle_wait", "engine"):
                     self._wake.wait(timeout=0.1)
                 self._wake.clear()
+
+    @contextlib.contextmanager
+    def _in_turn(self):
+        """Around ``with self._lock:`` on a thread that is not the
+        scheduler's: ``submit_ids``, and the fleet supervisor's
+        ``try_result``, ``try_status`` and ``kill`` (a supervisor that gets
+        in only when the engine idles kills no replica mid-decode). Counted
+        as asking from before it asks until it has let the lock go, so that
+        the scheduler thread lets it in between two steps; the last to
+        leave notifies."""
+        with self._turn:
+            self._asking += 1
+        try:
+            yield
+        finally:
+            with self._turn:
+                self._asking -= 1
+                if not self._asking:
+                    self._turn.notify_all()
+
+    def _let_submitters_in(self, at_most_s: float = 0.05) -> None:
+        """Between two holds of ``_lock`` by the scheduler thread: sleep,
+        off the lock, until every thread that is asking ``_in_turn`` has had
+        it (a ``submit_ids`` holds it for one ``batcher.submit``), so that
+        the next step admits what arrived during the last one. Returns at
+        once when none is asking. Bounded: arrivals that keep coming do not
+        hold the scheduler for more than ``at_most_s``."""
+        with self._turn:
+            if self._asking:  # notified only when the count reaches 0
+                self._turn.wait(timeout=at_most_s)
 
     def _step_locked(self) -> None:
         """One hold of the lock by the scheduler thread: the batcher's

@@ -134,6 +134,86 @@ class LlamaConfig:
 
 
 @dataclass(frozen=True)
+class HybridConfig:
+    """A decoder whose blocks are of several kinds (``models/nemotron_h.py``):
+    each block is ``x + mixer(rmsnorm(x))`` with exactly one mixer, chosen
+    by its character of ``pattern``: ``M`` a Mamba-2 state-space mixer,
+    ``E`` sparse experts in a latent space beside one shared expert, ``*``
+    GQA attention without positional embedding and without an MLP. Field
+    names follow the published ``nemotron_h`` ``config.json``.
+
+    ``n_routed_experts`` is the router's width (every expert of the
+    deployment); this process holds ``experts_held`` of them from
+    ``experts_offset`` on, computes their part of the result and drops what
+    the absent ones would add (expert parallelism without its exchange)."""
+
+    pattern: str = "ME*"
+    vocab_size: int = 32000
+    hidden_size: int = 4096
+    num_heads: int = 32
+    num_kv_heads: int = 2
+    head_dim: Optional[int] = 128
+    rms_norm_eps: float = 1e-5
+    max_seq_len: int = 4096
+    attn_impl: str = "dense"
+    # Mamba-2 mixer.
+    mamba_num_heads: int = 128
+    mamba_head_dim: int = 64
+    n_groups: int = 8
+    ssm_state_size: int = 128
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    # Latent sparse experts.
+    n_routed_experts: int = 512
+    experts_held: int = 512
+    experts_offset: int = 0
+    num_experts_per_tok: int = 22
+    moe_intermediate_size: int = 2688
+    moe_latent_size: int = 1024
+    moe_shared_expert_intermediate_size: int = 5376
+    routed_scaling_factor: float = 5.0
+    norm_topk_prob: bool = True
+
+    _KINDS = "ME*"
+
+    def __post_init__(self):
+        if not self.pattern or set(self.pattern) - set(self._KINDS):
+            raise ValueError(
+                f"pattern {self.pattern!r}: one of 'M', 'E', '*' a block")
+        if self.attn_impl not in ("dense", "flash"):
+            raise ValueError(
+                f"attn_impl must be 'dense' or 'flash', got {self.attn_impl!r}")
+        if not (0 <= self.experts_offset
+                and self.experts_offset + self.experts_held
+                <= self.n_routed_experts):
+            raise ValueError(
+                f"experts {self.experts_offset}..+{self.experts_held} are "
+                f"not among the router's {self.n_routed_experts}")
+        if self.mamba_num_heads % self.n_groups:
+            raise ValueError("mamba_num_heads must divide by n_groups")
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.pattern)
+
+    def count(self, kind: str) -> int:
+        return self.pattern.count(kind)
+
+    def resolved_head_dim(self) -> int:
+        return (self.head_dim if self.head_dim is not None
+                else self.hidden_size // self.num_heads)
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def conv_channels(self) -> int:
+        """x | B | C, the channels the causal convolution runs over."""
+        return self.mamba_inner + 2 * self.n_groups * self.ssm_state_size
+
+
+@dataclass(frozen=True)
 class ProjectorConfig:
     """Event-feature -> LM-embedding projection stack.
 
@@ -191,7 +271,9 @@ class EventChatConfig:
     """Top-level multimodal model config (EventChat_llama equivalent)."""
 
     vision: VisionConfig = field(default_factory=VisionConfig)
-    llama: LlamaConfig = field(default_factory=LlamaConfig)
+    # The decoder behind the tower: dense (LlamaConfig) or hybrid
+    # (HybridConfig); models/eventchat.decoder_of picks its module.
+    llama: Any = field(default_factory=LlamaConfig)
     projector: ProjectorConfig = field(default_factory=ProjectorConfig)
 
     # Event pipeline envelope (common/common.py:114,118).
@@ -267,7 +349,9 @@ def event_chat_config_from_dict(data: dict) -> EventChatConfig:
         if f.name not in data:
             continue
         v = data[f.name]
-        if f.name in _NESTED and isinstance(v, dict):
+        if f.name == "llama" and isinstance(v, dict) and "pattern" in v:
+            v = HybridConfig(**v)  # the decoder's kind, by what it states
+        elif f.name in _NESTED and isinstance(v, dict):
             v = _NESTED[f.name](**v)
         kwargs[f.name] = v
     return EventChatConfig(**kwargs)
@@ -299,9 +383,65 @@ def from_hf_config(hf: dict, attn_impl: Optional[str] = None) -> EventChatConfig
     ``event_feature_adaptor`` / ``mm_use_im_start_end`` / ``mm_use_im_patch_token``
     (``model/EventChatModel.py:75``, ``inference.py:33-34``).
     ``attn_impl=None`` resolves per platform (``default_attn_impl``).
+    ``model_type`` ``nemotron_h`` builds the hybrid decoder's configuration
+    (``hybrid_from_hf``); every other file a dense one.
     """
-    llama = LlamaConfig(
-        attn_impl=attn_impl if attn_impl is not None else default_attn_impl(),
+    attn_impl = attn_impl if attn_impl is not None else default_attn_impl()
+    if hf.get("model_type") == "nemotron_h":
+        llama = hybrid_from_hf(hf, attn_impl)
+    else:
+        llama = _dense_from_hf(hf, attn_impl)
+    return _behind_the_tower(hf, llama)
+
+
+def hybrid_from_hf(hf: dict, attn_impl: str) -> HybridConfig:
+    """The published ``nemotron_h`` keys -> ``HybridConfig``. Depth: the
+    first ``num_hidden_layers`` characters of ``hybrid_override_pattern``.
+    A file that holds a share of the experts gives the count it holds under
+    ``n_routed_experts`` and the router's width under
+    ``published.n_routed_experts`` (``experts_offset``: where the share
+    starts, 0 unless stated). The multi-token-prediction head
+    (``num_nextn_predict_layers``) drafts and is not on the next-token path:
+    it is not built."""
+    depth = int(hf["num_hidden_layers"])
+    pattern = str(hf["hybrid_override_pattern"])[:depth]
+    if len(pattern) != depth:
+        raise ValueError(
+            f"hybrid_override_pattern has {len(pattern)} blocks, "
+            f"num_hidden_layers asks for {depth}")
+    held = int(hf["n_routed_experts"])
+    width = int(hf.get("published", {}).get("n_routed_experts", held))
+    if int(hf.get("n_group", 1)) != 1 or int(hf.get("topk_group", 1)) != 1:
+        raise ValueError("group-limited routing (n_group, topk_group > 1) "
+                         "is not implemented")
+    if hf.get("mlp_hidden_act", "relu2") != "relu2":
+        raise ValueError(f"mlp_hidden_act {hf['mlp_hidden_act']!r}: only relu2")
+    return HybridConfig(
+        pattern=pattern, attn_impl=attn_impl,
+        vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"], head_dim=hf.get("head_dim"),
+        rms_norm_eps=hf.get("layer_norm_epsilon", 1e-5),
+        max_seq_len=min(hf.get("max_position_embeddings", 2048), 4096),
+        mamba_num_heads=hf["mamba_num_heads"],
+        mamba_head_dim=hf["mamba_head_dim"], n_groups=hf["n_groups"],
+        ssm_state_size=hf["ssm_state_size"], conv_kernel=hf["conv_kernel"],
+        chunk_size=hf["chunk_size"],
+        n_routed_experts=width, experts_held=held,
+        experts_offset=int(hf.get("experts_offset", 0)),
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        moe_latent_size=hf["moe_latent_size"],
+        moe_shared_expert_intermediate_size=hf[
+            "moe_shared_expert_intermediate_size"],
+        routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+        norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+    )
+
+
+def _dense_from_hf(hf: dict, attn_impl: str) -> LlamaConfig:
+    return LlamaConfig(
+        attn_impl=attn_impl,
         vocab_size=hf.get("vocab_size", 32000),
         hidden_size=hf.get("hidden_size", 4096),
         intermediate_size=hf.get("intermediate_size", 11008),
@@ -313,6 +453,9 @@ def from_hf_config(hf: dict, attn_impl: Optional[str] = None) -> EventChatConfig
         max_seq_len=min(hf.get("max_position_embeddings", 2048), 4096),
         tie_word_embeddings=hf.get("tie_word_embeddings", False),
     )
+
+
+def _behind_the_tower(hf: dict, llama) -> EventChatConfig:
     # The reference identifies its tower by name only (``mm_visual_tower`` ->
     # CLIP ViT-L/14-336, README.md:173-177); an explicit "vision_config" dict
     # (this framework's extension, written by its own config exports)

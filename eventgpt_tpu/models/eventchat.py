@@ -38,12 +38,69 @@ from eventgpt_tpu.ops.sampling import sample
 Params = Dict[str, Any]
 
 
+def decoder_of(cfg: EventChatConfig):
+    """The decoder's module, by the kind of ``cfg.llama``: the one place that
+    picks it. Both define ``init_params``, ``init_cache``, ``embed_tokens``,
+    ``prefill``, ``decode_step`` and ``forward`` under the same signatures;
+    the decoder's subtree of the parameters is ``params["llama"]`` either
+    way. What only the dense decoder has (``decode_kstep``, the paged and
+    int8 caches, fusing, quantization) is reached through ``llama_mod`` by
+    name, and ``ContinuousBatcher`` refuses the flags that need it for
+    another decoder."""
+    from eventgpt_tpu.config import HybridConfig
+
+    if isinstance(cfg.llama, HybridConfig):
+        from eventgpt_tpu.models import nemotron_h
+
+        return nemotron_h
+    return llama_mod
+
+
+def refuse_without_recurrent_state(**asked) -> None:
+    """Raise for the first option that is on, by its flag's name. A decoder
+    with recurrent layers keeps, beside keys and values by position, a
+    state a row that cannot be sliced at a position, rolled back by
+    ``length`` or shared between rows. The mechanisms below move, share or
+    roll back keys and values only, so each refuses such a decoder rather
+    than serve a stale state (ROADMAP.md, Queue 2). ``ContinuousBatcher``,
+    ``cli/infer.prepare_model`` and ``synthetic.served_shapes`` ask."""
+    why = {
+        "--kv_cache int8": "the int8 cache holds keys and values only",
+        "--kv_layout paged": "a block holds keys and values by position only",
+        "--speculative": "a rejected draft cannot be rolled back out of a "
+                         "recurrent state",
+        "--spec_buckets": "a rejected draft cannot be rolled back out of a "
+                          "recurrent state",
+        "--draft_head": "speculation is refused",
+        "--prefill_chunk": "chunked admission prefills through decode_kstep, "
+                           "which carries no recurrent state",
+        "--prefill_budget": "piggyback lanes prefill through decode_kstep, "
+                            "which carries no recurrent state (pass "
+                            "--prefill_budget 0)",
+        "--prefix_cache_mb": "a prefix entry holds keys and values and no "
+                             "snapshot of the recurrent state at its end "
+                             "(pass --no_prefix_cache)",
+        "--preempt": "a spill record holds block runs only",
+        "--role": "a handoff record holds block runs only",
+        "--mesh_model": "the decoder runs on one device (no expert axis in "
+                        "parallel/mesh.py; --mesh_data and --mesh_fsdp "
+                        "likewise)",
+        "--quant": "ops/quant is two-dimensional and does not take stacked "
+                   "experts",
+        "--fuse_params": "there is no q|k|v or gate|up to fuse",
+    }
+    for flag, on in asked.items():
+        if on:
+            raise ValueError(f"{flag} is refused for a decoder with "
+                             f"recurrent state: {why[flag]}")
+
+
 def init_eventchat_params(cfg: EventChatConfig, key: jax.Array, dtype=jnp.float32) -> Params:
     k1, k2, k3, k4 = jax.random.split(key, 4)
     params = {
         "clip": clip_mod.init_clip_params(cfg.vision, k1, dtype),
         "projector": proj_mod.init_projector_params(cfg.projector, k2, dtype),
-        "llama": llama_mod.init_llama_params(cfg.llama, k3, dtype),
+        "llama": decoder_of(cfg).init_params(cfg.llama, k3, dtype),
     }
     if cfg.use_event_qformer:
         from eventgpt_tpu.models import qformer as qformer_mod
@@ -160,7 +217,7 @@ def splice_embeddings(
         for kind, val in _interleave_segments(segments):
             if kind == "text":
                 ids = jnp.asarray(np.asarray(val, dtype=np.int32))
-                parts.append(llama_mod.embed_tokens(params["llama"], ids))
+                parts.append(decoder_of(cfg).embed_tokens(params["llama"], ids))
             else:
                 parts.append(event_tokens[val].astype(embed_dtype))
         out = jnp.concatenate(parts, axis=0)
@@ -230,7 +287,7 @@ def _pad_batch(embeds: List[jnp.ndarray]) -> Tuple[jnp.ndarray, jnp.ndarray, np.
 )
 def _prefill_jit(params, cfg: EventChatConfig, embeds, mask, cache,
                  last_only=False, return_hidden=False):
-    return llama_mod.prefill(
+    return decoder_of(cfg).prefill(
         params["llama"], cfg.llama, embeds, mask, cache, last_only=last_only,
         return_hidden=return_hidden,
     )
@@ -303,8 +360,9 @@ def _prefill_sharded(params, cfg: EventChatConfig, embeds, mask, cache, mesh,
 
 @functools.partial(jax.jit, static_argnames=("cfg",), donate_argnames=("cache",))
 def _decode_jit(params, cfg: EventChatConfig, tokens, cache):
-    token_embeds = llama_mod.embed_tokens(params["llama"], tokens[:, None])
-    return llama_mod.decode_step(params["llama"], cfg.llama, token_embeds, cache)
+    dec = decoder_of(cfg)
+    token_embeds = dec.embed_tokens(params["llama"], tokens[:, None])
+    return dec.decode_step(params["llama"], cfg.llama, token_embeds, cache)
 
 
 @functools.partial(
@@ -355,8 +413,9 @@ def _decode_loop_jit(
         # break XLA's aliasing of the donated KV cache through the
         # while_loop (a second full cache copy stays live — 3 GB at B=8).
         # The cost is one trailing decode_step past the stop condition.
-        token_embeds = llama_mod.embed_tokens(params["llama"], next_tok[:, None])
-        logits, cache = llama_mod.decode_step(
+        dec = decoder_of(cfg)
+        token_embeds = dec.embed_tokens(params["llama"], next_tok[:, None])
+        logits, cache = dec.decode_step(
             params["llama"], cfg.llama, token_embeds, cache
         )
         return step + 1, tokens, done, logits, cache, key
@@ -952,6 +1011,13 @@ def generate(
 
     compute_dtype = jax.tree_util.tree_leaves(params["llama"])[0].dtype
 
+    if decoder_of(cfg) is not llama_mod and (
+            speculative or num_beams > 1 or kv_quant or mesh is not None):
+        raise ValueError(
+            "a decoder with recurrent state generates greedy or sampled, on "
+            "one device, with the plain cache: speculative, num_beams, "
+            "kv_quant and mesh are refused (beams regather keys and values "
+            "only; a rejected draft cannot be rolled back)")
     if speculative and num_beams > 1:
         raise ValueError(
             "speculative decoding composes with greedy/sampled decode, "
@@ -983,7 +1049,7 @@ def generate(
     # and write one full window past the last commit — reserve 2 windows.
     max_len = t + max_new_tokens + (2 * speculative if speculative else 0)
     max_len = ((max_len + bucket - 1) // bucket) * bucket
-    cache = llama_mod.init_kv_cache(
+    cache = decoder_of(cfg).init_cache(
         cfg.llama, b, max_len, dtype=compute_dtype, quant=kv_quant
     )
     if serving is not None:
@@ -1106,4 +1172,5 @@ def forward_train(
     attention_mask: jnp.ndarray,
 ) -> jnp.ndarray:
     """Training forward: spliced embeds -> logits (B, T, V)."""
-    return llama_mod.forward(params["llama"], cfg.llama, inputs_embeds, attention_mask)
+    return decoder_of(cfg).forward(params["llama"], cfg.llama, inputs_embeds,
+                                   attention_mask)

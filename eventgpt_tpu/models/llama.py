@@ -93,6 +93,10 @@ def init_llama_params(cfg: LlamaConfig, key: jax.Array, dtype=jnp.float32) -> Pa
     }
 
 
+# The names a decoder module is reached by (models/eventchat.decoder_of).
+init_params = init_llama_params
+
+
 def embed_tokens(params: Params, input_ids: jnp.ndarray) -> jnp.ndarray:
     return params["embed_tokens"][input_ids]
 
@@ -337,6 +341,9 @@ def init_paged_kv_cache(cfg: LlamaConfig, batch: int, max_len: int,
     }
 
 
+init_cache = init_kv_cache
+
+
 def _kv_is_quant(cache: KVCache) -> bool:
     return isinstance(cache["k"], dict)
 
@@ -566,11 +573,17 @@ def decode_step(
     cfg: LlamaConfig,
     token_embeds: jnp.ndarray,
     cache: KVCache,
+    live: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, KVCache]:
     """One decode step. token_embeds: (B, 1, D). Returns (logits [B, V], cache).
 
     The new token lands at slot ``cache["length"]`` with position id equal to
     the number of real tokens so far (right-pad-free positions).
+
+    ``live`` (B,) bool is part of the decoders' common signature and unused
+    here: a row that is not live is rolled back by its ``length`` alone,
+    which the caller does, since every slot above it is masked and
+    overwritten; only a recurrent state needs the mask inside the step.
     """
     b = token_embeds.shape[0]
     max_len = _kv_max_len(cache)

@@ -64,6 +64,10 @@ def served_shapes(cfg: EventChatConfig, dtype, quant: str, fuse: bool):
                 p, bits=4 if quant == "int4" else 8)
         return p
 
+    if eventchat.decoder_of(cfg) is not llama_mod:
+        eventchat.refuse_without_recurrent_state(**{
+            "--quant": quant != "none", "--fuse_params": fuse})
+        return shapes  # the hybrid tree is served as initialised
     shapes["llama"] = jax.eval_shape(transform, shapes["llama"])
     return shapes
 

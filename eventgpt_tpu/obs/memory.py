@@ -291,10 +291,27 @@ def kv_pos_bytes(cfg, kv_quant: bool = False, dtype_bytes: int = 2) -> int:
     payload and adds one f32 scale per (layer, position, kv-head)."""
     lc = cfg.llama
     hd = lc.resolved_head_dim()
-    per_plane = lc.num_layers * lc.num_kv_heads  # per (k|v) per position
+    # A hybrid decoder keeps keys and values in its attention layers only.
+    layers = lc.count("*") if hasattr(lc, "pattern") else lc.num_layers
+    per_plane = layers * lc.num_kv_heads  # per (k|v) per position
     if kv_quant:
         return 2 * per_plane * (hd * 1 + 4)  # int8 payload + f32 scale
     return 2 * per_plane * hd * dtype_bytes
+
+
+def fixed_state_bytes(cfg, dtype_bytes: int = 2) -> Tuple[int, int]:
+    """(bytes a row, bytes a cache) of state that does not grow with the
+    position. Mirrors ``nemotron_h.init_cache``: a recurrent layer's conv
+    tail in the served type and its ``h`` in float32, a row; what the
+    expert layers last counted (4 int32 a layer), a cache. (0, 0) for a
+    decoder whose whole state is keys and values."""
+    lc = cfg.llama
+    if not hasattr(lc, "pattern"):
+        return 0, 0
+    row = lc.count("M") * (
+        (lc.conv_kernel - 1) * lc.conv_channels * dtype_bytes
+        + lc.mamba_num_heads * lc.mamba_head_dim * lc.ssm_state_size * 4)
+    return row, lc.count("E") * 4 * 4
 
 
 def _mesh_divisors(cfg, mesh_shape: Optional[Dict[str, int]],
@@ -356,8 +373,12 @@ def estimate(cfg, *, max_batch: int, max_len: int, kv_quant: bool = False,
         comp["kv_pool"] = n_blocks * bs * pos_bytes
         comp["kv_block_table"] = max_batch * nbpr * 4 + max_batch * 4
     else:
-        # Resident decode cache: B rows + the (B,) int32 length plane.
-        comp["kv_cache"] = max_batch * row_bytes + max_batch * 4
+        # Resident decode cache: B rows (keys and values by position, and
+        # a row's fixed state where the decoder has one) + the (B,) int32
+        # length plane.
+        fixed_row, fixed_cache = fixed_state_bytes(cfg, dtype_bytes)
+        comp["kv_cache"] = (max_batch * (row_bytes + fixed_row)
+                            + max_batch * 4 + fixed_cache)
     # Per-row next-token logits carry (f32 by construction).
     comp["logits"] = max_batch * vocab * 4
     if speculative:

@@ -92,13 +92,16 @@ SPANS = (
             "work (rids, n, path)"),
     SpanDef("dispatch", "sched", _SCHED, "engine",
             "_dispatch_segment: enqueue one decode / speculation segment "
-            "(chunk, live, rows, lanes, rids)"),
+            "(chunk, live, rows, lanes, rids; set at its harvest, of a "
+            "decoder with sparse experts, by step: experts_touched, "
+            "expert_fullest, held_assignments a layer, routed_tokens)"),
     SpanDef("segment_fetch", "sched", _SCHED, "engine",
             "_harvest_segment: the blocked fetch of a segment's outputs "
             "(wait_s, rids)"),
     SpanDef("harvest", "sched", _SCHED, "engine",
             "_harvest_segment: the host bookkeeping after the fetch "
-            "(tokens, rids)"),
+            "(tokens, rids; the segment's expert counters as on "
+            "sched.dispatch)"),
     SpanDef("prefix_lookup", "sched", _SCHED, "engine",
             "_admit: the prefix-KV trie probe (hit, rid)"),
     SpanDef("prefix_copy", "sched", _SCHED, "engine",
@@ -111,7 +114,8 @@ SPANS = (
             "encode_events_batch + splice + pad (n, rid or rids)"),
     SpanDef("prefill", "admit", _STEPS, "engine",
             "the _prefill_jit / _prefill_sharded / chunk / suffix call "
-            "(n, positions, rid or rids)"),
+            "(n, positions, rid or rids; set at the logits' readback, the "
+            "prefill's expert counters as on sched.dispatch, one step)"),
     SpanDef("scatter", "admit", _STEPS, "engine",
             "_scatter_wave / _finish_admission: the logits readback (NaN "
             "quarantine), prefix insertion, scatter, activation (n, rid or "

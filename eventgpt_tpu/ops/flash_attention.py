@@ -219,8 +219,8 @@ def _flash_forward(
         out_specs=pl.BlockSpec((None, block_q, hd), lambda bh, qb: (bh, qb, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, s_pad, hd), q.dtype),
         interpret=interpret,
-        # The kernel's name on a device trace (benchmark/programs.json
-        # finds it by "flash").
+        # The kernel's name on a device trace
+        # (benchmark/layer_metrics/flash_roofline.py finds it by this name).
         name="flash_forward",
     )(qh, kh, vh, valid_i)
 

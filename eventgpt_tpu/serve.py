@@ -545,7 +545,10 @@ def _decode_segment(
     cache, key, frozen_out, n_rem_out): ``tokens[r, :n_new[r]]`` are row
     r's newly committed tokens; ``done[r]`` marks rows that hit EOS inside
     this segment (budget exhaustion is the host's bookkeeping via
-    n_rem - n_new == 0). ``frozen_out``/``n_rem_out`` are the NEXT
+    n_rem - n_new == 0). A decoder with sparse experts returns a tenth,
+    ``counted``: what its layers counted in each step
+    (``cache["moe_stats"]`` stacked over the ``chunk`` steps), leaving the
+    device with the other outputs. ``frozen_out``/``n_rem_out`` are the NEXT
     segment's control state computed in-graph — the exact bookkeeping the
     host harvest applies (freeze on EOS / budget exhaustion / non-finite
     logits when ``nan_gate``), kept device-resident so the pipelined
@@ -553,17 +556,20 @@ def _decode_segment(
     outputs are ever fetched to the host.
     """
     b = logits.shape[0]
+    dec = eventchat.decoder_of(cfg)
     tokens0 = jnp.full((b, chunk), eos_token_id, jnp.int32)
     n_new0 = jnp.zeros((b,), jnp.int32)
     done0 = jnp.zeros((b,), bool)
+    counted0 = (jnp.zeros((chunk,) + cache["moe_stats"].shape, jnp.int32)
+                if "moe_stats" in cache else None)
 
     def cond(state):
-        t, _, n_new, done, _, _, _ = state
+        t, _, n_new, done = state[:4]
         live = ~(frozen | done) & (n_new < n_rem)
         return (t < chunk) & live.any()
 
     def body(state):
-        t, tokens, n_new, done, logits, cache, key = state
+        t, tokens, n_new, done, logits, cache, key, counted = state
         key, sub = jax.random.split(key)
         nxt = sample(logits, sub, temperature, top_p)
         commit = ~(frozen | done) & (n_new < n_rem)
@@ -576,21 +582,26 @@ def _decode_segment(
         # while_loop (see _decode_loop_jit). Frozen rows' slot writes stay
         # in bounds via submit()'s slack reservation and are masked out of
         # every attention read.
-        emb = llama_mod.embed_tokens(params["llama"], nxt[:, None])
-        new_logits, cache = llama_mod.decode_step(
-            params["llama"], cfg.llama, emb, cache
+        emb = dec.embed_tokens(params["llama"], nxt[:, None])
+        new_logits, cache = dec.decode_step(
+            params["llama"], cfg.llama, emb, cache, live=commit
         )
         # Frozen rows keep their pre-segment logits AND their length: the
         # row must resume exactly where it stopped when the next segment
         # runs (length would otherwise creep by one per segment step).
+        # (State that is not addressed by length stays put inside the
+        # step: ``live``.)
         logits = jnp.where(commit[:, None], new_logits, logits)
         cache = {**cache, "length": jnp.where(
             commit, cache["length"], cache["length"] - 1
         )}
-        return t + 1, tokens, n_new, done, logits, cache, key
+        if counted is not None:
+            counted = counted.at[t].set(cache["moe_stats"])
+        return t + 1, tokens, n_new, done, logits, cache, key, counted
 
-    t, tokens, n_new, done, logits, cache, key = lax.while_loop(
-        cond, body, (jnp.int32(0), tokens0, n_new0, done0, logits, cache, key)
+    t, tokens, n_new, done, logits, cache, key, counted = lax.while_loop(
+        cond, body, (jnp.int32(0), tokens0, n_new0, done0, logits, cache,
+                     key, counted0)
     )
     # Per-row non-finite-logit flag, computed IN-GRAPH (one fused reduce
     # per segment, no extra host dispatch): the scheduler quarantines a
@@ -604,8 +615,9 @@ def _decode_segment(
     if nan_gate:
         frozen_out = frozen_out | ~finite
     n_rem_out = jnp.where(frozen_out, 0, n_rem_out)
-    return (tokens, n_new, done, finite, logits, cache, key,
-            frozen_out, n_rem_out)
+    out = (tokens, n_new, done, finite, logits, cache, key,
+           frozen_out, n_rem_out)
+    return out if counted is None else out + (counted,)
 
 
 _decode_segment_jit = functools.partial(
@@ -767,10 +779,45 @@ _spec_segment_jit = functools.partial(
 )(_spec_segment)
 
 
+# The planes of a cache that hold a row's state, rows on axis 1: keys and
+# values by position, and what a recurrent layer keeps whatever the position
+# (models/nemotron_h.py). Any other entry of a cache is not a row's.
+_BY_POSITION = ("k", "v")
+_FIXED_STATE = ("conv", "h")
+
+
+def _expert_counts(counted) -> Dict[str, list]:
+    """Span args from what expert layers counted: ``counted`` (steps,
+    layers, 4) int32 of ``models/nemotron_h.STATS``. A step of a segment
+    that did not run, or ran for no live row, counted no token and is left
+    out. By step: held experts that received a token, a layer; the tokens
+    of the fullest held expert, a layer; assignments that fell on held
+    experts, a layer; tokens routed."""
+    counted = np.asarray(counted)
+    ran = counted[counted[:, 0, 3] > 0]
+    return {"experts_touched": ran[:, :, 0].tolist(),
+            "expert_fullest": ran[:, :, 1].tolist(),
+            "held_assignments": ran[:, :, 2].tolist(),
+            "routed_tokens": ran[:, 0, 3].tolist()}
+
+
+def _admission_readback(logits, prefilled_cache, prefill_span):
+    """The NaN quarantine's readback of an admission's logits; what the
+    prefill's expert layers counted comes in the same fetch and is set on
+    ``prefill_span``. Returns the logits on the host."""
+    host_logits, counted = jax.device_get(
+        (logits, prefilled_cache.get("moe_stats")))
+    if counted is not None and prefill_span is not None:
+        prefill_span.set(**_expert_counts(counted[None]))
+    return np.asarray(host_logits)
+
+
 def _admit_row(cache, logits_buf, row, row_cache, row_logits):
     """Insert a batch-1 prefill result at batch row ``row`` of the shared
     cache (dynamic-update on the batch axis; the prompt bucket length of
-    ``row_cache`` is a static shape — one compile per bucket)."""
+    ``row_cache`` is a static shape — one compile per bucket). Every plane
+    of the row's state goes in: a slot handed to a new request starts from
+    the new request's state."""
 
     def ins(buf, rbuf):
         if isinstance(buf, dict):
@@ -781,8 +828,9 @@ def _admit_row(cache, logits_buf, row, row_cache, row_logits):
         )
 
     new_cache = {
-        "k": ins(cache["k"], row_cache["k"]),
-        "v": ins(cache["v"], row_cache["v"]),
+        **cache,
+        **{name: ins(cache[name], row_cache[name])
+           for name in _BY_POSITION + _FIXED_STATE if name in cache},
         "length": cache["length"].at[row].set(row_cache["length"][0]),
     }
     return new_cache, logits_buf.at[row].set(row_logits[0])
@@ -794,14 +842,17 @@ _admit_row_jit = functools.partial(
 
 
 def _admit_wave(cache, logits_buf, rows, wave_k, wave_v, wave_len,
-                wave_logits):
+                wave_logits, wave_fixed=None):
     """Scatter one BATCHED admission prefill into the shared cache: every
     wave member's row lands in ONE dispatch instead of N ``_admit_row``
     calls. ``rows`` (Nb,) carries the destination row per wave slot;
     slots padded to the power-of-two wave size (and NaN-quarantined
     members) carry ``row == max_batch``, which is out of bounds — XLA
     DROPS out-of-bounds scatter updates (the same rule the frozen-row
-    slack reservation relies on), so pad slots write nothing."""
+    slack reservation relies on), so pad slots write nothing.
+    ``wave_fixed``: the wave's planes of state that does not grow with the
+    position (``_FIXED_STATE``), scattered whole; None where the decoder
+    has none."""
     s1 = (wave_k["q"] if isinstance(wave_k, dict) else wave_k).shape[2]
 
     def ins(buf, wbuf):
@@ -811,8 +862,11 @@ def _admit_wave(cache, logits_buf, rows, wave_k, wave_v, wave_len,
         return buf.at[:, rows, :s1].set(wbuf.astype(buf.dtype))
 
     new_cache = {
+        **cache,
         "k": ins(cache["k"], wave_k),
         "v": ins(cache["v"], wave_v),
+        **{name: cache[name].at[:, rows].set(plane.astype(cache[name].dtype))
+           for name, plane in (wave_fixed or {}).items()},
         "length": cache["length"].at[rows].set(
             wave_len.astype(cache["length"].dtype)),
     }
@@ -1649,6 +1703,18 @@ class ContinuousBatcher:
                 f"prefill_chunk must divide the prompt bucket grain "
                 f"{2 * SEQ_BUCKET}, got {prefill_chunk}"
             )
+        self._dec = eventchat.decoder_of(cfg)
+        if self._dec is not llama_mod:
+            eventchat.refuse_without_recurrent_state(**{
+                "--kv_cache int8": kv_quant,
+                "--kv_layout paged": kv_layout == "paged",
+                "--speculative": speculative, "--spec_buckets": spec_buckets,
+                "--draft_head": draft_head is not None,
+                "--prefill_chunk": prefill_chunk,
+                "--prefill_budget": prefill_budget > 0,
+                "--prefix_cache_mb": prefix_cache, "--preempt": preempt,
+                "--role": role != "colocated",
+                "--mesh_model": mesh is not None})
         if mesh is not None:
             from eventgpt_tpu.parallel import serving as serving_mod
 
@@ -1656,6 +1722,9 @@ class ContinuousBatcher:
             serving_mod.require_flash_heads_divide(cfg.llama, mesh)
         self.mesh = mesh
         self.params, self.cfg = params, cfg
+        # The most positions (rows x bucket) one admission wave may prefill;
+        # 0: as many as there are free rows.
+        self._wave_tokens = int(getattr(self._dec, "WAVE_TOKENS", 0))
         # Admission pads prompts to the serving bucket grain; a max_len off
         # the grain would let a bucketed row_cache outgrow the shared cache
         # (a trace-time shape crash). Round up once here.
@@ -1742,7 +1811,7 @@ class ContinuousBatcher:
                 dtype=self._dtype, quant=kv_quant,
             )
         else:
-            self.cache = llama_mod.init_kv_cache(
+            self.cache = self._dec.init_cache(
                 cfg.llama, max_batch, max_len, dtype=self._dtype,
                 quant=kv_quant
             )
@@ -3559,7 +3628,7 @@ class ContinuousBatcher:
         with obs_trace.span("dispatch", "sched", chunk=chunk, live=len(live),
                             rows=self.max_batch,
                             lanes=len(self._lanes) if mixed else 0,
-                            rids=live):
+                            rids=live) as rec["span"]:
             lane_out = None
             if self.speculative:
                 n_iters = max(1, chunk // spec_w)
@@ -3694,13 +3763,14 @@ class ContinuousBatcher:
                     )
                 else:
                     (tokens, n_new, done, fin, self.logits, self.cache,
-                     self.key, frozen_out, n_rem_out) = (
+                     self.key, frozen_out, n_rem_out, *counted) = (
                         _decode_segment_jit(
                             self.params, self.cfg, self.logits, self.cache,
                             self.key, frozen, n_rem, chunk, int(self.eos),
                             self.temperature, self.top_p, self.nan_check,
                         )
                     )
+                    rec["counted"] = counted[0] if counted else None
                 base_pos_out = None
                 rec.update(tokens=tokens, n_new=n_new, done=done, fin=fin)
             if lane_out is not None:
@@ -3754,14 +3824,15 @@ class ContinuousBatcher:
                 )
                 new_np = np.asarray(new_np)
                 tokens = None
-                finite = None
+                finite = counted = None
             else:
                 # The quarantine mask is computed in-graph and rides the
                 # same device_get as the segment outputs — no extra
                 # dispatch or round trip on the hot path.
-                tokens, n_new, done, finite, frozen_in = jax.device_get(
+                (tokens, n_new, done, finite, frozen_in,
+                 counted) = jax.device_get(
                     (rec["tokens"], rec["n_new"], rec["done"], rec["fin"],
-                     rec["frozen_in"])
+                     rec["frozen_in"], rec.get("counted"))
                 )
                 finite = np.asarray(finite) if self.nan_check else None
                 tokens = np.asarray(tokens)
@@ -3786,6 +3857,13 @@ class ContinuousBatcher:
         obs_metrics.SERVE_SEGMENT.observe(wait)
         self._t_prev_fetch_end = t_end
         with obs_trace.span("harvest", "sched", rids=rids) as sp:
+            if counted is not None and obs_trace.enabled():
+                # What the segment's expert layers counted, on the span
+                # that enqueued it and on this one.
+                counts = _expert_counts(counted)
+                if counts["routed_tokens"]:  # a segment that ran a step
+                    rec["span"].set(**counts)
+                    sp.set(**counts)
             if self.speculative:
                 self.spec_iterations += int(it_v)
                 self.spec_tokens += int(n_new.sum())
@@ -5004,6 +5082,8 @@ class ContinuousBatcher:
                        for r in range(self.max_batch))):
             if piggy and not self._lane_free:
                 break  # lanes at the token budget: the rest stay queued
+            if wave and not self._wave_takes(wave, self.queue[0]):
+                break  # the wave is at its positions: the rest stay queued
             if self._paged and not self._paged_admit_gate():
                 break  # pool can't cover the head's block reservation
             req = self.queue.popleft()
@@ -5167,7 +5247,8 @@ class ContinuousBatcher:
         want_hidden = self.draft_head is not None
         row_hidden = None
         with obs_trace.span("prefill", "admit", n=1,
-                            positions=int(padded.shape[1]), rid=req.rid):
+                            positions=int(padded.shape[1]),
+                            rid=req.rid) as prefill_span:
             row_cache = self._new_row_cache(padded.shape[1])
             if self.mesh is not None:
                 pre = _prefill_sharded(
@@ -5185,8 +5266,20 @@ class ContinuousBatcher:
         else:
             row_logits, row_cache = pre
         self._finish_admission(req, row, prompt_len, row_cache,
-                               row_logits, row_hidden)
+                               row_logits, row_hidden,
+                               prefill_span=prefill_span)
         return did_work
+
+    def _wave_takes(self, wave: List[tuple], req: _Request) -> bool:
+        """Whether one more member keeps the wave's prefill (members padded
+        to a power of two, prompts to the widest member's bucket) within
+        the decoder's ``WAVE_TOKENS``."""
+        if not self._wave_tokens:
+            return True
+        grain = 2 * SEQ_BUCKET
+        widest = max([r.prompt_len for r, _ in wave] + [req.prompt_len])
+        s1 = min(((widest + grain - 1) // grain) * grain, self.max_len)
+        return (1 << len(wave).bit_length()) * s1 <= self._wave_tokens
 
     def _mem_next_wave_bytes(self) -> int:
         """Predicted device bytes of admitting the queue head(s) that
@@ -5317,7 +5410,7 @@ class ContinuousBatcher:
         return pv
 
     def _new_row_cache(self, s1: int):
-        row_cache = llama_mod.init_kv_cache(
+        row_cache = self._dec.init_cache(
             self.cfg.llama, 1, s1, dtype=self._dtype, quant=self.kv_quant
         )
         if self.mesh is not None:
@@ -5430,8 +5523,8 @@ class ContinuousBatcher:
                 mask = self._serving.shard_batch_array(mask, self.mesh)
         want_hidden = self.draft_head is not None
         with obs_trace.span("prefill", "admit", n=n, positions=s1,
-                            rids=rids):
-            wave_cache = llama_mod.init_kv_cache(
+                            rids=rids) as prefill_span:
+            wave_cache = self._dec.init_cache(
                 self.cfg.llama, nb, s1, dtype=self._dtype,
                 quant=self.kv_quant)
             if self.mesh is not None:
@@ -5452,19 +5545,21 @@ class ContinuousBatcher:
         else:
             (wave_logits, wave_cache), wave_hidden = pre, None
         self._scatter_wave(wave, wave_cache, wave_logits, wave_hidden,
-                           prompt_lens)
+                           prompt_lens, prefill_span=prefill_span)
 
     # egpt-check: harvest -- admission NaN quarantine is a mandated readback of the wave logits before they touch the shared cache
     def _scatter_wave(self, members: List[tuple], wave_cache, wave_logits,
                       wave_hidden, prompt_lens: List[int],
                       entries: Optional[List[_PrefixEntry]] = None,
-                      path: str = "wave") -> None:
+                      path: str = "wave", prefill_span=None) -> None:
         """Common tail of both admission waves: per-member NaN
         quarantine, insert-on-prefill of new heads, the one-dispatch
         scatter of every surviving row into the shared cache, then row
         activation. ``members`` are (req, row) pairs; quarantined and
         pow2-pad slots keep row index ``max_batch`` (dropped by the
-        scatter's out-of-bounds rule)."""
+        scatter's out-of-bounds rule). What the prefill's expert layers
+        counted comes with the logits' readback and is set on
+        ``prefill_span``."""
         with obs_trace.span("scatter", "admit", n=len(members),
                             rids=[req.rid for req, _ in members]):
             n = len(members)
@@ -5474,8 +5569,8 @@ class ContinuousBatcher:
             good = []
             finite = None
             if self.nan_check:
-                finite = np.isfinite(
-                    np.asarray(jax.device_get(wave_logits))[:n]).all(axis=-1)
+                finite = np.isfinite(_admission_readback(
+                    wave_logits, wave_cache, prefill_span)[:n]).all(axis=-1)
             for i, (req, row) in enumerate(members):
                 if finite is not None and not finite[i]:
                     # Same per-request quarantine as the batch-1 path: the
@@ -5529,9 +5624,12 @@ class ContinuousBatcher:
                     )
                 else:
                     admit = _admit_wave_jit
+                fixed = {name: wave_cache[name] for name in _FIXED_STATE
+                         if name in wave_cache}
                 self.cache, self.logits = admit(
                     self.cache, self.logits, rows_arr, wave_cache["k"],
                     wave_cache["v"], wave_cache["length"], wave_logits,
+                    *([fixed] if fixed else []),
                 )
             for i, req, row in good:
                 row_hidden = (wave_hidden[i:i + 1]
@@ -5621,11 +5719,15 @@ class ContinuousBatcher:
     # egpt-check: harvest -- admission NaN quarantine reads back the row logits before the row joins the shared cache
     def _finish_admission(self, req, row, prompt_len, row_cache,
                           row_logits, row_hidden=None,
-                          prefix_entry=None, path: str = "full") -> None:
+                          prefix_entry=None, path: str = "full",
+                          prefill_span=None) -> None:
         """Insert the prefilled row into the shared cache + activate it."""
         with obs_trace.span("scatter", "admit", n=1, rid=req.rid):
-            if self.nan_check and not bool(
-                    np.isfinite(np.asarray(jax.device_get(row_logits))).all()):
+            finite = True
+            if self.nan_check:
+                finite = bool(np.isfinite(_admission_readback(
+                    row_logits, row_cache, prefill_span)).all())
+            if not finite:
                 # Prefill produced non-finite logits: quarantine the REQUEST
                 # before it touches the shared cache (the speculative path's
                 # only NaN gate — it commits the prefill sample at admission

@@ -3,7 +3,8 @@
 
 ``jax.named_scope`` marks ``tower``, ``projector``, ``splice``,
 ``prefill_attn``, ``decode_attn``, ``mlp``, ``lm_head`` and ``sample`` in the
-model (OBSERVABILITY.md "Profiling"). On a TPU trace the scope path of a
+model, and in the hybrid decoder ``ssm_scan``, ``ssm_step``, ``moe_route``,
+``moe_experts`` and ``moe_shared`` (OBSERVABILITY.md "Profiling"). On a TPU trace the scope path of a
 device operation is in the operation's *metadata*: the stat ``tf_op``, for
 instance ``jit(_decode_segment)/.../decode_attn/dot_general:``; a fusion
 carries its root's. ``jax.profiler.ProfileData`` shows an event's own stats
@@ -44,6 +45,7 @@ from benchmark.trace_reduce import (  # noqa: E402
     DEVICE_PLANE, MODULES_LINE, OPS_LINE, self_ns)
 
 SCOPES = ("tower", "projector", "splice", "prefill_attn", "decode_attn",
+          "ssm_scan", "ssm_step", "moe_route", "moe_experts", "moe_shared",
           "attn", "mlp", "lm_head", "sample")
 
 
