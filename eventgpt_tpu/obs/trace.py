@@ -88,8 +88,11 @@ SPANS = (
     SpanDef("stream_push", "engine", _FRONT, "engine",
             "_push_stream_deltas_locked + _harvest_locked (finished, rids)"),
     SpanDef("admit", "sched", _SCHED, "engine",
-            "ContinuousBatcher._admit, recorded when it did admission "
-            "work (rids, n, path)"),
+            "ContinuousBatcher._admit and _stage, recorded when they did "
+            "admission work (rids, n, path; staged: the members whose "
+            "upload, tower and prefill were dispatched while a segment was "
+            "in flight, 0 on the drained path; a staged wave has one span "
+            "for its staging and one for its landing)"),
     SpanDef("dispatch", "sched", _SCHED, "engine",
             "_dispatch_segment: enqueue one decode / speculation segment "
             "(chunk, live, rows, lanes, rids; set at its harvest, of a "

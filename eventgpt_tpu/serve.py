@@ -40,9 +40,9 @@ TPU-shaped design (everything jit-visible is static-shape):
     prefill instead of recompute; an event entry never serves a request
     whose pixels are a different stream.
   * BATCHED ADMISSION PREFILL: all full-prefill admissions ready at one
-    dispatch boundary run as ONE padded batched prefill (``_admit_wave``
+    dispatch boundary run as ONE padded batched prefill (``_prefill_wave``
     — N dispatches become one per wave), scattered into the shared cache
-    in one more dispatch.
+    in one more dispatch (``_scatter_wave``).
   * STALL-FREE ADMISSION (ISSUE 5): when ``prefill_budget > 0`` and rows
     are actively decoding, admissions no longer pause the batch for an
     exclusive prefill/suffix wave. Each admitting request becomes a
@@ -68,9 +68,18 @@ TPU-shaped design (everything jit-visible is static-shape):
     from device state while the host is still harvesting segment N —
     detokenization, history/draft bookkeeping and admission prep overlap
     device compute instead of serializing between dispatches. At most
-    one segment is in flight; row mutations (admission, cancel,
-    deadline) drain the pipeline at the dispatch boundary first. Chains
-    are byte-identical to the synchronous path (``pipeline=False``).
+    one segment is in flight; row mutations (cancel, deadline, the
+    suffix, chunked, lane-finish and paged admissions) drain the
+    pipeline at the dispatch boundary first. A FULL-PREFILL admission
+    drains only to land: its row-independent half (pop and reserve the
+    rows, upload, tower, splice, pad, the wave's prefill into a cache of
+    its own: ``_stage``) is dispatched while the segment is still in
+    flight and stands in the device's queue behind it, and only the
+    logits' readback, the scatter into the shared cache and the rows'
+    activation (``_land``) wait for the drain. With nothing in flight
+    (an idle server, ``pipeline=False``) the same two halves run back to
+    back. Chains are byte-identical to the synchronous path
+    (``pipeline=False``).
 
 Mesh-sharded serving (``mesh=``): the resident cache / logits / ids_buf
 are placed by ``parallel/serving.py``'s layout (batch over ``(data,
@@ -1553,6 +1562,21 @@ class _PendingAdmission:
 
 
 @dataclass
+class _Prefilled:
+    """A full-prefill admission between its two halves. The members' rows
+    are reserved (frozen) and their tower, splice and prefill are dispatched
+    into a fresh cache of the wave's own; nothing of the shared cache, the
+    carry or the host mirror has been touched. ``_land`` is the other half:
+    readback, scatter, activation, against settled state."""
+    members: List[tuple]  # (req, row), in the order of the wave's slots
+    cache: Any            # the wave's (or the row's) own prefilled cache
+    logits: Any           # (nb, V) future
+    hidden: Any           # (nb, D) future for Medusa seeding, else None
+    prompt_lens: List[int]
+    span: Any             # admit.prefill: gets the expert counters at readback
+
+
+@dataclass
 class _Request:
     rid: int
     input_ids: Sequence[int]
@@ -1932,6 +1956,9 @@ class ContinuousBatcher:
         self._next_rid = 0
         self.prefill_chunk = int(prefill_chunk)
         self._pending: Optional[_PendingAdmission] = None
+        # A full-prefill admission whose first half was dispatched behind
+        # the segment in flight (``_stage``); ``_admit_queue`` lands it.
+        self._staged: Optional[_Prefilled] = None
         # Prefix-KV cache (ISSUE 4 tentpole): the multi-entry trie that
         # replaced the single set_prefix slot. ``prefix_cache=False`` is
         # the A/B escape hatch (every admission full-prefills);
@@ -2873,6 +2900,13 @@ class ContinuousBatcher:
             self.rows[p.row] = None  # row stays frozen; cache untouched
             self._finish_forced(p.req, STATUS_CANCELLED)
             return True
+        for req, row in (self._staged.members if self._staged else ()):
+            if req.rid == rid and self.rows[row] is req:
+                # Staged, not landed: the row was only reserved, and the
+                # member's slot of the wave scatters out of bounds.
+                self.rows[row] = None
+                self._finish_forced(req, STATUS_CANCELLED)
+                return True
         for l in self._lanes:
             if l.req.rid == rid:
                 # A piggybacked admission mid-prefill: drop the lane and
@@ -2931,6 +2965,7 @@ class ContinuousBatcher:
             by_rid[l.req.rid] = l.req
         self._lanes = []
         self._lane_free = list(range(self._lane_cap))
+        self._staged = None  # its members leave with the rows below
         for r, req in enumerate(self.rows):
             if req is None:
                 continue
@@ -3306,9 +3341,14 @@ class ContinuousBatcher:
         device-resident carry FIRST, then the PREVIOUS segment's outputs
         are fetched — so detokenization, history/draft bookkeeping and
         admission prep run while the chip is already computing the next
-        segment. Anything that must mutate rows (an expired deadline, an
-        admission into a freed row, a pending chunked prefill) drains the
-        pipeline at the dispatch boundary before it is applied. With
+        segment. Anything that must mutate rows (an expired deadline, a
+        pending chunked prefill, the landing of an admission) drains the
+        pipeline at the dispatch boundary before it is applied; the half
+        of a full-prefill admission that mutates none (``_stage``) runs
+        at the end of the step, when the harvest has freed rows and the
+        segment just dispatched has its whole length ahead of it (and at
+        the top of a step too while at least as many rows are free as
+        decode: ``_fill_first``). With
         ``pipeline=False`` (or while the TTFT ramp owes a first token)
         every step harvests its own segment — the synchronous schedule.
         """
@@ -3316,18 +3356,27 @@ class ContinuousBatcher:
         faults.maybe_delay("serve.step")
         piggy = (self.prefill_budget > 0
                  and (bool(self._lanes) or not bool(self.frozen.all())))
+        if self._staged is None and self._fill_first():
+            # What arrived since the last step goes behind what is left
+            # of the segment in flight, and lands in this step.
+            self._stage()
+        landing = self._staged is not None
         if self._inflight is not None and (
                 self._deadline_expired()
                 or self._pending is not None
+                or landing
                 or any(l.filled >= l.prompt_len for l in self._lanes)
                 or (self.queue and not piggy
-                    and any(r is None for r in self.rows))):
+                    and any(r is None for r in self.rows)
+                    and not self._stages_head())):
             # A forced finish or admission is about to mutate rows: apply
             # it against settled state, at the dispatch boundary. A
             # piggyback JOIN is exempt (ISSUE 5): it only reserves a row
             # (host-side) and touches the lane buffers, never the decode
             # carry — so lane boundaries keep the pipeline full; only a
-            # lane FINISH (activation) drains.
+            # lane FINISH (activation) drains. So is a full-prefill
+            # admission that ``_stage`` will take at the end of this step:
+            # the pipeline stays full, and only its landing drains.
             self._drain()
         self._expire_deadlines()
         t0 = time.perf_counter()
@@ -3381,6 +3430,14 @@ class ContinuousBatcher:
             self._harvest_segment(prev)
         if self.pipeline and not ramp:
             self._inflight = rec
+            if not landing or self._fill_first():
+                # The harvest above freed rows and ``rec`` has its whole
+                # length ahead of it: the next admission's tower and
+                # prefill queue up behind it now. (Not in the step that
+                # landed the last one, while most rows decode: the rows
+                # that two segments free make one wave, as they did when
+                # admission drained, and a wave's fixed costs are shared.)
+                self._stage()
         else:
             self._harvest_segment(rec)
 
@@ -3399,6 +3456,7 @@ class ContinuousBatcher:
         re-uploads the repaired host view."""
         self._inflight = None
         self._dev_carry = None
+        self._staged = None  # its members hold rows: the sweep fails them
 
     def _deadline_expired(self) -> bool:
         """Cheap host predicate: does any live deadline need a forced
@@ -3442,6 +3500,12 @@ class ContinuousBatcher:
             p, self._pending = self._pending, None
             self.rows[p.row] = None
             self._finish_forced(p.req, STATUS_DEADLINE)
+        for req, row in (self._staged.members if self._staged else ()):
+            if self.rows[row] is req and expired(req):
+                # Staged, not landed: as a cancelled member, its slot of
+                # the wave scatters out of bounds.
+                self.rows[row] = None
+                self._finish_forced(req, STATUS_DEADLINE)
         for l in [x for x in self._lanes if expired(x.req)]:
             # A piggybacked admission expired mid-prefill: drop the lane
             # (its slot's KV is dead storage) and free the reserved row.
@@ -5022,11 +5086,13 @@ class ContinuousBatcher:
         recorded when the step did admission work: with the requests it
         worked for and the paths they took."""
         took: List[tuple] = []  # (rid, path)
+        staged = len(self._staged.members) if self._staged else 0
         with obs_trace.span("admit", "sched") as sp:
             did_work = self._admit_queue(took)
             if did_work:
                 sp.set(rids=[rid for rid, _ in took], n=len(took),
-                       path="+".join(sorted({p for _, p in took})))
+                       path="+".join(sorted({p for _, p in took})),
+                       staged=staged)
             else:
                 sp.drop()
         return did_work
@@ -5048,12 +5114,22 @@ class ContinuousBatcher:
         (suffix-only admission), else the chunked path (when actives are
         decoding), else collected into this step's FULL-PREFILL WAVE —
         every wave member runs in ONE batched prefill dispatch
-        (``_admit_wave``) instead of N sequential batch-1 prefills."""
-        from eventgpt_tpu.models.eventchat import _prefill_jit, _prefill_sharded
+        (``_prefill_wave``) instead of N sequential batch-1 prefills.
 
+        A wave that ``_stage`` prefilled behind the last segment lands
+        here, and nothing else is popped in the same step, nor in a step
+        that left a segment in flight: a row that a segment freed is
+        staged behind a later one, so that the device does not wait for
+        this path's host work while it has rows to decode."""
         faults.maybe_fail("serve.admit")
         faults.maybe_delay("serve.admit")
         did_work = False
+        landed, self._staged = self._staged, None
+        if landed is not None:
+            did_work = True
+            took += [(req.rid, "wave" if len(landed.members) > 1 else "row")
+                     for req, _ in landed.members]
+            self._land(landed)
         if self._lanes:
             # step() drained the pipeline when any lane was ready, so
             # the activations below apply against settled state.
@@ -5074,10 +5150,15 @@ class ContinuousBatcher:
         # next admission wave would exceed capacity - headroom, the
         # queue stays queued this boundary — decode keeps flowing, and
         # finishing rows free the bytes the deferred wave needs.
-        mem_defer = self._mem_guard_defers()
+        # A step that landed a staged wave pops nothing more either, nor
+        # does one that left a segment in flight for ``_stage`` to hide
+        # the admission behind (a lane's join never needed it drained).
+        hold = (landed is not None
+                or (self._inflight is not None and not piggy)
+                or self._mem_guard_defers())
         wave: List[tuple] = []  # (req, row) full-prefill admissions
         hits: List[tuple] = []  # (req, row, entry, suffix_ids, fit)
-        while (self._pending is None and self.queue and not mem_defer
+        while (self._pending is None and self.queue and not hold
                and any(self.rows[r] is None
                        for r in range(self.max_batch))):
             if piggy and not self._lane_free:
@@ -5086,25 +5167,8 @@ class ContinuousBatcher:
                 break  # the wave is at its positions: the rest stay queued
             if self._paged and not self._paged_admit_gate():
                 break  # pool can't cover the head's block reservation
-            req = self.queue.popleft()
+            req, row = self._take_head()
             did_work = True
-            t_deq = time.perf_counter()
-            obs_metrics.SERVE_QUEUE_DEPTH.set(len(self.queue))
-            obs_metrics.SERVE_QUEUE_WAIT.observe(t_deq - req.t_submit)
-            obs_journey.event(self._journey_owner, req.rid, "queue",
-                              t=t_deq, depth=len(self.queue))
-            if req.phase == "queued":
-                obs_trace.async_end("queued", req.rid)
-                obs_trace.async_begin("active", req.rid)
-                req.phase = "active"
-            row = next(r for r in range(self.max_batch)
-                       if self.rows[r] is None)
-            # Reserve the row NOW (it stays frozen until activation): a
-            # fault mid-admission (serve.prefix_copy, a prefill error)
-            # must leave the request somewhere the engine's sweep can
-            # fail cleanly instead of stranding its waiter.
-            self.rows[row] = req
-            req.row = row
             if self._paged and req.spill_run is not None:
                 # A preempted-and-spilled head restores through the
                 # paged admission seam instead of re-prefilling: fresh
@@ -5231,44 +5295,104 @@ class ContinuousBatcher:
                     for m in members:
                         self._drain_entry_pin(m[2])
                     raise
-        if not wave:
-            return did_work
-        obs_metrics.SERVE_ADMISSION_WAVE.observe(len(wave))
-        took += [(req.rid, "wave" if len(wave) > 1 else "row")
-                 for req, _ in wave]
-        if len(wave) > 1:
-            self._admit_wave(wave)
-            return True
-        # Single admission: the batch-1 path (its executables are the
-        # ones warmup precompiles). Medusa mode also needs the prompt's
-        # last hidden to seed the row's first draft window.
-        req, row = wave[0]
-        padded, mask, prompt_len = self._prep_request(req)
-        want_hidden = self.draft_head is not None
-        row_hidden = None
-        with obs_trace.span("prefill", "admit", n=1,
-                            positions=int(padded.shape[1]),
-                            rid=req.rid) as prefill_span:
-            row_cache = self._new_row_cache(padded.shape[1])
-            if self.mesh is not None:
-                pre = _prefill_sharded(
-                    self.params, self.cfg, padded, mask, row_cache,
-                    self.mesh, return_hidden=want_hidden,
-                )
-            else:
-                pre = _prefill_jit(
-                    self.params, self.cfg, padded, mask, row_cache, True,
-                    return_hidden=want_hidden,
-                )
-        obs_metrics.SERVE_PREFILL_DISPATCHES.inc(kind="full")
-        if want_hidden:
-            row_logits, row_hidden, row_cache = pre
-        else:
-            row_logits, row_cache = pre
-        self._finish_admission(req, row, prompt_len, row_cache,
-                               row_logits, row_hidden,
-                               prefill_span=prefill_span)
+        if wave:
+            took += [(req.rid, "wave" if len(wave) > 1 else "row")
+                     for req, _ in wave]
+            self._land(self._prefill_members(wave))
         return did_work
+
+    def _take_head(self) -> tuple:
+        """Pop the queue's head into the first free row: (req, row). The
+        row is reserved NOW (it stays frozen until activation): a fault
+        mid-admission (serve.prefix_copy, a prefill error) must leave the
+        request somewhere the engine's sweep can fail cleanly instead of
+        stranding its waiter."""
+        req = self.queue.popleft()
+        t_deq = time.perf_counter()
+        obs_metrics.SERVE_QUEUE_DEPTH.set(len(self.queue))
+        obs_metrics.SERVE_QUEUE_WAIT.observe(t_deq - req.t_submit)
+        obs_journey.event(self._journey_owner, req.rid, "queue",
+                          t=t_deq, depth=len(self.queue))
+        if req.phase == "queued":
+            obs_trace.async_end("queued", req.rid)
+            obs_trace.async_begin("active", req.rid)
+            req.phase = "active"
+        row = next(r for r in range(self.max_batch) if self.rows[r] is None)
+        self.rows[row] = req
+        req.row = row
+        return req, row
+
+    def _fill_first(self) -> bool:
+        """At least as many rows are free as decode (on the host mirror):
+        filling them comes before keeping the few that decode flowing. An
+        admission is then staged as soon as it is seen, at the top of a
+        step behind whatever is left of the segment in flight, and landed
+        in that step, as early as the drained path would have admitted it.
+        While most rows decode, staging waits for the end of a step (a
+        whole segment to hide behind, one wave for the rows of two
+        segments) and a free row for its turn."""
+        free = sum(r is None for r in self.rows)
+        return free >= self.max_batch - int(self.frozen.sum())
+
+    def _stages_head(self) -> bool:
+        """Whether the queue's head is an admission that ``_stage`` hides
+        behind a segment in flight: the drained path would run the
+        exclusive wave (or its batch-1 form) for it. Not while a chunked
+        admission or a lane is open, nor with a budget that makes the
+        request a lane or a chunked admission while rows decode; not a
+        head that the prefix cache can serve; no head of a paged server
+        (its gate reclaims, preempts and reserves blocks of the shared
+        pool; spilled and handed-off heads are paged too); not a wave over
+        the memory guard's budget. Those keep the drained path."""
+        return bool(
+            self.pipeline and self.queue and self._pending is None
+            and not self._lanes and not self._paged
+            and (not (self.prefill_budget > 0 or self.prefill_chunk)
+                 or self.frozen.all())
+            and not self._mem_over_budget()
+            and (self._prefix_cache is None
+                 or self._prefix_lookup(self.queue[0]) is None))
+
+    def _stage(self) -> None:
+        """The row-independent half of a full-prefill admission, behind
+        the segment in flight: pop the queue's heads into free rows as
+        ``_admit_queue`` does, then upload, encode, splice, pad and
+        dispatch their prefill into a fresh cache (``_prefill_members``),
+        and keep the result for ``_admit_queue`` to land once the segment
+        is drained. Nothing here waits for the device or touches the
+        shared cache, the carry, ``frozen`` or ``n_rem``: a reserved row
+        was frozen when the segment in flight was dispatched, so its
+        harvest passes over it.
+
+        It takes a power of two of members, the most that free rows, the
+        queue and ``_wave_takes`` allow: a wave pads to one, a padded slot
+        costs the tower and the prefill of a request, and a row left free
+        until the next wave costs its share of a segment or two."""
+        free = sum(r is None for r in self.rows)
+        if (self._inflight is None or self._staged is not None or not free
+                or not self._stages_head()):
+            return
+        heads: List[tuple] = []  # as ``_wave_takes`` reads a wave: (req, row)
+        for req in self.queue:
+            if (len(heads) >= free or not self._wave_takes(heads, req)
+                    or (heads and self._prefix_cache is not None
+                        and self._prefix_lookup(req) is not None)):
+                break
+            heads.append((req, None))
+        if not heads:
+            return
+        n = 1 << (len(heads).bit_length() - 1)
+        with obs_trace.span("admit", "sched", n=n, staged=n) as sp:
+            wave = []
+            for _ in range(n):
+                if self._prefix_cache is not None:
+                    self._prefix_cache.count_miss()
+                    obs_journey.event(self._journey_owner,
+                                      self.queue[0].rid, "prefix", hit=False)
+                wave.append(self._take_head())
+            sp.set(rids=[req.rid for req, _ in wave],
+                   path="wave" if n > 1 else "row")
+            self._staged = self._prefill_members(wave)
 
     def _wave_takes(self, wave: List[tuple], req: _Request) -> bool:
         """Whether one more member keeps the wave's prefill (members padded
@@ -5317,6 +5441,16 @@ class ContinuousBatcher:
             total += factor * bucket * self._kv_pos_bytes
         return total
 
+    def _mem_over_budget(self) -> bool:
+        """The guard's arithmetic alone: armed, and the ledger plus the
+        next wave's predicted bytes pass capacity - headroom. ``_stage``
+        asks it and leaves the deferral, its count and its fault site to
+        the drained path's one decision a boundary."""
+        return bool(
+            self.mem_headroom_bytes and self._mem_capacity and self.queue
+            and obs_memory.LEDGER.total() + self._mem_next_wave_bytes()
+            > self._mem_capacity - self.mem_headroom_bytes)
+
     def _mem_guard_defers(self) -> bool:
         """One headroom-guard decision per admission boundary. Deferral
         is pure TIMING — whatever chain a request decodes is unchanged
@@ -5339,10 +5473,9 @@ class ContinuousBatcher:
             faults.maybe_delay("serve.mem_guard")
         except faults.InjectedFault:
             return False
-        predicted = self._mem_next_wave_bytes()
-        budget = self._mem_capacity - self.mem_headroom_bytes
-        if obs_memory.LEDGER.total() + predicted <= budget:
+        if not self._mem_over_budget():
             return False
+        predicted = self._mem_next_wave_bytes()
         self.mem_deferrals += 1
         obs_metrics.MEM_GUARD_DEFERRALS.inc()
         obs_trace.instant("mem_guard_defer", cat="mem",
@@ -5475,7 +5608,57 @@ class ContinuousBatcher:
             )
             self._pending = None
 
-    def _admit_wave(self, wave: List[tuple]) -> None:
+    def _prefill_members(self, wave: List[tuple]) -> _Prefilled:
+        """First half of a full-prefill admission, of one member or of a
+        wave: everything up to the prefill's dispatch, into a cache of the
+        wave's own. Waits for nothing on the device."""
+        from eventgpt_tpu.models.eventchat import _prefill_jit, _prefill_sharded
+
+        obs_metrics.SERVE_ADMISSION_WAVE.observe(len(wave))
+        if len(wave) > 1:
+            return self._prefill_wave(wave)
+        # Single admission: the batch-1 path (its executables are the
+        # ones warmup precompiles). Medusa mode also needs the prompt's
+        # last hidden to seed the row's first draft window.
+        (req, row), = wave
+        padded, mask, prompt_len = self._prep_request(req)
+        want_hidden = self.draft_head is not None
+        with obs_trace.span("prefill", "admit", n=1,
+                            positions=int(padded.shape[1]),
+                            rid=req.rid) as prefill_span:
+            row_cache = self._new_row_cache(padded.shape[1])
+            if self.mesh is not None:
+                pre = _prefill_sharded(
+                    self.params, self.cfg, padded, mask, row_cache,
+                    self.mesh, return_hidden=want_hidden,
+                )
+            else:
+                pre = _prefill_jit(
+                    self.params, self.cfg, padded, mask, row_cache, True,
+                    return_hidden=want_hidden,
+                )
+        obs_metrics.SERVE_PREFILL_DISPATCHES.inc(kind="full")
+        if want_hidden:
+            row_logits, row_hidden, row_cache = pre
+        else:
+            (row_logits, row_cache), row_hidden = pre, None
+        return _Prefilled(wave, row_cache, row_logits, row_hidden,
+                          [prompt_len], prefill_span)
+
+    def _land(self, p: _Prefilled) -> None:
+        """Second half: readback, scatter and activation, against settled
+        state. A member that left its row since the first half (cancelled
+        or past its deadline while staged) is passed over."""
+        if len(p.members) > 1:
+            self._scatter_wave(p.members, p.cache, p.logits, p.hidden,
+                               p.prompt_lens, prefill_span=p.span)
+            return
+        (req, row), = p.members
+        if self.rows[row] is req:
+            self._finish_admission(req, row, p.prompt_lens[0], p.cache,
+                                   p.logits, p.hidden, prefill_span=p.span)
+
+    def _prefill_wave(self, wave: List[tuple]) -> _Prefilled:
         """BATCHED admission prefill (the tentpole's second half): N
         admissions ready at one dispatch boundary run ONE prefill at a
         common bucket instead of N sequential batch-1 dispatches (the
@@ -5544,8 +5727,8 @@ class ContinuousBatcher:
             wave_logits, wave_hidden, wave_cache = pre
         else:
             (wave_logits, wave_cache), wave_hidden = pre, None
-        self._scatter_wave(wave, wave_cache, wave_logits, wave_hidden,
-                           prompt_lens, prefill_span=prefill_span)
+        return _Prefilled(wave, wave_cache, wave_logits, wave_hidden,
+                          prompt_lens, prefill_span)
 
     # egpt-check: harvest -- admission NaN quarantine is a mandated readback of the wave logits before they touch the shared cache
     def _scatter_wave(self, members: List[tuple], wave_cache, wave_logits,
@@ -5572,6 +5755,10 @@ class ContinuousBatcher:
                 finite = np.isfinite(_admission_readback(
                     wave_logits, wave_cache, prefill_span)[:n]).all(axis=-1)
             for i, (req, row) in enumerate(members):
+                if self.rows[row] is not req:
+                    # Left its reserved row while the wave was staged
+                    # (cancelled, past its deadline): finished already.
+                    continue
                 if finite is not None and not finite[i]:
                     # Same per-request quarantine as the batch-1 path: the
                     # poisoned member never touches the shared cache (its

@@ -404,10 +404,10 @@ def test_a_wave_is_cut_at_the_decoders_positions(monkeypatch):
     srv = ContinuousBatcher(params, cfg, max_batch=4, max_len=512, chunk=2,
                             eos_token_id=None, prefix_cache=False)
     sizes = []
-    admit_wave = srv._admit_wave
-    monkeypatch.setattr(srv, "_admit_wave",
+    prefill_wave = srv._prefill_wave
+    monkeypatch.setattr(srv, "_prefill_wave",
                         lambda wave: (sizes.append(len(wave)),
-                                      admit_wave(wave))[1])
+                                      prefill_wave(wave))[1])
     rids = [srv.submit(*_request(rng, 6), 3) for _ in range(4)]
     out = srv.run_until_drained()
     assert sizes == [2, 2] and all(len(out[r]) == 3 for r in rids)

@@ -447,9 +447,43 @@ def test_dispatch_counts_live_rows_of_the_rows_it_pays_for(served):
     assert {p for e in admits for p in e["args"]["path"].split("+")} <= {
         "row", "wave", "suffix", "suffix_wave", "lane", "chunk"}
     assert all(e["args"]["n"] == len(e["args"]["rids"]) for e in admits)
-    assert sum(e["args"]["n"] for e in admits) == 6
+    # a staged wave has two spans, its staging and its landing
+    assert sorted({r for e in admits for r in e["args"]["rids"]}) == sorted(
+        served["rids"])
     ups = _x(served["ring"], "upload")
     assert all(e["args"]["bytes"] > 0 and e["args"]["n"] >= 1 for e in ups)
+
+
+def test_an_admission_says_whether_it_was_staged_and_keeps_its_children(served):
+    """``sched.admit`` carries ``staged``: 0 where both halves of the
+    admission ran drained, the members where the first half (upload, encode,
+    prefill) was dispatched under a segment in flight; then the landing is a
+    ``sched.admit`` of its own around the scatter. Either way the four
+    ``admit.*`` spans lie inside a ``sched.admit``."""
+    spans = [e for e in served["ring"] if e["ph"] == "X"]
+    admits = sorted(_x(spans, "admit"), key=lambda e: e["ts"])
+    assert all(e["args"]["staged"] in (0, e["args"]["n"]) for e in admits)
+    assert admits[0]["args"]["staged"] == 0          # an idle server
+    assert any(e["args"]["staged"] for e in admits)  # five at once, two rows
+
+    def inside(e):
+        return sorted(k["name"] for k in spans if k["cat"] == "admit"
+                      and e["ts"] <= k["ts"]
+                      and k["ts"] + k["dur"] <= e["ts"] + e["dur"] + 1.0)
+
+    whole = ["encode", "prefill", "scatter", "upload"]
+    halves = {0: [], 1: []}
+    for e in admits:
+        if not e["args"]["staged"]:
+            assert inside(e) == whole, e
+        else:
+            halves[inside(e) == ["scatter"]].append(e)
+    assert all(inside(e) == whole[:2] + whole[3:] for e in halves[0])
+    assert [e["args"]["rids"] for e in halves[0]] == [
+        e["args"]["rids"] for e in halves[1]]        # each staging landed
+    kids = [k for k in spans if k["cat"] == "admit"]
+    assert all(k["args"]["parent"] == "admit" for k in kids)
+    assert len(kids) == sum(len(inside(e)) for e in admits)
 
 
 def test_a_prefix_hit_records_its_lookup_and_copy_with_the_request():
