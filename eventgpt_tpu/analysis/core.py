@@ -44,7 +44,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 # Trees the suite scans (tests/ stays out on purpose: fixtures and
 # private test registries would drown every rule in noise; the telemetry
 # fault-coverage rule reads tests/ itself, for arming evidence only).
-SCAN_TREES = ("eventgpt_tpu", "scripts", "bench.py")
+SCAN_TREES = ("eventgpt_tpu", "scripts")
 
 _WAIVER_RE = re.compile(
     r"#\s*egpt-check:\s*ignore\[([A-Za-z0-9_,\- ]+)\]\s*(?:--\s*(.*))?")
@@ -313,7 +313,7 @@ def render_text(findings: Sequence[Finding],
 
 def render_json(findings: Sequence[Finding],
                 rules: Sequence[Rule]) -> str:
-    """The ``--json`` mode bench/CI tooling diffs across PRs: stable
+    """The ``--json`` mode, for tooling that diffs runs across PRs: stable
     keys, per-rule counts, waived findings carried separately."""
     live = unwaived(findings)
     waived = [f for f in findings if f.waived]

@@ -1,7 +1,7 @@
 """Jit-hygiene lint (ISSUE 8 tentpole, rule ``jit-cache``).
 
 Executable management is a convention in this repo, learned the hard
-way (PERFORMANCE.md, DISTRIBUTED.md):
+way (DISTRIBUTED.md):
 
   * configuration is DECLARED at the jit site — ``static_argnames`` /
     ``static_argnums`` / ``donate_argnums`` / ``donate_argnames`` /

@@ -51,7 +51,7 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--dtype", type=str, default="bfloat16",
                    choices=["bfloat16", "float32"])
     p.add_argument("--quant", type=str, default="none",
-                   choices=["none", "int8", "int4"])
+                   choices=["none", "int8"])
     p.add_argument("--kv_cache", type=str, default="bf16", choices=["bf16", "int8"],
                    help="KV cache storage; int8 halves cache memory/bandwidth "
                         "— the wide-batch (BASELINE config 2) serving knob")

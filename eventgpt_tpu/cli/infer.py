@@ -68,9 +68,8 @@ def build_parser() -> argparse.ArgumentParser:
                    choices=["dense", "flash"],
                    help="prefill attention kernel (default: flash on TPU)")
     p.add_argument("--quant", type=str, default="none",
-                   choices=["none", "int8", "int4"],
-                   help="weight-only quantization of the LM matmuls (int4: "
-                        "group-128 packed nibbles, half int8's HBM traffic)")
+                   choices=["none", "int8"],
+                   help="weight-only quantization of the LM matmuls")
     p.add_argument("--kv_cache", type=str, default="bf16", choices=["bf16", "int8"],
                    help="KV cache storage (int8 halves cache memory/bandwidth)")
     p.add_argument("--fuse_params", action="store_true",
@@ -162,8 +161,6 @@ def place_params(tree, jdt):
 
     if quant_mod.is_quantized(tree):
         return {"q": jnp.asarray(tree["q"]), "s": jnp.asarray(tree["s"], jnp.float32)}
-    if quant_mod.is_quantized4(tree):
-        return {"q4": jnp.asarray(tree["q4"]), "s": jnp.asarray(tree["s"], jnp.float32)}
     if isinstance(tree, dict):
         return {k: place_params(v, jdt) for k, v in tree.items()}
     if isinstance(tree, (list, tuple)):
@@ -183,13 +180,11 @@ def _fuse_and_quantize(llama, args):
         # Fuse BEFORE quantization so scales are computed on (and stream
         # with) the fused tensors (models/llama.py:fuse_llama_params).
         llama = fuse_llama_params(llama)
-    if args.quant in ("int8", "int4") and not quantized:
+    if args.quant == "int8" and not quantized:
         from eventgpt_tpu.ops.quant import quantize_llama_params
 
         llama = quantize_llama_params(
-            jax.tree_util.tree_map(np.asarray, llama), host=True,
-            bits=4 if args.quant == "int4" else 8,
-        )
+            jax.tree_util.tree_map(np.asarray, llama), host=True)
     return llama
 
 

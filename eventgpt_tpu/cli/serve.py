@@ -445,8 +445,8 @@ class ServingEngine:
             "faults": self.n_faults,
             "restarts": self.n_restarts,
             "admission_s": round(b.admission_s, 3),
-            # Pipelined-scheduler overlap story (PERFORMANCE.md): how much
-            # host scheduling the in-flight segment is hiding.
+            # Pipelined scheduler: how much host scheduling the in-flight
+            # segment is hiding (``overlap_ratio`` below).
             "pipeline": bool(getattr(b, "pipeline", False)),
             # Stall-free admission (ISSUE 5): live piggyback lanes and
             # the per-boundary prompt-token budget driving them.
@@ -1777,10 +1777,10 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--max_new_tokens", type=int, default=64)
     p.add_argument("--dtype", default="bfloat16",
                    choices=["bfloat16", "float32"])
-    p.add_argument("--quant", default="none", choices=["none", "int8", "int4"])
+    p.add_argument("--quant", default="none", choices=["none", "int8"])
     p.add_argument("--fuse_params", action="store_true",
                    help="fuse qkv / gate-up before quantization (+4%% at "
-                        "wide batches, neutral at batch 1 — PERFORMANCE.md)")
+                        "batch 8, neutral at batch 1 on the r05 chip run)")
     p.add_argument("--kv_cache", default="bf16", choices=["bf16", "int8"])
     p.add_argument("--kv_layout", default="dense",
                    choices=["dense", "paged"],
@@ -1851,8 +1851,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "(the A/B escape hatch)")
     p.add_argument("--first_chunk", type=int, default=0,
                    help="TTFT ramp: short segment length while a fresh "
-                        "admission owes its first token (0 = off; "
-                        "PERFORMANCE.md serving section for the tradeoff)")
+                        "admission owes its first token (0 = off): "
+                        "earlier first tokens for more dispatches")
     p.add_argument("--warmup", action="store_true")
     p.add_argument("--no_pipeline", action="store_true",
                    help="disable pipelined scheduling (dispatch segment "

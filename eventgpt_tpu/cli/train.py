@@ -45,11 +45,7 @@ def _extract(args: argparse.Namespace, cls):
     return cls(**{f.name: getattr(args, f.name) for f in dataclasses.fields(cls)})
 
 
-def main(argv=None):
-    from eventgpt_tpu.utils.compile_cache import enable_compile_cache
-
-    enable_compile_cache()
-    logging.basicConfig(level=logging.INFO)
+def build_parser() -> argparse.ArgumentParser:
     parser = argparse.ArgumentParser(description="EventGPT-TPU trainer")
     for cls in (ModelArguments, DataArguments, TrainingArguments):
         _add_dataclass_args(parser, cls)
@@ -65,7 +61,15 @@ def main(argv=None):
              "events here at exit (Perfetto / chrome://tracing; "
              "OBSERVABILITY.md)",
     )
-    args = parser.parse_args(argv)
+    return parser
+
+
+def main(argv=None):
+    from eventgpt_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    logging.basicConfig(level=logging.INFO)
+    args = build_parser().parse_args(argv)
 
     initialize_distributed()
 

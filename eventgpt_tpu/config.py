@@ -114,7 +114,7 @@ class LlamaConfig:
     @staticmethod
     def llama_7b() -> "LlamaConfig":
         # Flash prefill by default: measured 4.5x over dense at S=640 on
-        # v5e (bench record); decode still uses the single-query dense path.
+        # v5e (r05 chip run); decode still uses the single-query dense path.
         return LlamaConfig(attn_impl="flash")
 
     @staticmethod

@@ -284,7 +284,7 @@ class Fleet:
         self._stop = False
         self.t_start = time.time()
         self.n_requests = 0
-        # Host-side counters (bench/tests read these; the egpt_fleet_*
+        # Host-side counters (/stats and tests read these; the egpt_fleet_*
         # registry mirrors them for /metrics):
         self.n_shed: Dict[str, int] = {}
         self.n_failovers = 0
@@ -423,8 +423,8 @@ class Fleet:
         return freq.status if freq is not None else "ok"
 
     def replica_of(self, frid: int) -> int:
-        """The replica that served (or is serving) the request — test/
-        bench introspection for the affinity and failover assertions."""
+        """The replica that served (or is serving) the request: what
+        the tests' affinity and failover assertions read."""
         return self._requests[frid].replica
 
     def cancel(self, frid: int) -> bool:
@@ -562,8 +562,8 @@ class Fleet:
         return obs_series.alerts()
 
     def slo_stats(self) -> Dict[str, Any]:
-        """Aggregate per-class attainment across replicas (the bench's
-        goodput accounting for a fleet point)."""
+        """Aggregate per-class attainment across replicas (the fleet's
+        ``slo`` block of ``GET /stats``)."""
         classes: Dict[str, Dict[str, int]] = {}
         for rep in self.replicas:
             st = rep.engine.batcher.slo_stats()
@@ -575,15 +575,6 @@ class Fleet:
             c["attainment"] = (c["met"] / c["finished"]
                                if c["finished"] else 0.0)
         return {"classes": classes, "goodput_ratio": self.goodput_ratio()}
-
-    def reset_stats(self) -> None:
-        """Zero the phase-scoped host counters (the bench's per-point
-        reset; replica-level resets are the caller's, as ever)."""
-        with self._lock:
-            self.n_shed = {}
-            self.n_failovers = 0
-            self.n_kills = 0
-            self.n_route_faults = 0
 
     def shutdown(self) -> None:
         self._stop = True

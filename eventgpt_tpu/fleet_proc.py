@@ -142,8 +142,7 @@ class WorkerHandler:
 
     Ops: submit_ids / try_result / try_results / try_status / cancel /
     export_requests / snapshot / stats / memory / journey / set_prefix /
-    reset_stats / ping / shutdown / collect_handoffs / ack_handoffs /
-    import_handoff.
+    ping / shutdown / collect_handoffs / ack_handoffs / import_handoff.
 
     ``try_result`` is made IDEMPOTENT here: the engine pops a delivered
     answer, so a retried poll whose first response was lost would find
@@ -253,18 +252,6 @@ class WorkerHandler:
         if op == "set_prefix":
             return eng.set_prefix(p["prefix_prompt"],
                                   p.get("pixel_values"))
-        if op == "reset_stats":
-            b = eng.batcher
-            if hasattr(b, "reset_serving_stats"):
-                b.reset_serving_stats()
-            obs_metrics.REGISTRY.reset()
-            try:
-                from eventgpt_tpu.obs import memory as obs_memory
-
-                obs_memory.LEDGER.reset_peak()
-            except Exception:
-                pass  # stub worker: no ledger to reset
-            return True
         if op == "collect_handoffs":
             # Prefill role: drain the engine's outbox into the replay
             # dict, then serve EVERYTHING unacked — a coordinator whose
@@ -364,9 +351,6 @@ class _StubBatcher:
 
     def prefix_cache_stats(self) -> dict:
         return {"enabled": False}
-
-    def reset_serving_stats(self) -> None:
-        self.request_stats.clear()
 
 
 class _StubEngine:
@@ -1162,7 +1146,7 @@ class ProcFleet:
     def worker_of(self, frid: int) -> int:
         return self._requests[frid].worker
 
-    # bench/test shared-code alias (the thread fleet calls it replica_of)
+    # The thread fleet's name for it: tests drive both through one.
     replica_of = worker_of
 
     def cancel(self, frid: int) -> bool:
@@ -1402,28 +1386,6 @@ class ProcFleet:
             "active": sorted({r for w in workers
                               for r in w.get("active", [])}),
         }
-
-    def reset_stats(self, clear_prefix_cache: bool = False) -> None:
-        """Zero the phase-scoped counters here and in every worker
-        (the bench's per-point reset)."""
-        with self._lock:
-            self.n_failovers = 0
-            self.n_deaths = 0
-            self.n_respawns = 0
-            self.n_kills = 0
-            self.n_handoffs = 0
-            self.n_handoff_bytes = 0
-            self.n_handoff_retries = 0
-            self.n_handoff_redos = 0
-        for slot in self.slots:
-            if not slot.routable:
-                continue
-            try:
-                self._rpc(slot, "reset_stats",
-                          {"clear_prefix_cache": clear_prefix_cache},
-                          deadline_s=10.0)
-            except rpc.RpcError:
-                continue
 
     def journey(self, frid: int) -> Optional[Dict[str, Any]]:
         """Coordinator timeline (route / worker_lost / failover / repin

@@ -599,11 +599,9 @@ SPEC_LOOKUP_MAX = 8
 
 def _vocab_size(params: Params) -> int:
     """Actual vocab from the lm_head leaf (special-token registration can
-    grow it past cfg.llama.vocab_size; int4 packs the contraction dim, the
-    vocab (last) dim is unpacked either way)."""
+    grow it past cfg.llama.vocab_size)."""
     head = params["llama"]["lm_head"]
-    leaf = (head.get("q", head.get("q4")) if isinstance(head, dict)
-            else head)
+    leaf = head["q"] if isinstance(head, dict) else head
     return int(leaf.shape[-1])
 
 
@@ -871,7 +869,7 @@ def _spec_loop_jit(
     hidden), and each verify step emits the next window's drafts from the
     correction position's hidden — same exactness contracts either way.
 
-    Decode at batch 1 is weight-bandwidth-bound (PERFORMANCE.md): one
+    Decode at batch 1 is weight-bandwidth-bound (PERF.md section 5): one
     ``decode_step`` streams ~3.4 GB of int8 weights to emit ONE token. A
     ``decode_kstep`` window streams the same bytes to score ``window``
     candidate positions, so every accepted draft token is a whole

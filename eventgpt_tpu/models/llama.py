@@ -656,7 +656,7 @@ def decode_kstep(
     [0, length+i] — exactly what ``decode_step`` would have seen feeding the
     window one token at a time, so greedy argmax over these logits equals the
     sequential greedy chain (the speculative path's correctness contract).
-    Weight streaming is the decode bottleneck (PERFORMANCE.md): the K-row
+    Weight streaming is the decode bottleneck (PERF.md section 5): the K-row
     GEMMs read the same bytes as one decode_step, which is why verifying K
     tokens costs ~one token's wall time at batch 1.
     """

@@ -15,7 +15,7 @@ affects only speed, never correctness — tested with random heads in
 
 TPU shape: one residual SiLU block per head, stacked as a single
 (K, D, D) einsum so all heads run in one MXU matmul; logits reuse the
-frozen (possibly int8/int4-quantized) lm_head. Heads initialize to ZERO,
+frozen (possibly int8-quantized) lm_head. Heads initialize to ZERO,
 making each head's logits exactly the base model's next-token logits (the
 paper's identity start) — training only has to learn the *offset* from
 that baseline.

@@ -5,13 +5,13 @@ compute dtype needs as much HBM before quantization even starts. So a
 full-width start without a checkpoint builds the tree the way a quantized
 load ends up: on the host, leaf by leaf, directly at the final (fused /
 quantized) shapes. ``load_model`` serves it under ``--model_path
-eventgpt-7b-random``, and ``bench.py`` builds its trees with it.
+eventgpt-7b-random``.
 
 The values are random, not zeros: zero weights multiply a wrong kernel's
 output away. Matmul weights are uniform int8 values times a
 per-output-channel scale chosen so that ``x @ W`` keeps the variance the
 real init (``init_*_params``: normal / sqrt(fan_in)) gives it — stored as
-``{"q", "s"}`` under int8, nibble-packed under int4, multiplied out in the
+``{"q", "s"}`` under int8, multiplied out in the
 compute dtype otherwise. Norm scales are ones and biases zeros, as in the
 real init; ``tests/test_synthetic.py`` holds the two trees to one
 structure.
@@ -31,9 +31,8 @@ from eventgpt_tpu.config import EventChatConfig
 # (``tiny-random`` keeps its on-device init, ``cli/infer.load_model``).
 SYNTHETIC_7B = "eventgpt-7b-random"
 
-# Std of an integer uniform on [-128, 127] / on [-8, 7].
+# Std of an integer uniform on [-128, 127].
 _INT8_STD = math.sqrt((256 ** 2 - 1) / 12.0)
-_INT4_STD = math.sqrt((16 ** 2 - 1) / 12.0)
 # Lookup tables start the residual stream at unit RMS, and the projections
 # that write into it are scaled down by sqrt(2 * layers): what a token is
 # then survives to the head. With the real init's 0.02 tables the stream is
@@ -42,7 +41,7 @@ _INT4_STD = math.sqrt((16 ** 2 - 1) / 12.0)
 # as zero weights do.
 _LOOKUP_TABLES = ("embed_tokens", "position_embedding", "class_embedding")
 _BRANCH_OUT = ("o", "down", "fc2")
-_LEAF_PARTS = ("q", "q4", "s", "kernel", "bias")
+_LEAF_PARTS = ("q", "s", "kernel", "bias")
 
 
 def served_shapes(cfg: EventChatConfig, dtype, quant: str, fuse: bool):
@@ -59,9 +58,8 @@ def served_shapes(cfg: EventChatConfig, dtype, quant: str, fuse: bool):
     def transform(p):
         if fuse:
             p = llama_mod.fuse_llama_params(p)
-        if quant in ("int8", "int4"):
-            p = quant_mod.quantize_llama_params(
-                p, bits=4 if quant == "int4" else 8)
+        if quant == "int8":
+            p = quant_mod.quantize_llama_params(p)
         return p
 
     if eventchat.decoder_of(cfg) is not llama_mod:
@@ -97,17 +95,11 @@ def _fill(keys: Sequence[Any], leaf, siblings: Dict[str, Any],
     n_stacked = shape[0] if "layers" in keys and len(shape) == 3 else 0
     if dtype == np.int8:  # an int8 leaf's payload
         return _int8(rng, shape)
-    if dtype == np.uint8:  # an int4 leaf: two offset-binary nibbles a byte
-        return _int8(rng, shape).view(np.uint8)
-    if name == "s" and ("q" in siblings or "q4" in siblings):
-        # Per-channel (int8) / per-group (int4) scales, a little uneven so
-        # that a kernel which mislays them changes the answer.
-        if "q4" in siblings:
-            fan_in, grid_std = 2 * siblings["q4"].shape[-2], _INT4_STD
-        else:
-            fan_in, grid_std = siblings["q"].shape[-2], _INT8_STD
-        std = _weight_std(weight, fan_in, n_stacked)
-        return (rng.uniform(0.9, 1.1, shape) * std / grid_std
+    if name == "s" and "q" in siblings:
+        # Per-channel scales, a little uneven so that a kernel which
+        # mislays them changes the answer.
+        std = _weight_std(weight, siblings["q"].shape[-2], n_stacked)
+        return (rng.uniform(0.9, 1.1, shape) * std / _INT8_STD
                 ).astype(np.float32)
     if name == "scale" or name.endswith("norm"):
         return np.ones(shape, dtype)

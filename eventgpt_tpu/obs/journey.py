@@ -36,8 +36,8 @@ and be recorded the moment the recorder arms).
 Armed/disarmed like ``trace.py``: disarmed (the default) every probe is
 one module-global ``is None`` check — no timestamps read, no objects
 allocated. Recording reads host clocks and host ints ONLY, never jax
-values, so decoded chains are byte-identical armed or disarmed (tested,
-and re-measured in the workload bench's interleaved A/B). Retention:
+values, so decoded chains are byte-identical armed or disarmed
+(``tests/test_replay_identity.py``, arm ``telemetry_armed``). Retention:
 live timelines plus a ring of the last ``keep`` finished requests
 (``--journey_keep``), snapshotted by ``GET /requests`` /
 ``GET /request?rid=N``.

@@ -1,8 +1,8 @@
 """HBM memory ledger — per-component device-byte accounting (ISSUE 9).
 
 The serving stack has deep latency/goodput observability but was blind
-on the axis that actually caps it: HBM. The batch ceiling (40 OOMs at
-runtime, 48 at compile — PERFORMANCE.md "Batch scaling") and the prefix
+on the axis that actually caps it: HBM. The batch ceiling (on the r05
+chip run 40 rows ran out at run time, 48 at compile) and the prefix
 cache's byte budget both manage memory with no visibility into what the
 rest of the process holds. This module is the instrument that says
 where every byte lives, BEFORE the paged-KV block-pool refactor
@@ -75,8 +75,7 @@ class MemoryLedger:
     Thread-safety: the scheduler thread registers/releases while HTTP
     handler threads read ``summary()`` — every mutation and compound
     read takes ``_lock``. Peak tracking (``peak_bytes``) is phase-scoped
-    via ``reset_peak()`` (the bench's per-point reset, like
-    ``reset_serving_stats``)."""
+    via ``reset_peak()`` (like ``reset_serving_stats``)."""
 
     # Lock-discipline contract (egpt-check rule ``lock``): byte counters
     # and the entry map only move under the ledger lock. The last
@@ -157,8 +156,7 @@ class MemoryLedger:
             self._component_totals.get(component, 0), component=component)
 
     def reset_peak(self) -> None:
-        """Phase-scope the peak to the traffic that follows (the bench's
-        per-point reset)."""
+        """Phase-scope the peak to the traffic that follows."""
         with self._lock:
             self.peak_bytes = self.total_bytes
             obs_metrics.MEM_PEAK.set(self.peak_bytes)
@@ -186,7 +184,7 @@ class MemoryLedger:
             return out
 
     def summary(self) -> Dict[str, Any]:
-        """The /stats merge + bench record body: ledger totals plus the
+        """The /stats merge body: ledger totals plus the
         LAST reconcile's accounted/unaccounted split (None until one
         ran) — all host ints, no jax walk."""
         with self._lock:
@@ -208,7 +206,7 @@ class MemoryLedger:
         in flight, jit constants, leaked test fixtures) shows up here
         as unaccounted instead of silently vanishing. Costly relative
         to a counter read (walks every live buffer) — called from
-        GET /memory and bench points, never per scheduler step."""
+        GET /memory, never per scheduler step."""
         import jax
 
         live = 0
@@ -248,7 +246,7 @@ def params_bytes(tree: Any) -> int:
 def abstract_params_bytes(cfg, quant: str = "bf16", dtype_bytes: int = 2
                           ) -> int:
     """Weight-tree bytes WITHOUT materializing weights: ``eval_shape``
-    the init + (optional) int8/int4 quantization transform and sum the
+    the init + (optional) int8 quantization transform and sum the
     abstract leaf sizes — the 13B static-capacity check's weights term
     (the same never-materialize discipline as test_13b_readiness)."""
     import jax
@@ -262,14 +260,11 @@ def abstract_params_bytes(cfg, quant: str = "bf16", dtype_bytes: int = 2
         lambda k: eventchat.init_eventchat_params(cfg, k, dtype),
         jax.random.PRNGKey(0),
     )
-    if quant in ("int8", "int4"):
+    if quant == "int8":
         shapes = {
             **shapes,
-            "llama": jax.eval_shape(
-                lambda p: quant_mod.quantize_llama_params(
-                    p, bits=4 if quant == "int4" else 8),
-                shapes["llama"],
-            ),
+            "llama": jax.eval_shape(quant_mod.quantize_llama_params,
+                                    shapes["llama"]),
         }
     total = 0
     for leaf in jax.tree_util.tree_leaves(shapes):

@@ -17,8 +17,7 @@ Rules (enforced statically by ``scripts/lint_telemetry.py``):
 Thread-safety: every mutation takes the metric's lock (scheduler,
 handler and trainer threads all observe). Cost: a histogram observe is
 one bisect + three dict writes under a lock — sub-microsecond, a few
-dozen per decode segment, measured <2% of serve throughput end to end
-(PERFORMANCE.md "Telemetry overhead").
+dozen per decode segment.
 
 Histograms are FIXED-BUCKET log2: upper bounds at powers of two, so
 bucket assignment is a bisect over ~30 floats, merging across processes
@@ -467,8 +466,8 @@ class Registry:
         return self._register(Histogram(name, help, self, buckets))
 
     def configure(self, enabled: bool) -> None:
-        """Arm/disarm every metric in this registry (the A/B switch the
-        overhead bench and the chain-neutrality test flip)."""
+        """Arm/disarm every metric in this registry (``--no_telemetry``;
+        the switch the chain-neutrality tests flip)."""
         self.enabled = bool(enabled)
 
     def set_common_labels(self, **labels) -> None:
@@ -480,8 +479,8 @@ class Registry:
                 sorted((k, str(v)) for k, v in labels.items()))
 
     def reset(self) -> None:
-        """Zero every value (registration survives) — phase-scoped
-        measurement, e.g. bench excluding its warmup traffic."""
+        """Zero every value (registration survives): a measurement
+        window that leaves its warm-up traffic out."""
         with self._lock:
             metrics = list(self._metrics.values())
         for m in metrics:
@@ -669,8 +668,7 @@ SERVE_SLO_MISS_CAUSE = REGISTRY.counter(
 #    eventgpt_tpu/fleet.py) --
 # Aggregate-only on purpose: a per-replica label would be computed
 # (str(idx) — lint rule 5 bans it); per-replica numbers live in the
-# fleet's /stats JSON and the bench artifact, read from each replica's
-# host-side counters.
+# fleet's /stats JSON, read from each replica's host-side counters.
 FLEET_REPLICAS = REGISTRY.gauge(
     "egpt_fleet_replicas", "Configured replicas in the fleet")
 FLEET_ROUTABLE = REGISTRY.gauge(
@@ -702,8 +700,7 @@ FLEET_REPLICA_DEATHS = REGISTRY.counter(
 # -- process fleet: worker processes behind the RPC coordinator
 #    (ISSUE 11, eventgpt_tpu/fleet_proc.py + rpc.py) --
 # Aggregate-only like the egpt_fleet_* family (a per-slot label would
-# be computed — lint rule 5); per-worker numbers live in /fleet and
-# the PROCFLEET bench artifact.
+# be computed — lint rule 5); per-worker numbers live in /fleet.
 PROCFLEET_WORKERS = REGISTRY.gauge(
     "egpt_procfleet_workers",
     "Configured worker-process slots in the process fleet")

@@ -45,8 +45,8 @@ Armed/disarmed like ``trace.py``/``journey.py``: disarmed (the
 default) every probe is one module-global ``is None`` check. Sampling
 reads host clocks and the registry's host floats ONLY — never jax
 values — so decoded chains are byte-identical armed or disarmed
-(tests/test_series.py, re-measured in the workload bench's
-interleaved A/B). Exports are **duration-aligned** (ages relative to
+(tests/test_series.py; tests/test_replay_identity.py, arm
+``telemetry_armed``). Exports are **duration-aligned** (ages relative to
 the store's own now, like the journey stitcher), so a coordinator can
 merge worker series across process-clock domains.
 """

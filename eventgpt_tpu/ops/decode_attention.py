@@ -1,11 +1,11 @@
 """Pallas fused int8-KV decode attention (single query over the HBM cache).
 
 Why this kernel exists: batch-1 decode at 7B streams the whole weight set
-per token (PERFORMANCE.md), and the KV cache is the next-largest stream —
+per token, and the KV cache is the next-largest stream —
 ~0.5-0.7 GB/token bf16 at the reference's 512-token budget. The int8 cache
 halves those bytes, but through plain XLA the dequantize (int8 * f32 scale
 -> bf16) costs more VPU time than the bandwidth it saves: measured a WASH
-at batch 1 (12.3 vs 11.9 ms/token, PERFORMANCE.md negative results). This
+at batch 1 (12.3 vs 11.9 ms/token on the r05 chip run, 2026-07-31). This
 kernel performs the dequant in VMEM fused into the attention dots, so HBM
 traffic actually drops to the int8 payload + per-vector scales and the
 wash becomes a win.

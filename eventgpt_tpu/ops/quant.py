@@ -27,15 +27,10 @@ from typing import Any, Dict
 import jax.numpy as jnp
 
 QuantizedLeaf = Dict[str, jnp.ndarray]  # {"q": int8 [..., K, N], "s": f32 [..., 1, N]}
-# int4 leaf: {"q4": uint8 [..., K/2, N], "s": f32 [..., K/G, N]} — see quantize_tensor4.
 
 
 def is_quantized(leaf: Any) -> bool:
     return isinstance(leaf, dict) and "q" in leaf and "s" in leaf
-
-
-def is_quantized4(leaf: Any) -> bool:
-    return isinstance(leaf, dict) and "q4" in leaf and "s" in leaf
 
 
 def is_lora(leaf: Any) -> bool:
@@ -67,103 +62,6 @@ def dequantize_tensor(leaf: QuantizedLeaf, dtype=jnp.float32) -> jnp.ndarray:
     return (leaf["q"].astype(jnp.float32) * leaf["s"]).astype(dtype)
 
 
-def quantize_tensor4(w: jnp.ndarray, group: int = 128) -> QuantizedLeaf:
-    """Group-wise symmetric int4 quantization of a (K, N) matmul weight,
-    packed two rows per byte.
-
-    Packing is along the CONTRACTION axis: byte ``[r, n]`` holds rows
-    ``2r`` (high nibble) and ``2r+1`` (low nibble), stored offset-binary
-    (``value + 8``). That layout needs **no interleave at unpack time** —
-    ``x @ W == x[0::2] @ hi_plane + x[1::2] @ lo_plane`` where each plane is
-    a plain shift/mask of the packed bytes, so the dequantize stays a fusable
-    elementwise producer feeding the dot (HBM streams 0.5 bytes/weight).
-
-    Scales are per (group, out-channel): ``s[g, n] = max|w[gG:(g+1)G, n]|/7``
-    over ``group`` contraction rows (int4's range is too coarse for the
-    per-channel scheme int8 uses). ``group`` must divide K and be even;
-    ``group=0`` means one group (per-channel).
-    """
-    return _quantize4_impl(w, group, jnp)
-
-
-def _quantize4_impl(w, group: int, xp) -> QuantizedLeaf:
-    """Shared int4 math, parameterized on the array namespace (jnp on
-    device, numpy on host) so the two paths cannot drift."""
-    K, N = w.shape[-2], w.shape[-1]
-    if group <= 0:
-        group = K
-    if K % group or group % 2:
-        raise ValueError(f"group {group} must be even and divide K={K}")
-    w32 = xp.asarray(w).astype(xp.float32)
-    gshape = w32.shape[:-2] + (K // group, group, N)
-    wg = w32.reshape(gshape)
-    amax = xp.max(xp.abs(wg), axis=-2, keepdims=True)  # (..., K/G, 1, N)
-    scale = xp.maximum(amax, 1e-8) / 7.0
-    q = xp.clip(xp.round(wg / scale), -8, 7).astype(xp.int32).reshape(
-        w32.shape[:-2] + (K, N)
-    )
-    even, odd = q[..., 0::2, :] + 8, q[..., 1::2, :] + 8
-    packed = ((even << 4) | odd).astype(xp.uint8)  # (..., K/2, N)
-    return {"q4": packed, "s": scale[..., 0, :].astype(xp.float32)}  # (..., K/G, N)
-
-
-def _unpack4(q4: jnp.ndarray, dtype) -> tuple:
-    """Packed (..., K/2, N) uint8 -> (hi, lo) planes of the same shape in
-    ``dtype``: hi = even contraction rows, lo = odd."""
-    hi = (q4 >> 4).astype(jnp.int8) - 8
-    lo = (q4 & 0xF).astype(jnp.int8) - 8
-    return hi.astype(dtype), lo.astype(dtype)
-
-
-def dequantize_tensor4(leaf: QuantizedLeaf, dtype=jnp.float32) -> jnp.ndarray:
-    hi, lo = _unpack4(leaf["q4"], jnp.float32)
-    *lead, half_k, n = hi.shape
-    k = 2 * half_k
-    w = jnp.stack([hi, lo], axis=-2)  # (..., K/2, 2, N)
-    w = w.reshape(*lead, k, n)
-    gc = leaf["s"].shape[-2]
-    w = w.reshape(*lead, gc, k // gc, n) * leaf["s"][..., :, None, :]
-    return w.reshape(*lead, k, n).astype(dtype)
-
-
-def _matmul4(x: jnp.ndarray, leaf: QuantizedLeaf) -> jnp.ndarray:
-    """x (..., K) @ int4 leaf -> (..., N) f32 accumulator.
-
-    Dispatches to the Pallas kernel (``ops/int4_matmul.py``) when the
-    shapes meet its alignment contract — XLA materializes the nibble
-    unpack through HBM, which defeats int4's whole purpose (measured
-    slower than int8); the kernel dequantizes in VMEM. The XLA grouped
-    two-plane einsum remains the fallback for unaligned (tiny-model)
-    shapes."""
-    q4, s = leaf["q4"], leaf["s"]
-    if q4.ndim == 2:
-        from eventgpt_tpu.ops import int4_matmul as i4k
-
-        k = 2 * q4.shape[-2]
-        group = k // s.shape[-2]
-        if i4k.supported(k, q4.shape[-1], group):
-            lead = x.shape[:-1]
-            y = i4k.int4_matmul(x.reshape(-1, k), q4, s)
-            return y.reshape(*lead, q4.shape[-1])
-    if q4.ndim != 2:
-        raise ValueError("int4 matmul expects a per-layer (K/2, N) plane; "
-                         "stacked trees are sliced by the layer scan")
-    half_k, n = q4.shape
-    k = 2 * half_k
-    gc = s.shape[-2]
-    hg = half_k // gc  # packed rows per group
-    hi, lo = _unpack4(q4, x.dtype)
-    lead = x.shape[:-1]
-    xg = x.reshape(-1, gc, hg, 2)  # (..., g, packed-row, parity)
-    xe, xo = xg[..., 0], xg[..., 1]
-    part = jnp.einsum("bgk,gkn->bgn", xe, hi.reshape(gc, hg, n),
-                      preferred_element_type=jnp.float32)
-    part += jnp.einsum("bgk,gkn->bgn", xo, lo.reshape(gc, hg, n),
-                       preferred_element_type=jnp.float32)
-    y = jnp.einsum("bgn,gn->bn", part, s, preferred_element_type=jnp.float32)
-    return y.reshape(*lead, n)
-
-
 def _lora_branch_input(x: jnp.ndarray, w: Any) -> jnp.ndarray:
     """Adapter-branch input, with inverted dropout when the composite leaf
     carries per-layer mask state (``train/lora.py:apply_lora`` with a step
@@ -188,8 +86,6 @@ def matmul(x: jnp.ndarray, w: Any) -> jnp.ndarray:
         xl = _lora_branch_input(x, w)
         delta = jnp.matmul(xl, w["a"].astype(x.dtype)) @ w["b"].astype(x.dtype)
         return matmul(x, w["w"]) + delta
-    if is_quantized4(w):
-        return _matmul4(x, w).astype(x.dtype)
     if is_quantized(w):
         y = jnp.matmul(
             x, w["q"].astype(x.dtype), preferred_element_type=jnp.float32
@@ -204,8 +100,6 @@ def matmul_f32_out(x: jnp.ndarray, w: Any) -> jnp.ndarray:
         xl = _lora_branch_input(x, w)
         delta = jnp.matmul(xl, w["a"].astype(x.dtype)) @ w["b"].astype(x.dtype)
         return matmul_f32_out(x, w["w"]) + delta.astype(jnp.float32)
-    if is_quantized4(w):
-        return _matmul4(x, w)
     if is_quantized(w):
         y = jnp.matmul(
             x, w["q"].astype(x.dtype), preferred_element_type=jnp.float32
@@ -227,37 +121,14 @@ def quantize_tensor_host(w) -> QuantizedLeaf:
     return _quantize8_impl(w, np)
 
 
-def quantize_tensor4_host(w, group: int = 128) -> QuantizedLeaf:
-    """Numpy-side ``quantize_tensor4`` (same rationale as
-    ``quantize_tensor_host``: quantize before device placement)."""
-    import numpy as np
-
-    return _quantize4_impl(w, group, np)
-
-
-def quantize_llama_params(params: Dict[str, Any], host: bool = False,
-                          bits: int = 8, group: int = 128) -> Dict[str, Any]:
+def quantize_llama_params(params: Dict[str, Any],
+                          host: bool = False) -> Dict[str, Any]:
     """Quantize every matmul weight of a llama param tree (embeddings and
     norms untouched). Stacked-layer leaves (L, K, N) quantize per layer and
     channel; the scan over layers slices ``q``/``s`` together.
 
-    ``host=True`` runs the numpy path (see ``quantize_tensor_host``);
-    ``bits=4`` selects the packed group-wise int4 scheme (``group`` rows per
-    scale)."""
-    if bits == 4:
-        # Per-leaf group clamp: leaves whose contraction dim is smaller than
-        # (or not divisible by) the requested group fall back to one group
-        # over the whole K (per-channel) — small models stay quantizable
-        # without the caller knowing every layer's K.
-        def qt(w):
-            k = w.shape[-2]
-            g = group if group > 0 and k % group == 0 else k
-            return (quantize_tensor4_host(w, g) if host
-                    else quantize_tensor4(w, g))
-    elif bits == 8:
-        qt = quantize_tensor_host if host else quantize_tensor
-    else:
-        raise ValueError(f"unsupported bits={bits} (4 or 8)")
+    ``host=True`` runs the numpy path (see ``quantize_tensor_host``)."""
+    qt = quantize_tensor_host if host else quantize_tensor
     out = {k: v for k, v in params.items()}
     out["lm_head"] = qt(params["lm_head"])
     layers = dict(params["layers"])

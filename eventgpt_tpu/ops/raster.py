@@ -134,9 +134,9 @@ def events_to_structured_stream(events: EventDict) -> np.ndarray:
     """{x,y,t,p} dict -> the native reader's structured-array layout.
 
     The reference's samples are pickled dicts the native reader
-    deliberately does not parse; this is the conversion every harness
-    (``scripts/stream_demo.py``, ``bench.py --mode stream``) uses to
-    replay them through ``native.EventStream``.
+    deliberately does not parse; this is the conversion
+    ``scripts/stream_demo.py`` uses to replay them through
+    ``native.EventStream``.
     """
     n = len(events["t"])
     arr = np.zeros(n, dtype=STREAM_DTYPE)

@@ -5,7 +5,7 @@ The reference serves its frozen LLM on one GPU — torch module + HF generate
 north star is the same surface over a pod: HF weights loaded into a
 pjit-sharded FSDP/TP layout with the KV cache resident in HBM. This module
 is the serving half of ``parallel/sharding.py``: it places an EventChat
-param tree (plain, int8, int4 or LoRA-composite leaves) and a KV cache onto
+param tree (plain, int8 or LoRA-composite leaves) and a KV cache onto
 a ``Mesh`` so the existing jit'd prefill/decode units compile to one SPMD
 program — computation follows data, XLA inserts the collectives (fsdp
 all-gathers, model-axis psums).
@@ -52,7 +52,7 @@ from eventgpt_tpu.parallel.sharding import eventchat_param_specs
 def _scale_spec(spec: P) -> P:
     """Spec for a quantization-scale leaf: same rank as the weight spec with
     the contraction (second-to-last) axis replicated — int8 scales have a
-    size-1 dim there, int4 group counts need not divide ``fsdp``."""
+    size-1 dim there."""
     parts = list(spec) + [None] * 0
     if len(parts) >= 2:
         parts[-2] = None
@@ -66,13 +66,10 @@ def _put(x, mesh: Mesh, spec: P, dtype=None):
 
 def _shard_tree(tree: Any, spec: Any, mesh: Mesh, dtype) -> Any:
     """Recursive quant-aware placement. ``spec`` mirrors ``tree`` except at
-    composite leaves ({"q","s"} / {"q4","s"} / {"w","a","b"}), where one
+    composite leaves ({"q","s"} / {"w","a","b"}), where one
     PartitionSpec covers the whole composite."""
     if quant_mod.is_quantized(tree):
         return {"q": _put(tree["q"], mesh, spec),
-                "s": _put(tree["s"], mesh, _scale_spec(spec), jnp.float32)}
-    if quant_mod.is_quantized4(tree):
-        return {"q4": _put(tree["q4"], mesh, spec),
                 "s": _put(tree["s"], mesh, _scale_spec(spec), jnp.float32)}
     if quant_mod.is_lora(tree):
         rep = P(*([None] * (len(spec) if spec else 0)))

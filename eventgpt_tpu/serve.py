@@ -21,7 +21,7 @@ TPU-shaped design (everything jit-visible is static-shape):
     per-row budgets and a frozen mask). Between segments the host harvests
     finished rows and admits queued requests — the segment size is the
     scheduling latency, and at 32 tokens the extra dispatch overhead is
-    ~2-3% of decode (PERFORMANCE.md: whole-budget vs 64-token budgets).
+    ~2-3% of decode (r05 chip run: whole-budget vs 64-token budgets).
   * Frozen/free rows keep flowing through the fused step (a ``lax.cond``
     skip would break the donated cache aliasing — same reasoning as
     ``_decode_loop_jit``); their writes land above their frozen lengths —
@@ -487,8 +487,8 @@ class PrefixCache:
 
     def __del__(self):
         # A replaced/dropped cache must not leave stale bytes in the
-        # memory ledger (the bench swaps in a fresh cache per measured
-        # point). Best-effort: interpreter teardown may have torn the
+        # memory ledger (``reset_prefix_cache`` swaps in a fresh one).
+        # Best-effort: interpreter teardown may have torn the
         # ledger down first.
         try:
             obs_memory.LEDGER.release("prefix_cache", self._mem_key)
@@ -496,7 +496,7 @@ class PrefixCache:
             pass
 
     def clear(self) -> None:
-        """Drop every entry (the bench's per-leg reset): paged entries
+        """Drop every entry: paged entries
         release their block runs through the same deferred-on-pins rule
         as eviction, the trie/bytes reset, counters KEEP counting (a
         fresh-counter reset is ``ContinuousBatcher.reset_prefix_cache``,
@@ -1590,7 +1590,7 @@ class _Request:
     prompt_len: int = 0
     # Service timestamps (time.perf_counter at submit / first committed
     # token / completion) — the continuous-batching latency story: TTFT
-    # and completion latency per request, aggregated by bench --mode serve.
+    # and completion latency per request (``request_stats``, SLO scoring).
     t_submit: float = 0.0
     t_first: Optional[float] = None
     t_done: Optional[float] = None
@@ -1632,7 +1632,7 @@ class _Request:
     # re-queued, ``spill_run`` names its BlockPool spill registry entry
     # (None = the drop-and-re-prefill path, or never preempted) and the
     # SpillStore holds its gathered KV under ``rid``. ``preempts``
-    # counts evictions (observability; bench records it per request).
+    # counts evictions (observability: ``request_stats``).
     spill_run: Optional[int] = None
     preempts: int = 0
     # Prefill/decode disaggregation (ISSUE 17): on a decode-role worker,
@@ -1820,7 +1820,7 @@ class ContinuousBatcher:
             # Default pool = dense-equivalent capacity (+1 scratch): the
             # layout change alone never shrinks what fits. Operators cap
             # it lower (--kv_pool_blocks) to trade peak concurrency for
-            # HBM — the bench's paged batch-sweep leg does exactly that.
+            # HBM.
             n_blocks = int(kv_pool_blocks) or (max_batch * self._nbpr + 1)
             min_blocks = (2 * SEQ_BUCKET) // SEQ_BUCKET + 1
             if n_blocks < min_blocks:
@@ -2144,7 +2144,7 @@ class ContinuousBatcher:
     def __del__(self):
         # A dropped batcher must not leave stale owner-keyed bytes in
         # the memory ledger (multi-server processes: fleet rebuilds,
-        # bench legs, tests). The shared weight-tree entry stays — the
+        # tests). The shared weight-tree entry stays — the
         # tree may outlive this server. Best-effort: interpreter
         # teardown may have torn the ledger down first.
         owner = getattr(self, "_mem_owner", None)
@@ -3044,8 +3044,8 @@ class ContinuousBatcher:
         return out
 
     def reset_prefix_cache(self) -> None:
-        """Swap in a fresh (same-budget) prefix cache — the bench's
-        per-measured-point reset. This is THE supported reset: replacing
+        """Swap in a fresh (same-budget) prefix cache with fresh
+        counters. This is THE supported reset: replacing
         ``_prefix_cache`` by hand would orphan a paged cache's pinned
         block runs (their refs would never decref — the pool drains
         monotonically until admission livelocks on the block gate).
@@ -3223,7 +3223,7 @@ class ContinuousBatcher:
         """SLO-attainment snapshot (ISSUE 6): per-class finished/met
         counts + attainment ratio, and the windowed goodput ratio —
         host-side counters, so the numbers exist with telemetry disarmed
-        (the `/stats` merge and the bench read them here; /metrics
+        (the `/stats` merge reads them here; /metrics
         exposes the same story as ``egpt_serve_slo_*``)."""
         classes: Dict[str, Dict[str, Any]] = {}
         for (name, met), n in sorted(self.slo_counts.items()):
@@ -3246,12 +3246,14 @@ class ContinuousBatcher:
         """Realized aggregate acceptance: committed tokens per verify
         iteration (= per weight-streaming pass, summed across batch rows
         — exceeds the per-chain window bound when several rows are
-        active). THE definition; /stats and the bench both read it here."""
+        active). THE definition; /stats and
+        ``scripts/medusa_acceptance.py`` both read it here."""
         return self.spec_tokens / max(self.spec_iterations, 1)
 
     def spec_stats(self) -> Dict[str, Any]:
-        """Adaptive-speculation snapshot (ISSUE 13): the bench columns
-        (accepted tokens per dispatch, mean chosen window, masked rows)
+        """Adaptive-speculation snapshot (ISSUE 13): the ``spec`` block
+        of ``GET /stats`` (accepted tokens per dispatch, mean chosen
+        window, masked rows)
         plus the controller's own state. Host-side counters — available
         with telemetry disarmed, the prefix-cache counter convention."""
         out: Dict[str, Any] = {
@@ -3279,7 +3281,7 @@ class ContinuousBatcher:
         self.spec_tokens = 0
         # Adaptive speculation (ISSUE 13), phase-scoped like the
         # acceptance counters above: dispatches + chosen-window sum
-        # (their ratio is the bench's spec_depth_mean), rows masked
+        # (their ratio is ``spec_stats``' depth_mean), rows masked
         # below full depth, and the bounded chosen-window trace the
         # replay-determinism test compares run-to-run. Controller EMA
         # state is NOT reset — it is live policy, not a statistic.
@@ -3287,8 +3289,7 @@ class ContinuousBatcher:
         self.spec_depth_sum = 0
         self.spec_masked_rows = 0
         self.spec_depth_trace: deque = deque(maxlen=4096)
-        # Pipeline overlap accounting (all host-observable, definitions in
-        # PERFORMANCE.md "Pipelined scheduling"):
+        # Pipeline overlap accounting (all host-observable):
         #   device_segment_s  — host time BLOCKED waiting on the device
         #                       (the visible, un-hidden device time);
         #   host_gap_s        — host scheduler time between a fetch
@@ -3304,15 +3305,14 @@ class ContinuousBatcher:
         self.host_gap_s = 0.0
         self.overlap_hidden_s = 0.0
         self._t_prev_fetch_end: Optional[float] = None
-        # Stall-free admission evidence (ISSUE 5, definitions in
-        # PERFORMANCE.md "Stall-free admission"): mixed_boundaries counts
+        # Stall-free admission evidence (ISSUE 5): mixed_boundaries counts
         # harvested segments that carried live piggyback lanes alongside
         # live decode rows; mixed_zero_harvests counts those where the
         # decode rows committed ZERO tokens — by construction this stays
-        # 0 (a live row commits at least one token per segment), and the
-        # bench asserts it: in-flight rows receive tokens during every
-        # admission boundary. mixed_prefill_tokens totals the prompt
-        # positions advanced inside mixed segments.
+        # 0 (a live row commits at least one token per segment), and
+        # tests/test_mixed_segments.py asserts it: in-flight rows receive
+        # tokens during every admission boundary. mixed_prefill_tokens
+        # totals the prompt positions advanced inside mixed segments.
         self.mixed_boundaries = 0
         self.mixed_zero_harvests = 0
         self.mixed_prefill_tokens = 0

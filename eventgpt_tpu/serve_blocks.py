@@ -317,7 +317,7 @@ class BlockPool:
         obs_metrics.SERVE_KV_BLOCKS_USED.set(self.usable - len(self._free))
 
     def stats(self) -> Dict[str, Any]:
-        """Snapshot for ``GET /memory`` / bench records (lock-held)."""
+        """Snapshot for ``GET /memory`` (lock-held)."""
         with self._lock:
             free = len(self._free)
             n_spilled = len(self._spilled)
@@ -348,8 +348,7 @@ class SpillStore:
     to drop-and-re-prefill) and the refusal count is the exhaustion
     signal the 503 admission path keys on. Resident bytes are priced
     into the memory ledger under the ``spill`` component so
-    ``GET /memory`` and the bench records see the host tier next to the
-    device tiers.
+    ``GET /memory`` shows the host tier next to the device tiers.
 
     Thread contract: the owning batcher is externally serialized but
     HTTP handler threads read ``stats()`` — mutations run under

@@ -1,8 +1,8 @@
 """Adaptive speculation controller (ISSUE 13 tentpole, ROADMAP item 4).
 
-``speculative=K`` was a server-lifetime constant, yet the measured spec
-spread on this repo's own bench is ~8x (564-583 tok/s ceiling vs a
-~71 tok/s floor at the r05 shapes) and which end of it a deployment
+``speculative=K`` was a server-lifetime constant, yet the spec spread
+measured on the r05 chip run is ~8x (564-583 tok/s ceiling vs a
+~71 tok/s floor) and which end of it a deployment
 lands on is decided ENTIRELY by realized acceptance.  Greedy
 verification commits the target chain byte-for-byte at ANY draft depth
 (Leviathan et al., arXiv 2211.17192), so depth is a pure latency knob —
@@ -140,7 +140,7 @@ class SpecController:
         # (accepted, offered) per harvested segment.
         self._rows: Dict[int, Deque[Tuple[int, int]]] = {}
         self.current_window = min(self.default_window, self.max_window)
-        # Counters (host-side, surfaced via serving stats + bench).
+        # Counters (host-side, surfaced via serving stats).
         self.boundaries = 0
         self.switches = 0
         self.masked_row_boundaries = 0

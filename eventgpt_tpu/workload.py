@@ -21,8 +21,7 @@ traffic half of that measurement:
     ``interactive`` requests carry TTFT/ITL targets, ``batch`` requests
     an end-to-end latency target; ``SLO.met`` is THE attainment
     predicate (inclusive — a request exactly on target has met it),
-    shared by the batcher's finish-time scoring and the bench's goodput
-    accounting.
+    used by the batcher's finish-time scoring.
   * ``replay``: open-loop replay of a trace against a
     ``ContinuousBatcher`` — requests are submitted at their scheduled
     arrival times (scaled by ``rate_mult``, the offered-load dial)
@@ -31,7 +30,7 @@ traffic half of that measurement:
     hides saturation).
 
 Deliberately jax-free (numpy + stdlib): trace generation and SLO math
-must run on any host — the bench driver, a router tier, tests — without
+must run on any host — a router tier, tests — without
 owning an accelerator. ``eventgpt_tpu/serve.py`` imports the SLO types
 from here, not the other way around.
 """
@@ -192,7 +191,7 @@ def generate_trace(spec: WorkloadSpec) -> List[TraceRequest]:
     probs = probs / probs.sum()
     # Shared system head: identical TEXT across every stream (the
     # cross-session radix hit); BOS + a fixed filler token, the
-    # tests/bench prompt idiom.
+    # tests' prompt idiom.
     head = [1] + [7] * max(spec.head_len - 1, 0)
 
     def tail(n: int) -> List[int]:
@@ -255,13 +254,6 @@ def generate_trace(spec: WorkloadSpec) -> List[TraceRequest]:
             max_new_tokens=budget,
         ))
     return out
-
-
-def cache_positions(req: TraceRequest, num_event_tokens: int) -> int:
-    """Prompt length in KV-cache positions (text tokens + the event
-    block's expansion) — the server-sizing arithmetic."""
-    n_text = sum(1 for t in req.input_ids if t != EVENT_TOKEN_INDEX)
-    return n_text + num_event_tokens
 
 
 def stream_pixels(shape: Tuple[int, ...], seed: int) -> np.ndarray:

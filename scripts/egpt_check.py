@@ -14,7 +14,7 @@ Usage::
                                  [--waived] [--list]
 
   * ``--json``   machine-readable report (stable keys + per-rule
-    counts) so bench/CI tooling can diff finding counts across PRs;
+    counts) so tooling can diff finding counts across PRs;
   * ``--rules``  run a subset (ids from ``--list``);
   * ``--waived`` also print waived findings with their justifications;
   * ``--list``   print the rule catalogue and exit.

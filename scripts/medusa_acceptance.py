@@ -150,7 +150,8 @@ def main(argv=None):
     p.add_argument("--max_batch", type=int, default=1,
                    help="1 = sequential serving, so tokens_per_iteration "
                         "is PER-CHAIN acceptance (comparable to the "
-                        "lookup baselines in PERFORMANCE.md); >1 reports "
+                        "lookup draft's, which this script also runs); "
+                        ">1 reports "
                         "aggregate per weight pass")
     p.add_argument("--log_every", type=int, default=50)
     p.add_argument("--save_heads", default=None,

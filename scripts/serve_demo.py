@@ -48,10 +48,10 @@ def main(argv=None):
     p.add_argument("--temperature", type=float, default=0.0)
     p.add_argument("--dtype", default="bfloat16",
                    choices=["bfloat16", "float32"])
-    p.add_argument("--quant", default="none", choices=["none", "int8", "int4"])
+    p.add_argument("--quant", default="none", choices=["none", "int8"])
     p.add_argument("--fuse_params", action="store_true",
                    help="fuse qkv / gate-up before quantization (+4%% at "
-                        "wide batches — PERFORMANCE.md)")
+                        "batch 8 on the r05 chip run)")
     p.add_argument("--kv_cache", default="bf16", choices=["bf16", "int8"])
     p.add_argument("--speculative", type=int, default=0,
                    help="verify-window size K (0 = plain decode)")

@@ -68,7 +68,7 @@ def main(argv=None):
     p.add_argument("--pace_factor", type=float, default=1.0)
     # prepare_model surface (parity with cli/infer.py).
     p.add_argument("--dtype", default="bfloat16", choices=["bfloat16", "float32"])
-    p.add_argument("--quant", default="none", choices=["none", "int8", "int4"])
+    p.add_argument("--quant", default="none", choices=["none", "int8"])
     p.add_argument("--speculative", type=int, default=0,
                    help="speculative greedy decode window (exact-equivalent; "
                         "cuts per-answer decode latency when text repeats)")

@@ -46,7 +46,7 @@ def main(argv=None):
     p.add_argument("--spatial_temporal_encoder", default=True,
                    type=lambda s: s.lower() not in ("false", "0"))
     p.add_argument("--quant", default="none",
-                   choices=["none", "int8", "int4"],
+                   choices=["none", "int8"],
                    help="frozen-base storage during head training")
     p.add_argument("--fuse_params", action="store_true")
     args = p.parse_args(argv)

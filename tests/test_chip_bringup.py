@@ -108,11 +108,7 @@ try:
 finally:
     httpd.shutdown(); engine.shutdown(); httpd.server_close()
 
-# bench.py's process-fleet legs: what their parent resolves before it spawns.
-import argparse, bench
-preset, cfg, platform = bench._procfleet_preset(
-    argparse.Namespace(preset="auto"))
-print(json.dumps({"workers": fleet["workers"], "bench": [preset, platform],
+print(json.dumps({"workers": fleet["workers"],
                   "backend_up": xla_bridge.backends_are_initialized()}))
 """
 
@@ -121,14 +117,12 @@ def test_proc_fleet_coordinator_never_initialises_a_backend():
     """A parent that has touched JAX holds the chip and its workers cannot
     have it: building a ProcFleet engine the way ``--proc_fleet`` does
     (compile cache, config, tokenizer, HTTP front end) leaves JAX's
-    backends uninitialised, and so does what the parents of bench.py's
-    process-fleet legs resolve. Started as on the chip (JAX_PLATFORMS
+    backends uninitialised. Started as on the chip (JAX_PLATFORMS
     unset), where the workers' platform is the one asked for."""
     r = _run(_COORDINATOR_PROBE, env_unset=("JAX_PLATFORMS",))
     assert r.returncode == 0, r.stderr[-3000:]
     out = json.loads(r.stdout.strip().splitlines()[-1])
-    assert out == {"workers": 2, "bench": ["tiny", "tpu"],
-                   "backend_up": False}
+    assert out == {"workers": 2, "backend_up": False}
 
 
 def test_single_host_tpu_vm_is_not_a_pod_launch(monkeypatch):

@@ -1,7 +1,7 @@
 """Fused int8-KV decode-attention kernel: numerical parity.
 
 The kernel itself is a measured NEGATIVE result for the product path
-(PERFORMANCE.md: 10.1 ms vs 3.7 ms for the XLA fused-dequant attention at
+(r05 chip run: 10.1 ms vs 3.7 ms for the XLA fused-dequant attention at
 7B shapes — decode attention inside the sequential layer scan is
 op-granularity-bound, not dequant-bound), kept in-tree with the
 measurement. These tests pin its correctness in interpreter mode so the

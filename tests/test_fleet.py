@@ -144,25 +144,6 @@ def test_router_affinity_same_session_lands_same_replica(tiny):
         fleet.shutdown()
 
 
-def test_fleet_chains_match_single_engine(tiny):
-    """Routing is placement only: every request's greedy chain equals a
-    single-engine run of the same prompts."""
-    cfg, _ = tiny
-    reqs = [(_ids((40 + i,)), _pv(cfg, 100 + i), 6) for i in range(4)]
-    ref_b = _batcher(tiny, max_batch=2)
-    ref_rids = [ref_b.submit(ids, pv, n) for ids, pv, n in reqs]
-    ref = ref_b.run_until_drained()
-    fleet = _fleet(tiny)
-    try:
-        frids = [fleet.submit_ids(ids, pv, n) for ids, pv, n in reqs]
-        out = [fleet.result(f, timeout=120) for f in frids]
-        assert out == [ref[r] for r in ref_rids]
-        # Both replicas took part (4 distinct streams, least-queue).
-        assert {fleet.replica_of(f) for f in frids} == {0, 1}
-    finally:
-        fleet.shutdown()
-
-
 def test_shedding_batch_only_and_interactive_protected(tiny):
     """The acceptance bar: under the same overload, shedding armed keeps
     the interactive SLO-met ratio >= the unarmed ratio, and ONLY

@@ -5,8 +5,8 @@ result, not just compile).
 Runs a scaled-down version of ``scripts/medusa_acceptance.py``: finetune
 the tiny model on the deterministic motion corpus, train a head stack,
 serve the held-out split through the ContinuousBatcher with three drafts
-on identical traffic. The full-scale run (defaults; recorded in
-PERFORMANCE.md) shows trained heads beating the lookup draft; the test
+on identical traffic. The full-scale run (the script's defaults) showed
+trained heads beating the lookup draft; the test
 tier asserts the structural guarantees that make that number meaningful:
 exact chains across drafts, trained heads decisively above the
 random-head floor, and real multi-token acceptance.

@@ -416,34 +416,3 @@ def test_fleet_memory_stats_report_per_replica_share(tiny):
         assert ms["total_bytes"] >= owned + w
     finally:
         fleet.shutdown()
-
-
-def test_compare_bench_gates_memory_keys(tiny):
-    """CI satellite: peak bytes gate lower-is-better, and cross-topology
-    records drop memory keys with an unpaired note (the tok_s identity
-    design)."""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "compare_bench", os.path.join(ROOT, "scripts", "compare_bench.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    rec = {"metric": "serve_aggregate_tiny", "value": 1.0, "unit": "tok/s",
-           "mem_peak_bytes": 1000,
-           "memory": {"peak_bytes": 1000, "total_bytes": 900,
-                      "reconcile": {"unaccounted_bytes": 10,
-                                    "accounted_ratio": 0.99}}}
-    worse = json.loads(json.dumps(rec))
-    worse["mem_peak_bytes"] = 2000
-    worse["memory"]["peak_bytes"] = 2000
-    regs, _ = mod.compare(rec, worse)
-    assert any("mem_peak_bytes" in r for r in regs)
-    regs, _ = mod.compare(rec, rec, require=("mem_peak_bytes",))
-    assert regs == []
-    # Topology differs (fleet key present on one side): memory keys are
-    # dropped with a note instead of gating architecture as drift.
-    fleet_rec = json.loads(json.dumps(worse))
-    fleet_rec["fleet"] = 2
-    regs, notes = mod.compare(rec, fleet_rec)
-    assert not any("mem_peak" in r for r in regs)
-    assert any("memory" in n and "unpaired" in n for n in notes)

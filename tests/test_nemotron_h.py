@@ -373,7 +373,6 @@ def test_a_mesh_a_draft_head_quantization_and_fusing_refuse_too():
         ContinuousBatcher(params, cfg, max_batch=2, max_len=256,
                           prefix_cache=False, draft_head={"w": 0})
     for quant, fuse, flag in (("int8", False, "--quant"),
-                              ("int4", False, "--quant"),
                               ("none", True, "--fuse_params")):
         with pytest.raises(ValueError, match=flag):
             served_shapes(cfg, jnp.float32, quant, fuse)

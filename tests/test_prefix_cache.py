@@ -70,29 +70,6 @@ def test_insert_on_prefill_populates_and_hits(tiny):
     assert out_a[a] == want and out_b[b] == want
 
 
-def test_cache_on_off_chains_byte_identical(tiny):
-    """The exactness contract: multi-session traffic (2 streams x 2
-    requests + one non-matching prompt) commits identical chains with
-    the cache enabled, disabled, and vs one-shot generate."""
-    cfg, params = tiny
-    reqs = [
-        ([1, 5, -200, 9, 9], _pv(cfg, 0), 7),
-        ([1, 5, -200, 9, 9], _pv(cfg, 1), 7),   # same text, OTHER stream
-        ([1, 5, -200, 3], _pv(cfg, 0), 6),      # session 0 again
-        ([2, 6, -200, 11], _pv(cfg, 2), 6),     # different system head
-        ([1, 5, -200, 9, 9], _pv(cfg, 1), 7),   # session 1 again
-    ]
-    outs = {}
-    for cache in (True, False):
-        srv = _srv(params, cfg, prefix_cache=cache)
-        rids = [srv.submit(i, p, b) for i, p, b in reqs]
-        out = srv.run_until_drained()
-        outs[cache] = [out[r] for r in rids]
-    assert outs[True] == outs[False]
-    for got, (i, p, b) in zip(outs[True], reqs):
-        assert got == _oneshot(params, cfg, i, p, b)
-
-
 def test_wrong_stream_never_hits_event_entry(tiny):
     """ISSUE 4 non-negotiable: same prompt text, different pixels must
     never read an event-block entry's KV. It MAY hit the (stream-free)

@@ -453,7 +453,7 @@ def test_saturation_replay_fires_alerts_x16_but_not_x1():
                                 chunk=4, eos_token_id=None)
         # Warm EVERY shape the measured replay will hit (full trace,
         # unpaced, store disarmed) so compile stalls never masquerade
-        # as saturation — the bench's --bench_warmup, in miniature.
+        # as saturation.
         wl.replay(srv, trace, pixels_for=pixels_for, paced=False)
         obs_metrics.REGISTRY.reset()
         obs_series.configure(

@@ -259,7 +259,7 @@ def test_warmup_precompiles_and_serves_exactly(tiny):
     """warmup() compiles encode/prefill/admit/segment against the live
     state without corrupting it; a subsequent real request decodes the
     exact one-shot chain. (The latency effect — first request ~= steady
-    state — is measured on hardware by bench --mode serve --warmup.)"""
+    state — is the benchmark's ``compiles_in_window``, on the chip.)"""
     cfg, params = tiny
     srv = ContinuousBatcher(params, cfg, max_batch=2, max_len=256, chunk=4,
                             eos_token_id=None)
@@ -723,7 +723,7 @@ def test_set_prefix_coexists_with_auto_entries_and_warmup(tiny):
 
 
 def test_pipelined_overlap_counters(tiny):
-    """The overlap instrumentation the serve bench records: pipelined
+    """The overlap instrumentation ``GET /stats`` reports: pipelined
     runs hide host work behind in-flight segments (overlap_ratio > 0);
     the synchronous path measures ~0 by construction; warmup and
     reset_serving_stats leave a clean measurement window."""

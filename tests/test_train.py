@@ -230,8 +230,7 @@ def test_remat_policy_sweep_loss_equality(tiny, tokenizer):
     """ISSUE 13 satellite (VERDICT r5 / ROADMAP item 4 enabler): the
     stage-2 step under every jax.checkpoint policy computes the SAME
     loss and the same update as full remat — the policy only moves
-    backward-pass memory/recompute, never values. Dryrun form of the
-    hardware sweep (bench --mode train --remat_policy ...)."""
+    backward-pass memory/recompute, never values."""
     import dataclasses
 
     cfg, params = tiny
@@ -261,8 +260,14 @@ def test_remat_policy_sweep_loss_equality(tiny, tokenizer):
                                    err_msg=policy)
         for a, b in zip(jax.tree_util.tree_leaves(base_tr),
                         jax.tree_util.tree_leaves(tr)):
+            # A rematerialised backward re-associates float32 sums, and
+            # one AdamW step divides each gradient element by its own
+            # magnitude: where a gradient is near zero the update moves.
+            # Seen: 1 of 4,096 elements of one leaf off by 1.9e-6
+            # absolute (7.5e-5 relative) under dots_saveable, at a
+            # learning rate of 1e-2.
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-5, atol=1e-6,
+                                       rtol=2e-5, atol=5e-6,
                                        err_msg=policy)
 
 
