@@ -36,7 +36,9 @@ Every plane has the rows on axis 1, so admission scatters them alike.
 Prefill is the chunked scan at ``chunk_size`` (matrix products within a
 chunk, a recurrence across chunks); a right-padded wave leaves each row's
 state as it was at its own last real position (``dt = 0`` at pads, the conv
-tail gathered at each row's length). Decode is one recurrence step;
+tail gathered at each row's length). Decode is one recurrence step, which
+reads and writes each plane of ``h`` once, in place (``ops/ssm_step.py``:
+update and readout of a block while it is in VMEM);
 ``decode_step(live=...)`` leaves the state of rows that are not live
 untouched, since a recurrent state cannot be rolled back by ``length`` as
 keys and values can.
@@ -69,6 +71,7 @@ from eventgpt_tpu.models.llama import (
     _attn_block, _cache_write, _lm_head, embed_tokens, rms_norm,
 )
 from eventgpt_tpu.ops.quant import matmul as _mm, matmul_f32_out as _mm_f32
+from eventgpt_tpu.ops.ssm_step import ssm_step
 
 Params = Dict[str, Any]
 Cache = Dict[str, jnp.ndarray]
@@ -296,13 +299,12 @@ def _mamba_prefill(cfg: HybridConfig, layer: Params, x_in, mask, lengths):
     return _mm_f32(y.astype(x_in.dtype), layer["out_proj"]), tail, h
 
 
-def _mamba_step(cfg: HybridConfig, layer: Params, x_in, tail, h, live):
-    """One position a row. x_in (B, D); tail (B, K-1, C); h (B, H, P, N)
-    float32; ``live`` (B,) bool or None. Rows that are not live keep tail
-    and h."""
+def _mamba_step(cfg: HybridConfig, layer: Params, x_in, tail, h_buf, i: int,
+                live):
+    """One position a row. x_in (B, D); tail (B, K-1, C); h_buf (planes, B,
+    H, P, N) float32, of which this layer steps plane ``i`` in place;
+    ``live`` (B,) bool or None. Rows that are not live keep tail and h."""
     bsz = x_in.shape[0]
-    g = cfg.n_groups
-    r = cfg.mamba_num_heads // g
     z, xbc, dt = _split_in_proj(cfg, _mm_f32(x_in, layer["in_proj"]))
     with jax.named_scope("ssm_step"):
         window = jnp.concatenate([tail.astype(jnp.float32), xbc[:, None]],
@@ -313,18 +315,15 @@ def _mamba_step(cfg: HybridConfig, layer: Params, x_in, tail, h, live):
             conv + layer["conv_b"].astype(jnp.float32)))
         dt = jax.nn.softplus(dt + layer["dt_bias"])
         if live is not None:
+            # dt = 0: decay = 1 and xdt = 0, so the row's h stays as it is
             dt = jnp.where(live[:, None], dt, 0.0)
             new_tail = jnp.where(live[:, None, None], new_tail, tail)
-        hg = h.reshape(bsz, g, r, cfg.mamba_head_dim, cfg.ssm_state_size)
-        decay = jnp.exp(dt * -jnp.exp(layer["A_log"])).reshape(bsz, g, r)
-        xdt = (x * dt[..., None]).reshape(bsz, g, r, cfg.mamba_head_dim)
-        hg = hg * decay[..., None, None] \
-            + xdt[..., None] * b[:, :, None, None, :]
-        y = jnp.sum(hg * c[:, :, None, None, :], axis=-1)       # (B, G, R, P)
-        y = y.reshape(x.shape) + x * layer["D"][:, None]
+        decay = jnp.exp(dt * -jnp.exp(layer["A_log"]))          # (B, H)
+        h_buf, y = ssm_step(h_buf, i, decay, x * dt[..., None], b, c)
+        y = y + x * layer["D"][:, None]
         y = _gated_norm(cfg, y.reshape(bsz, -1), z, layer["gate_norm"])
     return (_mm_f32(y.astype(x_in.dtype), layer["out_proj"]), new_tail,
-            hg.reshape(h.shape))
+            h_buf)
 
 
 # -- E: sparse experts in a latent, and the shared expert ----------------------
@@ -524,10 +523,9 @@ def decode_step(
         seen[kind] += 1
         if kind == "M":
             y = rms_norm(x, layer["norm"], cfg.rms_norm_eps).astype(dtype)
-            out, tail, h = _mamba_step(cfg, layer, y[:, 0], conv_buf[i],
-                                       h_buf[i], live)
+            out, tail, h_buf = _mamba_step(cfg, layer, y[:, 0], conv_buf[i],
+                                           h_buf, i, live)
             conv_buf = conv_buf.at[i].set(tail)
-            h_buf = h_buf.at[i].set(h)
             out = out[:, None]
         elif kind == "E":
             y = rms_norm(x, layer["norm"], cfg.rms_norm_eps)
