@@ -266,12 +266,11 @@ def prepare_model(cfg, params, tokenizer, args, mesh=None):
         params["llama"] = resize_token_embeddings(params["llama"], len(tokenizer))
     from eventgpt_tpu.models import eventchat, llama as llama_mod
 
-    if eventchat.decoder_of(cfg) is not llama_mod:
-        # Fusing and quantization are the dense decoder's transforms.
-        eventchat.refuse_without_recurrent_state(**{
-            "--quant": args.quant != "none",
-            "--fuse_params": getattr(args, "fuse_params", False)})
-    else:
+    # Fusing and quantization are the dense decoder's transforms.
+    eventchat.refuse_unserved(cfg, **{
+        "--quant": args.quant != "none",
+        "--fuse_params": getattr(args, "fuse_params", False)})
+    if eventchat.decoder_of(cfg) is llama_mod:
         params["llama"] = _fuse_and_quantize(params["llama"], args)
     import jax.numpy as jnp
 

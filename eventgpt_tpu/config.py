@@ -214,6 +214,80 @@ class HybridConfig:
 
 
 @dataclass(frozen=True)
+class AfmoeConfig:
+    """A decoder of window and global attention layers over sparse experts
+    (``models/afmoe.py``). Field names follow the published ``afmoe``
+    ``config.json`` (Arcee Trinity). Layer ``i`` attends within
+    ``sliding_window`` positions and rotates queries and keys where
+    ``layer_types[i]`` is ``sliding_attention``; where it is
+    ``full_attention`` it sees every earlier position and applies no
+    positional embedding. The first ``num_dense_layers`` layers carry a
+    dense SwiGLU MLP of ``intermediate_size``, the others ``num_experts``
+    routed SwiGLU experts of ``moe_intermediate_size`` beside one shared
+    expert.
+
+    ``num_experts`` is the router's width (every expert of the deployment);
+    this process holds ``experts_held`` of them from ``experts_offset`` on,
+    as ``HybridConfig`` does."""
+
+    layer_types: tuple = ("sliding_attention", "full_attention")
+    sliding_window: int = 4096
+    num_dense_layers: int = 1
+    vocab_size: int = 32000
+    hidden_size: int = 3072
+    intermediate_size: int = 12288
+    moe_intermediate_size: int = 3072
+    num_heads: int = 48
+    num_kv_heads: int = 8
+    head_dim: Optional[int] = 128
+    rope_theta: float = 10000.0
+    rms_norm_eps: float = 1e-5
+    max_seq_len: int = 4096
+    attn_impl: str = "dense"
+    num_experts: int = 256
+    experts_held: int = 256
+    experts_offset: int = 0
+    num_experts_per_tok: int = 4
+    route_norm: bool = True
+    route_scale: float = 1.0
+    mup_enabled: bool = True
+
+    _KINDS = ("sliding_attention", "full_attention")
+
+    def __post_init__(self):
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if not self.layer_types or set(self.layer_types) - set(self._KINDS):
+            raise ValueError(
+                f"layer_types {self.layer_types!r}: each one of {self._KINDS}")
+        if self.attn_impl not in ("dense", "flash"):
+            raise ValueError(
+                f"attn_impl must be 'dense' or 'flash', got {self.attn_impl!r}")
+        if not 0 <= self.num_dense_layers <= len(self.layer_types):
+            raise ValueError(
+                f"num_dense_layers {self.num_dense_layers} of "
+                f"{len(self.layer_types)} layers")
+        if self.sliding_window < 1:
+            raise ValueError(f"sliding_window {self.sliding_window}")
+        if not (0 <= self.experts_offset
+                and self.experts_offset + self.experts_held
+                <= self.num_experts):
+            raise ValueError(
+                f"experts {self.experts_offset}..+{self.experts_held} are "
+                f"not among the router's {self.num_experts}")
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.layer_types)
+
+    def count(self, kind: str) -> int:
+        return self.layer_types.count(kind)
+
+    def resolved_head_dim(self) -> int:
+        return (self.head_dim if self.head_dim is not None
+                else self.hidden_size // self.num_heads)
+
+
+@dataclass(frozen=True)
 class ProjectorConfig:
     """Event-feature -> LM-embedding projection stack.
 
@@ -271,8 +345,9 @@ class EventChatConfig:
     """Top-level multimodal model config (EventChat_llama equivalent)."""
 
     vision: VisionConfig = field(default_factory=VisionConfig)
-    # The decoder behind the tower: dense (LlamaConfig) or hybrid
-    # (HybridConfig); models/eventchat.decoder_of picks its module.
+    # The decoder behind the tower: dense (LlamaConfig), hybrid
+    # (HybridConfig) or window / global attention over sparse experts
+    # (AfmoeConfig); models/eventchat.decoder_of picks its module.
     llama: Any = field(default_factory=LlamaConfig)
     projector: ProjectorConfig = field(default_factory=ProjectorConfig)
 
@@ -351,6 +426,8 @@ def event_chat_config_from_dict(data: dict) -> EventChatConfig:
         v = data[f.name]
         if f.name == "llama" and isinstance(v, dict) and "pattern" in v:
             v = HybridConfig(**v)  # the decoder's kind, by what it states
+        elif f.name == "llama" and isinstance(v, dict) and "layer_types" in v:
+            v = AfmoeConfig(**v)
         elif f.name in _NESTED and isinstance(v, dict):
             v = _NESTED[f.name](**v)
         kwargs[f.name] = v
@@ -384,11 +461,14 @@ def from_hf_config(hf: dict, attn_impl: Optional[str] = None) -> EventChatConfig
     (``model/EventChatModel.py:75``, ``inference.py:33-34``).
     ``attn_impl=None`` resolves per platform (``default_attn_impl``).
     ``model_type`` ``nemotron_h`` builds the hybrid decoder's configuration
-    (``hybrid_from_hf``); every other file a dense one.
+    (``hybrid_from_hf``), ``afmoe`` the window / global decoder's
+    (``afmoe_from_hf``); every other file a dense one.
     """
     attn_impl = attn_impl if attn_impl is not None else default_attn_impl()
     if hf.get("model_type") == "nemotron_h":
         llama = hybrid_from_hf(hf, attn_impl)
+    elif hf.get("model_type") == "afmoe":
+        llama = afmoe_from_hf(hf, attn_impl)
     else:
         llama = _dense_from_hf(hf, attn_impl)
     return _behind_the_tower(hf, llama)
@@ -422,7 +502,7 @@ def hybrid_from_hf(hf: dict, attn_impl: str) -> HybridConfig:
         num_heads=hf["num_attention_heads"],
         num_kv_heads=hf["num_key_value_heads"], head_dim=hf.get("head_dim"),
         rms_norm_eps=hf.get("layer_norm_epsilon", 1e-5),
-        max_seq_len=min(hf.get("max_position_embeddings", 2048), 4096),
+        max_seq_len=hf.get("max_position_embeddings", 2048),
         mamba_num_heads=hf["mamba_num_heads"],
         mamba_head_dim=hf["mamba_head_dim"], n_groups=hf["n_groups"],
         ssm_state_size=hf["ssm_state_size"], conv_kernel=hf["conv_kernel"],
@@ -439,6 +519,56 @@ def hybrid_from_hf(hf: dict, attn_impl: str) -> HybridConfig:
     )
 
 
+def afmoe_from_hf(hf: dict, attn_impl: str) -> AfmoeConfig:
+    """The published ``afmoe`` keys -> ``AfmoeConfig``. Depth: a file cut in
+    depth names the published layers it keeps under ``layers_kept`` (indices
+    into ``layer_types``, ``num_hidden_layers`` of them); without it, the
+    first ``num_hidden_layers`` entries. The first ``num_dense_layers`` of
+    the kept layers are dense. A file that holds a share of the experts
+    gives the count it holds under ``num_experts`` and the router's width
+    under ``published.num_experts`` (``experts_offset``: where the share
+    starts, 0 unless stated)."""
+    depth = int(hf["num_hidden_layers"])
+    kept = hf.get("layers_kept", range(depth))
+    types = tuple(hf["layer_types"][int(i)] for i in kept)
+    if len(types) != depth:
+        raise ValueError(f"{len(types)} layers kept, num_hidden_layers "
+                         f"asks for {depth}")
+    for key in ("n_group", "topk_group", "num_expert_groups",
+                "num_limited_groups"):
+        if int(hf.get(key, 1)) != 1:
+            raise ValueError(f"group-limited routing ({key} > 1) is not "
+                             f"implemented")
+    if hf.get("score_func", "sigmoid") != "sigmoid":
+        raise ValueError(f"score_func {hf['score_func']!r}: only sigmoid")
+    if hf.get("hidden_act", "silu") != "silu":
+        raise ValueError(f"hidden_act {hf['hidden_act']!r}: only silu")
+    if int(hf.get("num_shared_experts", 1)) != 1:
+        raise ValueError("num_shared_experts: one shared expert only")
+    if hf.get("rope_scaling") is not None:
+        raise ValueError("rope_scaling is not implemented for afmoe")
+    held = int(hf["num_experts"])
+    return AfmoeConfig(
+        layer_types=types, attn_impl=attn_impl,
+        sliding_window=int(hf["sliding_window"]),
+        num_dense_layers=int(hf["num_dense_layers"]),
+        vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
+        intermediate_size=hf["intermediate_size"],
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"], head_dim=hf.get("head_dim"),
+        rope_theta=float(hf.get("rope_theta", 10000.0)),
+        rms_norm_eps=hf.get("rms_norm_eps", 1e-5),
+        max_seq_len=hf.get("max_position_embeddings", 2048),
+        num_experts=int(hf.get("published", {}).get("num_experts", held)),
+        experts_held=held, experts_offset=int(hf.get("experts_offset", 0)),
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        route_norm=bool(hf.get("route_norm", True)),
+        route_scale=float(hf.get("route_scale", 1.0)),
+        mup_enabled=bool(hf.get("mup_enabled", False)),
+    )
+
+
 def _dense_from_hf(hf: dict, attn_impl: str) -> LlamaConfig:
     return LlamaConfig(
         attn_impl=attn_impl,
@@ -450,7 +580,7 @@ def _dense_from_hf(hf: dict, attn_impl: str) -> LlamaConfig:
         num_kv_heads=hf.get("num_key_value_heads", hf.get("num_attention_heads", 32)),
         rope_theta=hf.get("rope_theta", 10000.0),
         rms_norm_eps=hf.get("rms_norm_eps", 1e-5),
-        max_seq_len=min(hf.get("max_position_embeddings", 2048), 4096),
+        max_seq_len=hf.get("max_position_embeddings", 2048),
         tie_word_embeddings=hf.get("tie_word_embeddings", False),
     )
 

@@ -40,59 +40,40 @@ Params = Dict[str, Any]
 
 def decoder_of(cfg: EventChatConfig):
     """The decoder's module, by the kind of ``cfg.llama``: the one place that
-    picks it. Both define ``init_params``, ``init_cache``, ``embed_tokens``,
+    picks it. Each defines ``init_params``, ``init_cache``, ``embed_tokens``,
     ``prefill``, ``decode_step`` and ``forward`` under the same signatures;
-    the decoder's subtree of the parameters is ``params["llama"]`` either
-    way. What only the dense decoder has (``decode_kstep``, the paged and
-    int8 caches, fusing, quantization) is reached through ``llama_mod`` by
-    name, and ``ContinuousBatcher`` refuses the flags that need it for
-    another decoder."""
-    from eventgpt_tpu.config import HybridConfig
+    the decoder's subtree of the parameters is ``params["llama"]`` whichever
+    it is. Each also says what ``ContinuousBatcher`` has to know of it:
+    ``fixed_state`` (the planes of a row's state that do not grow with its
+    position), ``WAVE_TOKENS`` (the most positions one admission wave may
+    prefill; 0: no cap), ``span_counts`` (what a dispatch span carries of
+    the decoder's own) and ``REFUSES`` / ``REFUSED_AS`` (the flags it cannot
+    serve yet, each with its reason: ``refuse_unserved``). What only the
+    dense decoder has (``decode_kstep``, the paged and int8 caches, fusing,
+    quantization) is reached through ``llama_mod`` by name."""
+    from eventgpt_tpu.config import AfmoeConfig, HybridConfig
 
     if isinstance(cfg.llama, HybridConfig):
         from eventgpt_tpu.models import nemotron_h
 
         return nemotron_h
+    if isinstance(cfg.llama, AfmoeConfig):
+        from eventgpt_tpu.models import afmoe
+
+        return afmoe
     return llama_mod
 
 
-def refuse_without_recurrent_state(**asked) -> None:
-    """Raise for the first option that is on, by its flag's name. A decoder
-    with recurrent layers keeps, beside keys and values by position, a
-    state a row that cannot be sliced at a position, rolled back by
-    ``length`` or shared between rows. The mechanisms below move, share or
-    roll back keys and values only, so each refuses such a decoder rather
-    than serve a stale state (ROADMAP.md, Queue 2). ``ContinuousBatcher``,
+def refuse_unserved(cfg: EventChatConfig, **asked) -> None:
+    """Raise for the first option that is on and that the configuration's
+    decoder cannot serve, by its flag's name and with the decoder's own
+    reason (its module's ``REFUSES``). ``ContinuousBatcher``,
     ``cli/infer.prepare_model`` and ``synthetic.served_shapes`` ask."""
-    why = {
-        "--kv_cache int8": "the int8 cache holds keys and values only",
-        "--kv_layout paged": "a block holds keys and values by position only",
-        "--speculative": "a rejected draft cannot be rolled back out of a "
-                         "recurrent state",
-        "--spec_buckets": "a rejected draft cannot be rolled back out of a "
-                          "recurrent state",
-        "--draft_head": "speculation is refused",
-        "--prefill_chunk": "chunked admission prefills through decode_kstep, "
-                           "which carries no recurrent state",
-        "--prefill_budget": "piggyback lanes prefill through decode_kstep, "
-                            "which carries no recurrent state (pass "
-                            "--prefill_budget 0)",
-        "--prefix_cache_mb": "a prefix entry holds keys and values and no "
-                             "snapshot of the recurrent state at its end "
-                             "(pass --no_prefix_cache)",
-        "--preempt": "a spill record holds block runs only",
-        "--role": "a handoff record holds block runs only",
-        "--mesh_model": "the decoder runs on one device (no expert axis in "
-                        "parallel/mesh.py; --mesh_data and --mesh_fsdp "
-                        "likewise)",
-        "--quant": "ops/quant is two-dimensional and does not take stacked "
-                   "experts",
-        "--fuse_params": "there is no q|k|v or gate|up to fuse",
-    }
+    dec = decoder_of(cfg)
     for flag, on in asked.items():
-        if on:
-            raise ValueError(f"{flag} is refused for a decoder with "
-                             f"recurrent state: {why[flag]}")
+        if on and flag in dec.REFUSES:
+            raise ValueError(f"{flag} is refused for {dec.REFUSED_AS}: "
+                             f"{dec.REFUSES[flag]}")
 
 
 def init_eventchat_params(cfg: EventChatConfig, key: jax.Array, dtype=jnp.float32) -> Params:
@@ -1009,10 +990,10 @@ def generate(
 
     compute_dtype = jax.tree_util.tree_leaves(params["llama"])[0].dtype
 
-    if decoder_of(cfg) is not llama_mod and (
+    if decoder_of(cfg).REFUSES and (
             speculative or num_beams > 1 or kv_quant or mesh is not None):
         raise ValueError(
-            "a decoder with recurrent state generates greedy or sampled, on "
+            f"{decoder_of(cfg).REFUSED_AS} generates greedy or sampled, on "
             "one device, with the plain cache: speculative, num_beams, "
             "kv_quant and mesh are refused (beams regather keys and values "
             "only; a rejected draft cannot be rolled back)")

@@ -93,8 +93,22 @@ def init_llama_params(cfg: LlamaConfig, key: jax.Array, dtype=jnp.float32) -> Pa
     }
 
 
-# The names a decoder module is reached by (models/eventchat.decoder_of).
+# The names a decoder module is reached by (models/eventchat.decoder_of),
+# and what ``ContinuousBatcher`` asks one: the dense decoder's whole state is
+# keys and values by position, no wave is capped, and no flag is refused.
 init_params = init_llama_params
+WAVE_TOKENS = 0
+REFUSES: Dict[str, str] = {}
+
+
+def fixed_state(cfg: LlamaConfig) -> Tuple[str, ...]:
+    """No plane of a row's state but keys and values by position."""
+    return ()
+
+
+def span_counts(cfg: LlamaConfig, lengths) -> Dict[str, int]:
+    """Nothing of this decoder's own on a dispatch span."""
+    return {}
 
 
 def embed_tokens(params: Params, input_ids: jnp.ndarray) -> jnp.ndarray:
@@ -256,16 +270,26 @@ def _attn_block(cfg: LlamaConfig, q_proj: jnp.ndarray, layer: Params,
 
             ctx = flash_attention(q, k, v, valid=valid, causal=True)
         else:
-            # Queries regrouped per KV head (h = g * rep + r, _repeat_kv's
-            # order) and contracted against K / V as stored: M = rep * Q
-            # rows per (b, g) product, no repeated and no f32 copy of K / V.
-            qg = q.reshape(b, q_len, kvh, rep, hd)
-            scores = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k_full,
-                                preferred_element_type=jnp.float32)
-            scores = scores * (1.0 / math.sqrt(hd)) + mask[:, :, None]
-            probs = jax.nn.softmax(scores, axis=-1).astype(q_proj.dtype)
-            ctx = jnp.einsum("bgrqk,bkgd->bqgrd", probs, v_full)
+            ctx = grouped_attention(q, k_full, v_full, mask)
     return _mm(ctx.reshape(b, q_len, h * hd), layer["attn"]["o"])
+
+
+def grouped_attention(q: jnp.ndarray, k_full: jnp.ndarray,
+                      v_full: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
+    """Dense attention of q (B, Q, H, hd) over K / V as stored (B, S, KV,
+    hd) under an additive ``mask`` (B, 1, Q, S): the read every decode step
+    takes. Queries regrouped per KV head (h = g * rep + r, _repeat_kv's
+    order) and contracted against K / V as they are: M = rep * Q rows per
+    (b, g) product, no repeated and no f32 copy of K / V. Returns (B, Q,
+    KV, rep, hd) in q's type."""
+    b, q_len, h, hd = q.shape
+    kvh = k_full.shape[2]
+    qg = q.reshape(b, q_len, kvh, h // kvh, hd)
+    scores = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k_full,
+                        preferred_element_type=jnp.float32)
+    scores = scores * (1.0 / math.sqrt(hd)) + mask[:, :, None]
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bgrqk,bkgd->bqgrd", probs, v_full)
 
 
 def _mlp_block(x: jnp.ndarray, layer: Params) -> jnp.ndarray:

@@ -67,6 +67,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from eventgpt_tpu.config import HybridConfig
+from eventgpt_tpu.models import experts as experts_mod
 from eventgpt_tpu.models.llama import (
     _attn_block, _cache_write, _lm_head, embed_tokens, rms_norm,
 )
@@ -76,11 +77,9 @@ from eventgpt_tpu.ops.ssm_step import ssm_step
 Params = Dict[str, Any]
 Cache = Dict[str, jnp.ndarray]
 
-# What an expert layer counts in one call, over the tokens that are real
-# (prefill) or live (decode): held experts that received a token, the tokens
-# of the fullest held expert, the assignments that fell on held experts, and
-# the tokens routed. ``cache["moe_stats"]``: (E layers, 4) int32.
-STATS = ("touched", "fullest", "held_assignments", "tokens")
+# What an expert layer counts in one call (``models/experts.STATS``), stacked
+# over the ``E`` layers: ``cache["moe_stats"]``, (E layers, 4) int32.
+STATS = experts_mod.STATS
 
 # Up to this many tokens (a decode step's rows) the held experts are computed
 # as one batched product over every held expert, each token weighted 0 where
@@ -93,10 +92,55 @@ STATS = ("touched", "fullest", "held_assignments", "tokens")
 # takes 2.1 ms a product and the weights' stream 0.86 ms (PERF.md).
 DENSE_EXPERTS_UP_TO = 128
 
+# -- what ``ContinuousBatcher`` asks a decoder module ------------------------
+def fixed_state(cfg: HybridConfig) -> Tuple[str, ...]:
+    """The planes of a row's state that do not grow with its position (rows
+    on axis 1; scattered whole at admission), beside ``k`` / ``v`` by
+    position."""
+    return ("conv", "h")
+
+
 # The most positions one admission wave may prefill at once (rows x bucket):
 # the expert layer sorts ``num_experts_per_tok`` assignments a position, and
 # its buffers grow with them. ``ContinuousBatcher`` cuts a wave here.
 WAVE_TOKENS = 8192
+# A decoder with recurrent layers keeps, beside keys and values by position,
+# a state a row that cannot be sliced at a position, rolled back by
+# ``length`` or shared between rows. The mechanisms below move, share or
+# roll back keys and values only, so each refuses such a decoder rather
+# than serve a stale state (ROADMAP.md, Queue 2).
+REFUSED_AS = "a decoder with recurrent state"
+REFUSES = {
+    "--kv_cache int8": "the int8 cache holds keys and values only",
+    "--kv_layout paged": "a block holds keys and values by position only",
+    "--speculative": "a rejected draft cannot be rolled back out of a "
+                     "recurrent state",
+    "--spec_buckets": "a rejected draft cannot be rolled back out of a "
+                      "recurrent state",
+    "--draft_head": "speculation is refused",
+    "--prefill_chunk": "chunked admission prefills through decode_kstep, "
+                       "which carries no recurrent state",
+    "--prefill_budget": "piggyback lanes prefill through decode_kstep, "
+                        "which carries no recurrent state (pass "
+                        "--prefill_budget 0)",
+    "--prefix_cache_mb": "a prefix entry holds keys and values and no "
+                         "snapshot of the recurrent state at its end "
+                         "(pass --no_prefix_cache)",
+    "--preempt": "a spill record holds block runs only",
+    "--role": "a handoff record holds block runs only",
+    "--mesh_model": "the decoder runs on one device (no expert axis in "
+                    "parallel/mesh.py; --mesh_data and --mesh_fsdp "
+                    "likewise)",
+    "--quant": "ops/quant is two-dimensional and does not take stacked "
+               "experts",
+    "--fuse_params": "there is no q|k|v or gate|up to fuse",
+}
+
+
+def span_counts(cfg: HybridConfig, lengths) -> Dict[str, int]:
+    """Nothing of this decoder's own on a dispatch span (the expert layers'
+    counts leave the device with the segment)."""
+    return {}
 
 
 # -- parameters ---------------------------------------------------------------
@@ -328,75 +372,26 @@ def _mamba_step(cfg: HybridConfig, layer: Params, x_in, tail, h_buf, i: int,
 
 # -- E: sparse experts in a latent, and the shared expert ----------------------
 
-def _relu2(x):
-    return jnp.square(jax.nn.relu(x))
-
-
-def _route(cfg: HybridConfig, layer: Params, y):
-    """y (T, D) float32 -> (experts (T, K) int32 over the whole deployment,
-    weights (T, K) float32). Scores, choice and weights in float32."""
-    with jax.named_scope("moe_route"):
-        with jax.default_matmul_precision("highest"):
-            s = jax.nn.sigmoid(y @ layer["router"].astype(jnp.float32))
-        _, experts = lax.top_k(s + layer["e_score_correction_bias"],
-                               cfg.num_experts_per_tok)
-        w = jnp.take_along_axis(s, experts, axis=-1)
-        if cfg.norm_topk_prob:
-            w = w / jnp.sum(w, axis=-1, keepdims=True)
-        return experts, w * cfg.routed_scaling_factor
-
-
-def _per_expert(key, held: int):
-    """Assignments a held expert: ``key`` (A,) int32 in 0 .. held, ``held``
-    standing for an absent expert. A compare and a sum (no scatter)."""
-    return jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
-                   dtype=jnp.int32)
+def _routing(cfg: HybridConfig) -> experts_mod.Routing:
+    return experts_mod.Routing(
+        top_k=cfg.num_experts_per_tok, held=cfg.experts_held,
+        offset=cfg.experts_offset, normalise=cfg.norm_topk_prob,
+        scale=cfg.routed_scaling_factor)
 
 
 def _moe_block(cfg: HybridConfig, layer: Params, y, counted, dtype):
     """y (T, D) float32, normed; ``counted`` (T,) bool: the tokens that are
     real or live; ``dtype``: the compute type of the products. Returns (the
-    layer's output (T, D) float32, its ``STATS`` (4,) int32). Every token is
-    computed; only the counted ones are counted."""
-    t = y.shape[0]
-    k, held = cfg.num_experts_per_tok, cfg.experts_held
-    experts, w = _route(cfg, layer, y)
-    y = y.astype(dtype)
-    with jax.named_scope("moe_experts"):
-        u = _mm(y, layer["latent_down"])                        # (T, latent)
-        local = experts - cfg.experts_offset
-        mine = (local >= 0) & (local < held)
-        if t <= DENSE_EXPERTS_UP_TO:
-            # A decode step: every held expert computes every token, and a
-            # token's weight for an expert it did not choose is 0.
-            weight = jnp.zeros((t, held + 1), jnp.float32).at[
-                jnp.arange(t)[:, None], jnp.where(mine, local, held)
-            ].set(jnp.where(mine, w, 0.0))[:, :held]
-            a = jnp.einsum("tl,elf->etf", u, layer["experts_up"])
-            o = jnp.einsum("etf,efl->etl", _relu2(a), layer["experts_down"])
-            routed = jnp.einsum("etl,te->tl", o.astype(jnp.float32), weight)
-        else:
-            # Assignments sorted by held expert; those of absent experts
-            # sort behind every group and are computed by none.
-            key = jnp.where(mine, local, held).reshape(t * k)
-            order = jnp.argsort(key)
-            sizes = _per_expert(key, held)
-            a = lax.ragged_dot(u[order // k], layer["experts_up"], sizes)
-            o = lax.ragged_dot(_relu2(a), layer["experts_down"], sizes)
-            # Back in the tokens' order; a row no group computed holds
-            # nothing that may be read.
-            o = jnp.where(mine[..., None],
-                          o[jnp.argsort(order)].reshape(t, k, -1), 0)
-            routed = jnp.einsum("tkl,tk->tl", o.astype(jnp.float32), w)
-        routed = _mm_f32(routed.astype(dtype), layer["latent_up"])
-    with jax.named_scope("moe_shared"):
-        shared = _mm_f32(_relu2(_mm(y, layer["shared_up"])),
-                         layer["shared_down"])
-    load = _per_expert(jnp.where(mine & counted[:, None], local, held)
-                       .reshape(t * k), held)
-    stats = jnp.stack([jnp.sum(load > 0), jnp.max(load), jnp.sum(load),
-                       jnp.sum(counted)]).astype(jnp.int32)
-    return routed + shared, stats
+    layer's output (T, D) float32, its ``STATS`` (4,) int32):
+    ``models/experts.sparse_experts`` with relu^2 experts inside the latent
+    projections."""
+    return experts_mod.sparse_experts(
+        _routing(cfg), y, counted, dtype,
+        router=layer["router"], bias=layer["e_score_correction_bias"],
+        experts={"up": layer["experts_up"], "down": layer["experts_down"]},
+        shared={"up": layer["shared_up"], "down": layer["shared_down"]},
+        latent=(layer["latent_down"], layer["latent_up"]),
+        dense_up_to=DENSE_EXPERTS_UP_TO)
 
 
 # -- the four entry points -----------------------------------------------------

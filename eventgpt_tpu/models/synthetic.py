@@ -62,10 +62,10 @@ def served_shapes(cfg: EventChatConfig, dtype, quant: str, fuse: bool):
             p = quant_mod.quantize_llama_params(p)
         return p
 
+    eventchat.refuse_unserved(cfg, **{
+        "--quant": quant != "none", "--fuse_params": fuse})
     if eventchat.decoder_of(cfg) is not llama_mod:
-        eventchat.refuse_without_recurrent_state(**{
-            "--quant": quant != "none", "--fuse_params": fuse})
-        return shapes  # the hybrid tree is served as initialised
+        return shapes  # fusing and quantization are the dense decoder's
     shapes["llama"] = jax.eval_shape(transform, shapes["llama"])
     return shapes
 

@@ -286,8 +286,14 @@ def kv_pos_bytes(cfg, kv_quant: bool = False, dtype_bytes: int = 2) -> int:
     payload and adds one f32 scale per (layer, position, kv-head)."""
     lc = cfg.llama
     hd = lc.resolved_head_dim()
-    # A hybrid decoder keeps keys and values in its attention layers only.
-    layers = lc.count("*") if hasattr(lc, "pattern") else lc.num_layers
+    # A hybrid decoder keeps keys and values in its attention layers only;
+    # a decoder with window layers, by position in its global layers only.
+    if hasattr(lc, "pattern"):
+        layers = lc.count("*")
+    elif hasattr(lc, "layer_types"):
+        layers = lc.count("full_attention")
+    else:
+        layers = lc.num_layers
     per_plane = layers * lc.num_kv_heads  # per (k|v) per position
     if kv_quant:
         return 2 * per_plane * (hd * 1 + 4)  # int8 payload + f32 scale
@@ -298,9 +304,15 @@ def fixed_state_bytes(cfg, dtype_bytes: int = 2) -> Tuple[int, int]:
     """(bytes a row, bytes a cache) of state that does not grow with the
     position. Mirrors ``nemotron_h.init_cache``: a recurrent layer's conv
     tail in the served type and its ``h`` in float32, a row; what the
-    expert layers last counted (4 int32 a layer), a cache. (0, 0) for a
-    decoder whose whole state is keys and values."""
+    expert layers last counted (4 int32 a layer), a cache. And
+    ``afmoe.init_cache``: a window layer's ring of ``sliding_window`` slots
+    of keys and values, a row. (0, 0) for a decoder whose whole state is
+    keys and values by position."""
     lc = cfg.llama
+    if hasattr(lc, "layer_types"):
+        ring = (2 * lc.count("sliding_attention") * lc.sliding_window
+                * lc.num_kv_heads * lc.resolved_head_dim() * dtype_bytes)
+        return ring, (lc.num_layers - lc.num_dense_layers) * 4 * 4
     if not hasattr(lc, "pattern"):
         return 0, 0
     row = lc.count("M") * (

@@ -95,7 +95,9 @@ SPANS = (
             "for its staging and one for its landing)"),
     SpanDef("dispatch", "sched", _SCHED, "engine",
             "_dispatch_segment: enqueue one decode / speculation segment "
-            "(chunk, live, rows, lanes, rids; set at its harvest, of a "
+            "(chunk, live, rows, lanes, rids; of a decoder with window "
+            "layers past_window, the live rows at or past the window by the "
+            "host's mirror of their lengths; set at its harvest, of a "
             "decoder with sparse experts, by step: experts_touched, "
             "expert_fullest, held_assignments a layer, routed_tokens)"),
     SpanDef("segment_fetch", "sched", _SCHED, "engine",

@@ -789,15 +789,15 @@ _spec_segment_jit = functools.partial(
 
 
 # The planes of a cache that hold a row's state, rows on axis 1: keys and
-# values by position, and what a recurrent layer keeps whatever the position
-# (models/nemotron_h.py). Any other entry of a cache is not a row's.
+# values by position, and what the decoder's module names as a row's fixed
+# state (``fixed_state``: a recurrent layer's, a window layer's ring). Any
+# other entry of a cache is not a row's.
 _BY_POSITION = ("k", "v")
-_FIXED_STATE = ("conv", "h")
 
 
 def _expert_counts(counted) -> Dict[str, list]:
     """Span args from what expert layers counted: ``counted`` (steps,
-    layers, 4) int32 of ``models/nemotron_h.STATS``. A step of a segment
+    layers, 4) int32 of ``models/experts.STATS``. A step of a segment
     that did not run, or ran for no live row, counted no token and is left
     out. By step: held experts that received a token, a layer; the tokens
     of the fullest held expert, a layer; assignments that fell on held
@@ -821,12 +821,12 @@ def _admission_readback(logits, prefilled_cache, prefill_span):
     return np.asarray(host_logits)
 
 
-def _admit_row(cache, logits_buf, row, row_cache, row_logits):
+def _admit_row(cache, logits_buf, row, row_cache, row_logits, fixed=()):
     """Insert a batch-1 prefill result at batch row ``row`` of the shared
     cache (dynamic-update on the batch axis; the prompt bucket length of
     ``row_cache`` is a static shape — one compile per bucket). Every plane
-    of the row's state goes in: a slot handed to a new request starts from
-    the new request's state."""
+    of the row's state goes in (``fixed``: the decoder's ``fixed_state``): a
+    slot handed to a new request starts from the new request's state."""
 
     def ins(buf, rbuf):
         if isinstance(buf, dict):
@@ -839,14 +839,15 @@ def _admit_row(cache, logits_buf, row, row_cache, row_logits):
     new_cache = {
         **cache,
         **{name: ins(cache[name], row_cache[name])
-           for name in _BY_POSITION + _FIXED_STATE if name in cache},
+           for name in _BY_POSITION + tuple(fixed)},
         "length": cache["length"].at[row].set(row_cache["length"][0]),
     }
     return new_cache, logits_buf.at[row].set(row_logits[0])
 
 
 _admit_row_jit = functools.partial(
-    jax.jit, donate_argnames=("cache", "logits_buf")
+    jax.jit, donate_argnames=("cache", "logits_buf"),
+    static_argnames=("fixed",)
 )(_admit_row)
 
 
@@ -860,7 +861,7 @@ def _admit_wave(cache, logits_buf, rows, wave_k, wave_v, wave_len,
     DROPS out-of-bounds scatter updates (the same rule the frozen-row
     slack reservation relies on), so pad slots write nothing.
     ``wave_fixed``: the wave's planes of state that does not grow with the
-    position (``_FIXED_STATE``), scattered whole; None where the decoder
+    position (the decoder's ``fixed_state``), scattered whole; None where the decoder
     has none."""
     s1 = (wave_k["q"] if isinstance(wave_k, dict) else wave_k).shape[2]
 
@@ -1727,18 +1728,20 @@ class ContinuousBatcher:
                 f"prefill_chunk must divide the prompt bucket grain "
                 f"{2 * SEQ_BUCKET}, got {prefill_chunk}"
             )
+        # What the decoder carries, from its module: the kinds of state a
+        # row keeps, the cap on an admission wave, its own span counts and
+        # the flags it cannot serve yet (models/eventchat.decoder_of).
         self._dec = eventchat.decoder_of(cfg)
-        if self._dec is not llama_mod:
-            eventchat.refuse_without_recurrent_state(**{
-                "--kv_cache int8": kv_quant,
-                "--kv_layout paged": kv_layout == "paged",
-                "--speculative": speculative, "--spec_buckets": spec_buckets,
-                "--draft_head": draft_head is not None,
-                "--prefill_chunk": prefill_chunk,
-                "--prefill_budget": prefill_budget > 0,
-                "--prefix_cache_mb": prefix_cache, "--preempt": preempt,
-                "--role": role != "colocated",
-                "--mesh_model": mesh is not None})
+        eventchat.refuse_unserved(cfg, **{
+            "--kv_cache int8": kv_quant,
+            "--kv_layout paged": kv_layout == "paged",
+            "--speculative": speculative, "--spec_buckets": spec_buckets,
+            "--draft_head": draft_head is not None,
+            "--prefill_chunk": prefill_chunk,
+            "--prefill_budget": prefill_budget > 0,
+            "--prefix_cache_mb": prefix_cache, "--preempt": preempt,
+            "--role": role != "colocated",
+            "--mesh_model": mesh is not None})
         if mesh is not None:
             from eventgpt_tpu.parallel import serving as serving_mod
 
@@ -1748,7 +1751,8 @@ class ContinuousBatcher:
         self.params, self.cfg = params, cfg
         # The most positions (rows x bucket) one admission wave may prefill;
         # 0: as many as there are free rows.
-        self._wave_tokens = int(getattr(self._dec, "WAVE_TOKENS", 0))
+        self._wave_tokens = int(self._dec.WAVE_TOKENS)
+        self._fixed_state = tuple(self._dec.fixed_state(cfg.llama))
         # Admission pads prompts to the serving bucket grain; a max_len off
         # the grain would let a bucketed row_cache outgrow the shared cache
         # (a trace-time shape crash). Round up once here.
@@ -2322,7 +2326,8 @@ class ContinuousBatcher:
                         self._logits_sh
                     )
                 else:
-                    admit = _admit_row_jit
+                    admit = functools.partial(
+                        _admit_row_jit, fixed=self._fixed_state)
                 self.cache, self.logits = admit(
                     self.cache, self.logits, 0, row_cache, row_logits
                 )
@@ -3686,13 +3691,19 @@ class ContinuousBatcher:
         # live: the rows this segment decodes for, by the host's mirror
         # (which a pipelined carry may be one segment ahead of); rows:
         # those it pays for.
-        live = ([req.rid for r, req in enumerate(self.rows)
-                 if req is not None and not self.frozen[r]]
-                if obs_trace.enabled() else ())
+        # own: what the decoder's module counts of them, from their lengths
+        # as the host mirrors them (no fetch).
+        live, own = (), {}
+        if obs_trace.enabled():
+            live_reqs = [req for r, req in enumerate(self.rows)
+                         if req is not None and not self.frozen[r]]
+            live = [req.rid for req in live_reqs]
+            own = self._dec.span_counts(self.cfg.llama, [
+                req.prompt_len + len(req.tokens) for req in live_reqs])
         with obs_trace.span("dispatch", "sched", chunk=chunk, live=len(live),
                             rows=self.max_batch,
                             lanes=len(self._lanes) if mixed else 0,
-                            rids=live) as rec["span"]:
+                            rids=live, **own) as rec["span"]:
             lane_out = None
             if self.speculative:
                 n_iters = max(1, chunk // spec_w)
@@ -5811,8 +5822,8 @@ class ContinuousBatcher:
                     )
                 else:
                     admit = _admit_wave_jit
-                fixed = {name: wave_cache[name] for name in _FIXED_STATE
-                         if name in wave_cache}
+                fixed = {name: wave_cache[name]
+                         for name in self._fixed_state}
                 self.cache, self.logits = admit(
                     self.cache, self.logits, rows_arr, wave_cache["k"],
                     wave_cache["v"], wave_cache["length"], wave_logits,
@@ -5949,7 +5960,8 @@ class ContinuousBatcher:
                         self._logits_sh
                     )
                 else:
-                    admit = _admit_row_jit
+                    admit = functools.partial(
+                        _admit_row_jit, fixed=self._fixed_state)
                 self.cache, self.logits = admit(
                     self.cache, self.logits, row, row_cache, row_logits
                 )

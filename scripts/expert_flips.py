@@ -29,7 +29,7 @@ from benchmark import loader, weights  # noqa: E402
 from benchmark.reference import _f32, _rms_norm  # noqa: E402
 from benchmark.references import nemotron_h as ref  # noqa: E402
 from eventgpt_tpu.config import from_hf_config  # noqa: E402
-from eventgpt_tpu.models import nemotron_h as nh  # noqa: E402
+from eventgpt_tpu.models import experts as experts_mod, nemotron_h as nh  # noqa: E402
 from eventgpt_tpu.models.synthetic import served_shapes  # noqa: E402
 
 
@@ -49,21 +49,21 @@ def main(config, seed, t, attn, out_path):
     k = z["top_k"]
 
     captured = []
-    route = nh._route
+    route = experts_mod.route
 
-    def spy(c, layer, y):
-        experts, w = route(c, layer, y)
+    def spy(routing, router, bias, y):
+        experts, w = route(routing, router, bias, y)
         captured.append(experts)
         return experts, w
 
     @jax.jit
     def program(dec, x):
         captured.clear()
-        nh._route = spy
+        experts_mod.route = spy
         try:
             logits = nh.forward(dec, lc, x[None])[0]
         finally:
-            nh._route = route
+            experts_mod.route = route
         return logits.astype(jnp.float32).argmax(-1), jnp.stack(captured)
 
     def chosen_by(layer, x, lower):

@@ -45,6 +45,7 @@ from benchmark.trace_reduce import (  # noqa: E402
     DEVICE_PLANE, MODULES_LINE, OPS_LINE, self_ns)
 
 SCOPES = ("tower", "projector", "splice", "prefill_attn", "decode_attn",
+          "prefill_attn_window", "decode_attn_window", "attn_gate",
           "ssm_scan", "ssm_step", "moe_route", "moe_experts", "moe_shared",
           "attn", "mlp", "lm_head", "sample")
 

@@ -119,3 +119,96 @@ def test_llama_train_forward_with_flash_differentiable():
     g = jax.grad(loss)(params)
     gn = sum(float(jnp.sum(jnp.abs(x))) for x in jax.tree_util.tree_leaves(g))
     assert np.isfinite(gn) and gn > 0
+
+
+# -- the blocked kernel: K / V through the grid, and the band ---------------------
+
+def _banded_reference(q, k, v, valid, window):
+    """Dense attention under the band ``0 <= i - j < window`` (None: causal),
+    K / V at their own head count."""
+    b, s, h, hd = q.shape
+    rep = h // k.shape[2]
+    k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+    sc = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(hd)
+    i, j = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+    see = j <= i
+    if window is not None:
+        see = see & (i - j < window)
+    see = see[None, None] & valid[:, None, None, :]
+    pr = jax.nn.softmax(jnp.where(see, sc, -1e30), -1)
+    out = jnp.einsum("bhqk,bkhd->bqhd", pr, v)
+    return jnp.where(valid[:, :, None, None], out, 0)
+
+
+@pytest.mark.parametrize("s, window, lens", [
+    (700, 300, [700, 333]),      # a window smaller than the sequence, ragged
+    (512, 512, [512, 512]),      # equal to it
+    (600, 5000, [600, 555]),     # larger: the whole causal square
+    (1536, 512, [1536, 1]),      # a band of whole blocks; a row of one position
+    (1100, 129, [1024, 1100]),   # a window off the block grain
+    (200, None, [150, 200]),     # no window, blocked K / V
+])
+def test_blocked_flash_matches_the_banded_reference(s, window, lens):
+    from eventgpt_tpu.ops.flash_attention import flash_attention_blocked
+
+    rng = np.random.default_rng(7)
+    b, h, kvh, hd = 2, 4, 2, 128
+    q = jnp.asarray(rng.normal(size=(b, s, h, hd)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(b, s, kvh, hd)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(b, s, kvh, hd)), jnp.float32)
+    valid = jnp.asarray(np.arange(s)[None, :] < np.array(lens)[:, None])
+    out = flash_attention_blocked(q, k, v, valid, window=window,
+                                  interpret=True)
+    ref = _banded_reference(q, k, v, valid, window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=1e-4)
+    assert np.abs(np.asarray(out[0, lens[0]:])).max(initial=0.0) == 0.0
+
+
+def test_the_band_is_guarded_and_named():
+    """A window changes the result (the mask is not decoration), and the
+    banded call carries its own name on a trace while the full causal call
+    keeps ``flash_forward``, which ``flash_roofline`` prices as a square."""
+    from eventgpt_tpu.ops import flash_attention as fa
+
+    rng = np.random.default_rng(8)
+    q, k, v = (jnp.asarray(rng.normal(size=(1, 384, 2, 128)), jnp.float32)
+               for _ in range(3))
+    valid = jnp.ones((1, 384), bool)
+    banded = fa.flash_attention_blocked(q, k, v, valid, window=100,
+                                        interpret=True)
+    whole = fa.flash_attention_blocked(q, k, v, valid, interpret=True)
+    assert np.abs(np.asarray(banded[0, :100] - whole[0, :100])).max() < 1e-5
+    assert np.abs(np.asarray(banded[0, 100:] - whole[0, 100:])).max() > 1e-2
+    traced = lambda w: str(jax.make_jaxpr(
+        lambda q, k, v: fa._flash_blocked_forward(
+            q, k, v, valid, window=w, interpret=True))(q, k, v))
+    assert "name=flash_window_forward" in traced(100)
+    for w in (None, 4096):  # no window, and one that covers the sequence
+        assert "name=flash_forward" in traced(w)
+        assert "flash_window_forward" not in traced(w)
+
+
+def test_afmoe_prefill_flash_matches_dense():
+    """The window / global decoder's prefill through the blocked kernel
+    against its dense masks, both kinds of layer, a ragged wave."""
+    from eventgpt_tpu.config import AfmoeConfig
+    from eventgpt_tpu.models import afmoe
+
+    cfg = AfmoeConfig(
+        layer_types=("sliding_attention", "full_attention"), sliding_window=96,
+        num_dense_layers=1, vocab_size=64, hidden_size=128,
+        intermediate_size=128, moe_intermediate_size=64, num_heads=2,
+        num_kv_heads=1, head_dim=128, num_experts=8, experts_held=8,
+        num_experts_per_tok=2, route_scale=2.0, max_seq_len=512)
+    params = afmoe.init_params(cfg, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(9)
+    b, t = 2, 256
+    embeds = jnp.asarray(rng.normal(size=(b, t, 128)) * 0.1, jnp.float32)
+    mask = jnp.asarray(np.arange(t)[None, :] < np.array([[t], [170]]))
+    ref = afmoe.forward(params, cfg, embeds, mask)
+    out = afmoe.forward(params, dataclasses.replace(cfg, attn_impl="flash"),
+                        embeds, mask)
+    m = np.asarray(mask)
+    np.testing.assert_allclose(np.asarray(out)[m], np.asarray(ref)[m],
+                               atol=5e-4, rtol=5e-3)

@@ -191,7 +191,8 @@ def test_the_four_shares_of_an_expert_layer_add_up_to_the_uncut_layer(t):
     whole, stats = nh._moe_block(cfg, layer, y, counted, jnp.float32)
     assert int(stats[2]) == t * 6  # every assignment falls on a held expert
     with jax.default_matmul_precision("highest"):
-        shared = nh._relu2(y @ layer["shared_up"]) @ layer["shared_down"]
+        shared = jnp.square(jax.nn.relu(y @ layer["shared_up"])) \
+            @ layer["shared_down"]
     total = shared
     held_sum = 0
     for share in range(4):
@@ -217,6 +218,73 @@ def test_the_four_shares_of_an_expert_layer_add_up_to_the_uncut_layer(t):
         assert part_hf["published"]["n_routed_experts"] == 32
     assert held_sum == t * 6
     close(total, whole)
+
+
+def _moe_block_before_it_was_shared(cfg, layer, y, counted, dtype):
+    """``nemotron_h._moe_block`` as it stood before ``models/experts.py``
+    (PR 32's tree), kept to hold the shared layer to it bit for bit."""
+    from jax import lax
+
+    from eventgpt_tpu.ops.quant import matmul as mm, matmul_f32_out as mm_f32
+
+    relu2 = lambda v: jnp.square(jax.nn.relu(v))
+    per_expert = lambda key, held: jnp.sum(
+        key[:, None] == jnp.arange(held)[None, :], axis=0, dtype=jnp.int32)
+    t = y.shape[0]
+    k, held = cfg.num_experts_per_tok, cfg.experts_held
+    with jax.default_matmul_precision("highest"):
+        s = jax.nn.sigmoid(y @ layer["router"].astype(jnp.float32))
+    _, experts = lax.top_k(s + layer["e_score_correction_bias"], k)
+    w = jnp.take_along_axis(s, experts, axis=-1)
+    w = w / jnp.sum(w, axis=-1, keepdims=True)
+    w = w * cfg.routed_scaling_factor
+    y = y.astype(dtype)
+    u = mm(y, layer["latent_down"])
+    local = experts - cfg.experts_offset
+    mine = (local >= 0) & (local < held)
+    if t <= nh.DENSE_EXPERTS_UP_TO:
+        weight = jnp.zeros((t, held + 1), jnp.float32).at[
+            jnp.arange(t)[:, None], jnp.where(mine, local, held)
+        ].set(jnp.where(mine, w, 0.0))[:, :held]
+        a = jnp.einsum("tl,elf->etf", u, layer["experts_up"])
+        o = jnp.einsum("etf,efl->etl", relu2(a), layer["experts_down"])
+        routed = jnp.einsum("etl,te->tl", o.astype(jnp.float32), weight)
+    else:
+        key = jnp.where(mine, local, held).reshape(t * k)
+        order = jnp.argsort(key)
+        sizes = per_expert(key, held)
+        a = lax.ragged_dot(u[order // k], layer["experts_up"], sizes)
+        o = lax.ragged_dot(relu2(a), layer["experts_down"], sizes)
+        o = jnp.where(mine[..., None],
+                      o[jnp.argsort(order)].reshape(t, k, -1), 0)
+        routed = jnp.einsum("tkl,tk->tl", o.astype(jnp.float32), w)
+    routed = mm_f32(routed.astype(dtype), layer["latent_up"])
+    shared = mm_f32(relu2(mm(y, layer["shared_up"])), layer["shared_down"])
+    load = per_expert(jnp.where(mine & counted[:, None], local, held)
+                      .reshape(t * k), held)
+    stats = jnp.stack([jnp.sum(load > 0), jnp.max(load), jnp.sum(load),
+                       jnp.sum(counted)]).astype(jnp.int32)
+    return routed + shared, stats
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("t", [29, nh.DENSE_EXPERTS_UP_TO + 22])
+def test_the_shared_expert_layer_is_bit_for_bit_the_hybrids_own(t, dtype):
+    """Both forms of the held experts' product, in both compute types,
+    jitted as the server runs them: the layer that two decoders now call
+    gives the hybrid what its own gave, to the last bit, counters too."""
+    cfg, params = params_of(hf_of(hybrid_override_pattern="E",
+                                  num_hidden_layers=1))
+    layer = params["layers"][0]
+    y = embeds(t, cfg.hidden_size)
+    counted = jnp.arange(t) % 3 != 0
+    new = jax.jit(lambda l, y: nh._moe_block(cfg, l, y, counted, dtype))
+    old = jax.jit(lambda l, y: _moe_block_before_it_was_shared(
+        cfg, l, y, counted, dtype))
+    (out, stats), (want, want_stats) = new(layer, y), old(layer, y)
+    assert out.dtype == want.dtype and bool(jnp.all(out == want))
+    assert bool(jnp.all(stats == want_stats)) and int(stats[3]) == int(
+        counted.sum())
 
 
 def test_the_sliced_vocabulary_is_a_smaller_vocabulary():
