@@ -304,21 +304,21 @@ def fixed_state_bytes(cfg, dtype_bytes: int = 2) -> Tuple[int, int]:
     """(bytes a row, bytes a cache) of state that does not grow with the
     position. Mirrors ``nemotron_h.init_cache``: a recurrent layer's conv
     tail in the served type and its ``h`` in float32, a row; what the
-    expert layers last counted (4 int32 a layer), a cache. And
-    ``afmoe.init_cache``: a window layer's ring of ``sliding_window`` slots
-    of keys and values, a row. (0, 0) for a decoder whose whole state is
-    keys and values by position."""
+    expert layers last counted (``models/experts.STATS``: 5 int32 a layer),
+    a cache. And ``afmoe.init_cache``: a window layer's ring of
+    ``sliding_window`` slots of keys and values, a row. (0, 0) for a decoder
+    whose whole state is keys and values by position."""
     lc = cfg.llama
     if hasattr(lc, "layer_types"):
         ring = (2 * lc.count("sliding_attention") * lc.sliding_window
                 * lc.num_kv_heads * lc.resolved_head_dim() * dtype_bytes)
-        return ring, (lc.num_layers - lc.num_dense_layers) * 4 * 4
+        return ring, (lc.num_layers - lc.num_dense_layers) * 5 * 4
     if not hasattr(lc, "pattern"):
         return 0, 0
     row = lc.count("M") * (
         (lc.conv_kernel - 1) * lc.conv_channels * dtype_bytes
         + lc.mamba_num_heads * lc.mamba_head_dim * lc.ssm_state_size * 4)
-    return row, lc.count("E") * 4 * 4
+    return row, lc.count("E") * 5 * 4
 
 
 def _mesh_divisors(cfg, mesh_shape: Optional[Dict[str, int]],

@@ -99,7 +99,8 @@ SPANS = (
             "layers past_window, the live rows at or past the window by the "
             "host's mirror of their lengths; set at its harvest, of a "
             "decoder with sparse experts, by step: experts_touched, "
-            "expert_fullest, held_assignments a layer, routed_tokens)"),
+            "expert_fullest, held_assignments, experts_over_capacity a "
+            "layer, routed_tokens)"),
     SpanDef("segment_fetch", "sched", _SCHED, "engine",
             "_harvest_segment: the blocked fetch of a segment's outputs "
             "(wait_s, rids)"),

@@ -797,17 +797,19 @@ _BY_POSITION = ("k", "v")
 
 def _expert_counts(counted) -> Dict[str, list]:
     """Span args from what expert layers counted: ``counted`` (steps,
-    layers, 4) int32 of ``models/experts.STATS``. A step of a segment
+    layers, 5) int32 of ``models/experts.STATS``. A step of a segment
     that did not run, or ran for no live row, counted no token and is left
     out. By step: held experts that received a token, a layer; the tokens
     of the fullest held expert, a layer; assignments that fell on held
-    experts, a layer; tokens routed."""
+    experts, a layer; tokens routed; whether the held assignments passed
+    the grouped product's capacity, a layer."""
     counted = np.asarray(counted)
     ran = counted[counted[:, 0, 3] > 0]
     return {"experts_touched": ran[:, :, 0].tolist(),
             "expert_fullest": ran[:, :, 1].tolist(),
             "held_assignments": ran[:, :, 2].tolist(),
-            "routed_tokens": ran[:, 0, 3].tolist()}
+            "routed_tokens": ran[:, 0, 3].tolist(),
+            "experts_over_capacity": ran[:, :, 4].tolist()}
 
 
 def _admission_readback(logits, prefilled_cache, prefill_span):

@@ -353,7 +353,8 @@ def test_the_counters_leave_with_the_segment():
     for args in by_name["dispatch"]:
         steps = len(args["routed_tokens"])
         assert 1 <= steps <= 3
-        for name in ("experts_touched", "expert_fullest", "held_assignments"):
+        for name in ("experts_touched", "expert_fullest", "held_assignments",
+                     "experts_over_capacity"):
             assert len(args[name]) == steps
             assert all(len(step) == n_e for step in args[name])
         for held, tokens in zip(args["held_assignments"],
@@ -449,4 +450,4 @@ def test_the_memory_estimate_counts_ring_and_plane():
     slot = 2 * lc.num_kv_heads * lc.resolved_head_dim() * 4
     ring = lc.count("sliding_attention") * lc.sliding_window * slot
     plane = lc.count("full_attention") * 256 * slot
-    assert est["kv_cache"] == 3 * (ring + plane + 4) + 4 * 16
+    assert est["kv_cache"] == 3 * (ring + plane + 4) + 4 * 20
