@@ -210,6 +210,25 @@ def span_counts(cfg: AfmoeConfig, lengths) -> Dict[str, int]:
                                for n in lengths)}
 
 
+def prefill_counts(cfg: AfmoeConfig, positions: int, lengths) -> Dict[str, int]:
+    """What an ``admit.prefill`` span carries of this decoder's own, from the
+    host's lengths of the wave's rows (no fetch): ``flash_blocks``, the key
+    blocks the blocked flash kernel computes a head, and
+    ``flash_blocks_interior``, those it computes without a mask, over the
+    window and global layers and the rows (``flash_block_census``); nothing
+    where attention is dense."""
+    if cfg.attn_impl != "flash":
+        return {}
+    from eventgpt_tpu.ops.flash_attention import flash_block_census
+
+    blocks = interior = 0
+    for kind, window in ((WINDOW, cfg.sliding_window), (GLOBAL, None)):
+        n, inner = flash_block_census(positions, window, lengths)
+        blocks += cfg.count(kind) * n
+        interior += cfg.count(kind) * inner
+    return {"flash_blocks": blocks, "flash_blocks_interior": interior}
+
+
 # -- a layer's two halves --------------------------------------------------------
 
 def _routing(cfg: AfmoeConfig) -> experts_mod.Routing:

@@ -46,8 +46,8 @@ def decoder_of(cfg: EventChatConfig):
     it is. Each also says what ``ContinuousBatcher`` has to know of it:
     ``fixed_state`` (the planes of a row's state that do not grow with its
     position), ``WAVE_TOKENS`` (the most positions one admission wave may
-    prefill; 0: no cap), ``span_counts`` (what a dispatch span carries of
-    the decoder's own) and ``REFUSES`` / ``REFUSED_AS`` (the flags it cannot
+    prefill; 0: no cap), ``span_counts`` and ``prefill_counts`` (what a
+    dispatch span and a prefill span carry of the decoder's own) and ``REFUSES`` / ``REFUSED_AS`` (the flags it cannot
     serve yet, each with its reason: ``refuse_unserved``). What only the
     dense decoder has (``decode_kstep``, the paged and int8 caches, fusing,
     quantization) is reached through ``llama_mod`` by name."""

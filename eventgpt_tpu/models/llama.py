@@ -111,6 +111,11 @@ def span_counts(cfg: LlamaConfig, lengths) -> Dict[str, int]:
     return {}
 
 
+def prefill_counts(cfg: LlamaConfig, positions: int, lengths) -> Dict[str, int]:
+    """Nothing of this decoder's own on a prefill span."""
+    return {}
+
+
 def embed_tokens(params: Params, input_ids: jnp.ndarray) -> jnp.ndarray:
     return params["embed_tokens"][input_ids]
 

@@ -143,6 +143,11 @@ def span_counts(cfg: HybridConfig, lengths) -> Dict[str, int]:
     return {}
 
 
+def prefill_counts(cfg: HybridConfig, positions: int, lengths) -> Dict[str, int]:
+    """Nothing of this decoder's own on a prefill span."""
+    return {}
+
+
 # -- parameters ---------------------------------------------------------------
 
 def init_params(cfg: HybridConfig, key: jax.Array, dtype=jnp.float32) -> Params:

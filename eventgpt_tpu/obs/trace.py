@@ -120,7 +120,10 @@ SPANS = (
             "encode_events_batch + splice + pad (n, rid or rids)"),
     SpanDef("prefill", "admit", _STEPS, "engine",
             "the _prefill_jit / _prefill_sharded / chunk / suffix call "
-            "(n, positions, rid or rids; set at the logits' readback, the "
+            "(n, positions, rid or rids; of a decoder with the blocked flash "
+            "kernel, flash_blocks and flash_blocks_interior: the key blocks "
+            "it computes a head and those it computes without a mask, over "
+            "the wave's layers and rows; set at the logits' readback, the "
             "prefill's expert counters as on sched.dispatch, one step)"),
     SpanDef("scatter", "admit", _STEPS, "engine",
             "_scatter_wave / _finish_admission: the logits readback (NaN "

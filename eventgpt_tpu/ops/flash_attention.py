@@ -25,6 +25,20 @@ reads head ``h // (H / KV)``), so nothing is repeated in HBM. Its
 ``flash_forward`` where the call is a full causal square, which is what
 ``benchmark/layer_metrics/flash_roofline.py`` prices under that name.
 
+The blocked kernel has two bodies for a step. A step is **interior** where
+its key block lies wholly below the diagonal and wholly inside the band and
+every key of it is valid (``_interior``; the keys' validity a row's block
+comes in scalar memory, so telling costs no pass over the tile): every pair
+it computes is visible, and it runs the online softmax with no iota, compare
+or select. Any other step runs the masked body. On an interior block the
+masked body's two selects are identities, and the interior body keeps its
+every operation in the same order, so its outputs are bit for bit the masked
+body's (``tests/test_flash_attention.py``). At Trinity's prompt (12,280
+positions, blocks of 1024, a window of 4,096) 30 of a banded call's 50
+computed blocks a head are interior and 66 of a full call's 78
+(``flash_block_census``, which ``models/afmoe.py`` sets on the prefill's
+span).
+
 Where the CPU was asked for the kernel runs in interpreter mode (tests on
 the CPU mesh; ``utils/platform.pallas_interpret`` decides, for every
 kernel in ``ops/``); the dense path in ``models/llama.py`` remains the default until the
@@ -121,17 +135,33 @@ def _band(q_start, block_q: int, block_k: int, window):
     return jax.lax.div(jnp.maximum(q_start - window + 1, 0), block_k), last
 
 
-def _flash_blocked_kernel(q_ref, k_ref, v_ref, valid_ref, out_ref,
-                          acc_ref, m_ref, l_ref, *, block_q: int,
+def _interior(q_start, kb, block_q: int, block_k: int, window, full):
+    """Whether the step of query block ``q_start`` over key block ``kb`` sees
+    every pair it computes: the key block lies wholly below the diagonal,
+    wholly inside the band, and ``full`` (every key of it is valid). Such a
+    step needs no mask. Python ints or traced scalars alike: the kernel and
+    ``flash_block_census`` ask the same question."""
+    below = kb * block_k + block_k - 1 <= q_start
+    inside = True if window is None else (
+        q_start + block_q - 1 - kb * block_k < window)
+    return below & inside & full
+
+
+def _flash_blocked_kernel(full_ref, q_ref, k_ref, v_ref, valid_ref, out_ref,
+                          acc_ref, m_ref, l_ref, *, heads: int, block_q: int,
                           block_k: int, window, scale: float):
     """One (batch*head, q-block, step) cell: step ``j`` of the key axis is
-    key block ``first + j`` of the q-block's band. q_ref (BQ, hd); k_ref /
-    v_ref (BK, hd), one block; valid_ref (1, BK) int32; out_ref (BQ, hd);
-    scratch acc (BQ, hd), m and l (BQ, 1) float32, carried over the steps."""
+    key block ``first + j`` of the q-block's band. full_ref (B * blocks,)
+    int32 in scalar memory, 1 where every key of a row's block is valid;
+    q_ref (BQ, hd); k_ref / v_ref (BK, hd), one block; valid_ref (1, BK)
+    int32; out_ref (BQ, hd); scratch acc (BQ, hd), m and l (BQ, 1) float32,
+    carried over the steps."""
     j = pl.program_id(2)
     q_start = pl.program_id(1) * block_q
     first, last = _band(q_start, block_q, block_k, window)
     kb = first + j
+    # read here: the interpreter cannot lower a program id under pl.when
+    row_blocks = (pl.program_id(0) // heads) * pl.num_programs(1)
 
     @pl.when(j == 0)
     def _():
@@ -139,24 +169,32 @@ def _flash_blocked_kernel(q_ref, k_ref, v_ref, valid_ref, out_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(kb <= last)
-    def _():
+    def accumulate(masked: bool):
         scores = jax.lax.dot_general(
             q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale       # (BQ, BK)
-        q_pos = q_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_pos = kb * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = (valid_ref[...] > 0) & (k_pos <= q_pos)
-        if window is not None:
-            mask = mask & (q_pos - k_pos < window)
-        scores = jnp.where(mask, scores, NEG_INF)
+        if masked:
+            q_pos = q_start + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0)
+            k_pos = kb * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1)
+            mask = (valid_ref[...] > 0) & (k_pos <= q_pos)
+            if window is not None:
+                mask = mask & (q_pos - k_pos < window)
+            scores = jnp.where(mask, scores, NEG_INF)
+        else:
+            # The identity on every score the select would keep; like the
+            # select it keeps the product rounded before the subtraction
+            # below, which a compiler would otherwise fuse into one rounding
+            # (the CPU's does), and the bits would differ.
+            scores = jnp.maximum(scores, NEG_INF)
         m = m_ref[...]
         m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
-        # A row with no visible key yet keeps m = NEG_INF: exp(0) = 1 a
-        # masked score, which the mask takes out again.
-        p = jnp.where(mask, jnp.exp(scores - m_new), 0.0)
+        p = jnp.exp(scores - m_new)
+        if masked:
+            # A row with no visible key yet keeps m = NEG_INF: exp(0) = 1 a
+            # masked score, which the mask takes out again.
+            p = jnp.where(mask, p, 0.0)
         corr = jnp.exp(m - m_new)
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
@@ -164,11 +202,53 @@ def _flash_blocked_kernel(q_ref, k_ref, v_ref, valid_ref, out_ref,
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
+    @pl.when(kb <= last)
+    def _():
+        interior = _interior(q_start, kb, block_q, block_k, window,
+                             full_ref[row_blocks + kb] > 0)
+        pl.when(interior)(lambda: accumulate(masked=False))
+        pl.when(jnp.logical_not(interior))(lambda: accumulate(masked=True))
+
     @pl.when(j == pl.num_programs(2) - 1)
     def _():
         # Fully-masked rows (padding queries) have l == 0; emit zeros.
         out_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
                         ).astype(out_ref.dtype)
+
+
+def _geometry(s: int, window, block: int):
+    """The blocked call's shape for ``s`` positions: the block (at most
+    ``block``, at least the positions rounded up to 128), the padded
+    length, and the window (None where the band is the whole causal
+    square)."""
+    block = min(block, ((s + 127) // 128) * 128)
+    s_pad = ((s + block - 1) // block) * block
+    if window is not None and window >= s_pad:
+        window = None
+    return block, s_pad, window
+
+
+def flash_block_census(positions: int, window, lengths,
+                       block: int = BLOCK) -> tuple:
+    """(computed, interior): the key blocks the blocked kernel computes for
+    one head of each row of a call over ``positions`` (rows right-padded to
+    ``lengths``), summed over the rows, and of them those it runs without a
+    mask, by the kernel's own rule (``_interior``). From the host's lengths;
+    nothing is fetched."""
+    block, s_pad, window = _geometry(positions, window, block)
+    computed = interior = 0
+    for length in lengths:
+        for qb in range(s_pad // block):
+            q_start = qb * block
+            first = (0 if window is None
+                     else max(q_start - window + 1, 0) // block)
+            last = (q_start + block - 1) // block
+            computed += last - first + 1
+            interior += sum(
+                bool(_interior(q_start, kb, block, block, window,
+                               (kb + 1) * block <= length))
+                for kb in range(first, last + 1))
+    return computed, interior
 
 
 @functools.partial(jax.jit, static_argnames=("window", "block", "interpret"))
@@ -182,14 +262,11 @@ def _flash_blocked_forward(q, k, v, valid, window=None, block: int = BLOCK,
     rep = h // kvh
     if interpret is None:
         interpret = pallas_interpret()
-    block = min(block, ((s + 127) // 128) * 128)
-    s_pad = ((s + block - 1) // block) * block
+    block, s_pad, window = _geometry(s, window, block)
     if s_pad != s:
         pad = ((0, 0), (0, s_pad - s), (0, 0), (0, 0))
         q, k, v = jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad)
         valid = jnp.pad(valid, ((0, 0), (0, s_pad - s)))
-    if window is not None and window >= s_pad:
-        window = None  # the band is the whole causal square
 
     def to_bh(x):  # (B, S, N, hd) -> (B*N, S, hd)
         return x.transpose(0, 2, 1, 3).reshape(-1, s_pad, hd)
@@ -197,33 +274,40 @@ def _flash_blocked_forward(q, k, v, valid, window=None, block: int = BLOCK,
     n_blocks = s_pad // block
     steps = n_blocks if window is None else min(
         n_blocks, (window - 1 + block + block - 1) // block + 1)
+    # Whether every key of a row's block is valid, a row's blocks in a row:
+    # what tells an interior block without a pass over its tile.
+    full = valid.reshape(b * n_blocks, block).all(axis=1).astype(jnp.int32)
 
     def kv_block(bh, qb, j):
         first, last = _band(qb * block, block, block, window)
         return jnp.minimum(first + j, last)
 
-    def kv_map(bh, qb, j):
+    def kv_map(bh, qb, j, full_ref):
         return (bh // h) * kvh + (bh % h) // rep, kv_block(bh, qb, j), 0
 
     kernel = functools.partial(
-        _flash_blocked_kernel, block_q=block, block_k=block, window=window,
-        scale=1.0 / math.sqrt(hd))
+        _flash_blocked_kernel, heads=h, block_q=block, block_k=block,
+        window=window, scale=1.0 / math.sqrt(hd))
     out = pl.pallas_call(
         kernel,
-        grid=(b * h, n_blocks, steps),
-        in_specs=[
-            pl.BlockSpec((None, block, hd), lambda bh, qb, j: (bh, qb, 0)),
-            pl.BlockSpec((None, block, hd), kv_map),
-            pl.BlockSpec((None, block, hd), kv_map),
-            pl.BlockSpec((None, 1, block),
-                         lambda bh, qb, j: (bh // h, 0, kv_block(bh, qb, j))),
-        ],
-        out_specs=pl.BlockSpec((None, block, hd),
-                               lambda bh, qb, j: (bh, qb, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b * h, n_blocks, steps),
+            in_specs=[
+                pl.BlockSpec((None, block, hd),
+                             lambda bh, qb, j, full_ref: (bh, qb, 0)),
+                pl.BlockSpec((None, block, hd), kv_map),
+                pl.BlockSpec((None, block, hd), kv_map),
+                pl.BlockSpec((None, 1, block),
+                             lambda bh, qb, j, full_ref: (
+                                 bh // h, 0, kv_block(bh, qb, j))),
+            ],
+            out_specs=pl.BlockSpec((None, block, hd),
+                                   lambda bh, qb, j, full_ref: (bh, qb, 0)),
+            scratch_shapes=[pltpu.VMEM((block, hd), jnp.float32),
+                            pltpu.VMEM((block, 1), jnp.float32),
+                            pltpu.VMEM((block, 1), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((b * h, s_pad, hd), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block, hd), jnp.float32),
-                        pltpu.VMEM((block, 1), jnp.float32),
-                        pltpu.VMEM((block, 1), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
@@ -231,7 +315,8 @@ def _flash_blocked_forward(q, k, v, valid, window=None, block: int = BLOCK,
         # (benchmark/layer_metrics/flash_window_roofline.py); a full causal
         # square is what flash_roofline.py prices as ``flash_forward``.
         name="flash_forward" if window is None else "flash_window_forward",
-    )(to_bh(q), to_bh(k), to_bh(v), valid.astype(jnp.int32)[:, None, :])
+    )(full, to_bh(q), to_bh(k), to_bh(v),
+      valid.astype(jnp.int32)[:, None, :])
     out = out.reshape(b, h, s_pad, hd).transpose(0, 2, 1, 3)[:, :s]
     return jnp.where(valid[:, :s, None, None], out, 0)
 

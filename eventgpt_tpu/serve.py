@@ -5637,8 +5637,10 @@ class ContinuousBatcher:
         padded, mask, prompt_len = self._prep_request(req)
         want_hidden = self.draft_head is not None
         with obs_trace.span("prefill", "admit", n=1,
-                            positions=int(padded.shape[1]),
-                            rid=req.rid) as prefill_span:
+                            positions=int(padded.shape[1]), rid=req.rid,
+                            **self._prefill_counts(int(padded.shape[1]),
+                                                   [prompt_len])
+                            ) as prefill_span:
             row_cache = self._new_row_cache(padded.shape[1])
             if self.mesh is not None:
                 pre = _prefill_sharded(
@@ -5657,6 +5659,14 @@ class ContinuousBatcher:
             (row_logits, row_cache), row_hidden = pre, None
         return _Prefilled(wave, row_cache, row_logits, row_hidden,
                           [prompt_len], prefill_span)
+
+    def _prefill_counts(self, positions: int, lengths: List[int]) -> dict:
+        """What the decoder's module counts of a full prefill of
+        ``positions`` over rows of ``lengths``, for its span; nothing while
+        tracing is off."""
+        if not obs_trace.enabled():
+            return {}
+        return self._dec.prefill_counts(self.cfg.llama, positions, lengths)
 
     def _land(self, p: _Prefilled) -> None:
         """Second half: readback, scatter and activation, against settled
@@ -5718,8 +5728,11 @@ class ContinuousBatcher:
                 padded = self._serving.shard_batch_array(padded, self.mesh)
                 mask = self._serving.shard_batch_array(mask, self.mesh)
         want_hidden = self.draft_head is not None
-        with obs_trace.span("prefill", "admit", n=n, positions=s1,
-                            rids=rids) as prefill_span:
+        # pad slots keep one position (above)
+        with obs_trace.span("prefill", "admit", n=n, positions=s1, rids=rids,
+                            **self._prefill_counts(
+                                s1, prompt_lens + [1] * (nb - n))
+                            ) as prefill_span:
             wave_cache = self._dec.init_cache(
                 self.cfg.llama, nb, s1, dtype=self._dtype,
                 quant=self.kv_quant)

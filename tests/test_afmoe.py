@@ -264,8 +264,8 @@ def test_the_sliced_vocabulary_is_a_smaller_vocabulary():
 
 # -- through ContinuousBatcher ---------------------------------------------------
 
-def _model(seed: int = 0):
-    cfg = from_hf_config(TOY, attn_impl="dense")
+def _model(seed: int = 0, attn_impl: str = "dense"):
+    cfg = from_hf_config(TOY, attn_impl=attn_impl)
     params = eventchat.init_eventchat_params(cfg, jax.random.PRNGKey(seed),
                                              jnp.float32)
     params["llama"] = params_of(TOY, seed)[1]
@@ -330,9 +330,12 @@ def test_staggered_admissions_into_recycled_slots():
 
 
 def test_the_counters_leave_with_the_segment():
+    """The expert counters on every span that carries them; and, the prefill
+    through the blocked flash kernel, its census of blocks on the prefill's
+    span (one block of 128 a layer a row: none of them interior)."""
     from eventgpt_tpu.obs import trace as obs_trace
 
-    cfg, params = _model()
+    cfg, params = _model(attn_impl="flash")
     rng = np.random.default_rng(1)
     obs_trace.configure(4096)
     try:
@@ -365,6 +368,13 @@ def test_the_counters_leave_with_the_segment():
     prompts = [e["args"] for e in ring if e.get("ph") == "X"
                and e.get("name") == "prefill"]
     assert all(a["positions"] == 128 for a in prompts)
+    for a in prompts:
+        rows = 1 << (a["n"] - 1).bit_length()
+        assert a["flash_blocks"] == cfg.llama.num_layers * rows
+        assert a["flash_blocks_interior"] == 0
+    prefill, = [d for d in obs_trace.SPANS
+                if (d.cat, d.name) == ("admit", "prefill")]
+    assert "flash_blocks and flash_blocks_interior" in prefill.brackets
 
 
 REFUSED = {

@@ -140,29 +140,120 @@ def _banded_reference(q, k, v, valid, window):
     return jnp.where(valid[:, :, None, None], out, 0)
 
 
-@pytest.mark.parametrize("s, window, lens", [
+# A row's keys: an int is a length (right padding); a pair (a, b) is every
+# position but [a, b) (a hole, or at 0 left padding).
+BLOCKED_CASES = [
     (700, 300, [700, 333]),      # a window smaller than the sequence, ragged
     (512, 512, [512, 512]),      # equal to it
     (600, 5000, [600, 555]),     # larger: the whole causal square
     (1536, 512, [1536, 1]),      # a band of whole blocks; a row of one position
     (1100, 129, [1024, 1100]),   # a window off the block grain
     (200, None, [150, 200]),     # no window, blocked K / V
-])
+    (1536, None, [(200, 260), (0, 300)]),  # not right-padded: a hole, the left
+]
+
+
+def _blocked_inputs(s, lens, dtype=jnp.float32, h=4, kvh=2):
+    rng = np.random.default_rng(7)
+    b, hd = len(lens), 128
+    q = jnp.asarray(rng.normal(size=(b, s, h, hd)), dtype)
+    k = jnp.asarray(rng.normal(size=(b, s, kvh, hd)), dtype)
+    v = jnp.asarray(rng.normal(size=(b, s, kvh, hd)), dtype)
+    pos = np.arange(s)
+    valid = np.stack([pos < n if isinstance(n, int)
+                      else (pos < n[0]) | (pos >= n[1]) for n in lens])
+    return q, k, v, jnp.asarray(valid)
+
+
+@pytest.mark.parametrize("s, window, lens", BLOCKED_CASES)
 def test_blocked_flash_matches_the_banded_reference(s, window, lens):
     from eventgpt_tpu.ops.flash_attention import flash_attention_blocked
 
-    rng = np.random.default_rng(7)
-    b, h, kvh, hd = 2, 4, 2, 128
-    q = jnp.asarray(rng.normal(size=(b, s, h, hd)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(b, s, kvh, hd)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(b, s, kvh, hd)), jnp.float32)
-    valid = jnp.asarray(np.arange(s)[None, :] < np.array(lens)[:, None])
+    q, k, v, valid = _blocked_inputs(s, lens)
     out = flash_attention_blocked(q, k, v, valid, window=window,
                                   interpret=True)
     ref = _banded_reference(q, k, v, valid, window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=1e-4)
-    assert np.abs(np.asarray(out[0, lens[0]:])).max(initial=0.0) == 0.0
+    assert np.abs(np.asarray(out)[~np.asarray(valid)]).max(initial=0.0) == 0.0
+
+
+def _census_by_pairs(valid, window, block):
+    """(computed, interior) by brute force: every (query block, key block)
+    step the kernel's grid computes, interior where no pair of it is
+    masked; summed over ``valid``'s rows (B, positions) bool."""
+    from eventgpt_tpu.ops.flash_attention import _geometry
+
+    block, s_pad, window = _geometry(valid.shape[1], window, block)
+    keys = np.zeros((valid.shape[0], s_pad), bool)
+    keys[:, :valid.shape[1]] = valid
+    i = np.arange(block)[:, None]
+    j = np.arange(block)[None, :]
+    computed = interior = 0
+    for row in keys:
+        for qb in range(s_pad // block):
+            qs = qb * block
+            first = 0 if window is None else max(qs - window + 1, 0) // block
+            for kb in range(first, (qs + block - 1) // block + 1):
+                d = (qs + i) - (kb * block + j)
+                seen = (d >= 0) & row[kb * block:(kb + 1) * block][None, :]
+                if window is not None:
+                    seen &= d < window
+                computed += 1
+                interior += bool(seen.all())
+    return computed, interior
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("s, window, lens", BLOCKED_CASES)
+def test_interior_blocks_are_the_masked_body_bit_for_bit(s, window, lens,
+                                                         dtype, monkeypatch):
+    """Blocks of 128, so that most of a call's blocks are interior: the
+    kernel as shipped against the kernel with every block classified as
+    needing its mask (the helper patched), in one trace; equal bit for
+    bit, and at float32 the band's reference all the same. Two query heads
+    on one K / V head, for the suite's time."""
+    from eventgpt_tpu.ops import flash_attention as fa
+
+    q, k, v, valid = _blocked_inputs(s, lens, dtype, h=2, kvh=1)
+    forward = fa._flash_blocked_forward.__wrapped__
+
+    def both(q, k, v, valid):
+        shipped = forward(q, k, v, valid, window, 128, True)
+        with monkeypatch.context() as mp:
+            mp.setattr(fa, "_interior", lambda *args: False)
+            masked = forward(q, k, v, valid, window, 128, True)
+        return shipped, masked
+
+    shipped, masked = map(np.asarray, jax.jit(both)(q, k, v, valid))
+    assert np.array_equal(shipped, masked)
+    # Every case but the window of 129 (no block of 128 fits inside it) has
+    # interior blocks, so the comparison is not of one body with itself.
+    assert (_census_by_pairs(np.asarray(valid), window, 128)[1] > 0
+            or window < 2 * 128 - 1)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(
+            shipped, np.asarray(_banded_reference(q, k, v, valid, window)),
+            atol=2e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("s, window, lens, block", [
+    (s, w, lens, block) for s, w, lens in BLOCKED_CASES
+    if all(isinstance(n, int) for n in lens) for block in (128, 1024)
+] + [(12288, 4096, [12280], 1024), (12288, None, [12280], 1024)])
+def test_the_census_counts_the_blocks_the_kernel_leaves_unmasked(
+        s, window, lens, block):
+    """``flash_block_census`` against every pair of every block the grid
+    computes; Trinity's prompt (12,280 in the 12,288 bucket, a window of
+    4,096) is 50 blocks a head banded, 30 of them interior, and 78 whole,
+    66 interior."""
+    from eventgpt_tpu.ops.flash_attention import flash_block_census
+
+    valid = np.arange(s)[None, :] < np.array(lens)[:, None]
+    got = flash_block_census(s, window, lens, block=block)
+    assert got == _census_by_pairs(valid, window, block)
+    if s == 12288:
+        assert got == ((50, 30) if window else (78, 66))
 
 
 def test_the_band_is_guarded_and_named():
