@@ -1534,6 +1534,14 @@ def build_engine(args, force_single: bool = False):
         # Arm fault injection from the CLI (EGPT_FAULTS works too): chaos
         # drills against a live server use the same spec grammar as tests.
         faults.configure(getattr(args, "faults"))
+    with obs_trace.span("build", "setup"):
+        return _build_engine(args, force_single)
+
+
+def _build_engine(args, force_single: bool):
+    """``build_engine`` after the arming, inside its ``setup.build`` span:
+    the model loaded and placed, the batchers built and warmed, the
+    engines (or the fleet) around them."""
     n_proc = int(getattr(args, "proc_fleet", 0) or 0)
     if n_proc > 1 and not force_single:
         # Process-fleet mode (ISSUE 11): the coordinator loads NO model
@@ -1592,15 +1600,18 @@ def build_engine(args, force_single: bool = False):
     # This process serves (a single engine, or one --worker): it takes
     # the device here, and refuses a silent fall back to the CPU.
     backend_platform()
-    cfg, params, tokenizer = load_model(
-        args.model_path, args.dtype, None, args.tokenizer_path,
-        quant=args.quant, fuse=getattr(args, "fuse_params", False),
-    )
+    with obs_trace.span("load", "setup"):
+        cfg, params, tokenizer = load_model(
+            args.model_path, args.dtype, None, args.tokenizer_path,
+            quant=args.quant, fuse=getattr(args, "fuse_params", False),
+        )
     # prepare_model places the host tree straight onto the mesh — a
     # post-hoc reshard would first materialize the full unsharded tree in
     # one chip's HBM (exactly what the mesh path exists to avoid at 7B+).
-    mesh = build_serving_mesh(args.mesh_data, args.mesh_fsdp, args.mesh_model)
-    cfg, params = prepare_model(cfg, params, tokenizer, args, mesh=mesh)
+    with obs_trace.span("place", "setup"):
+        mesh = build_serving_mesh(args.mesh_data, args.mesh_fsdp,
+                                  args.mesh_model)
+        cfg, params = prepare_model(cfg, params, tokenizer, args, mesh=mesh)
     draft_head = None
     if getattr(args, "draft_head", None):
         from eventgpt_tpu.models.medusa import load_medusa
@@ -1675,10 +1686,13 @@ def build_engine(args, force_single: bool = False):
 
         from eventgpt_tpu.fleet import Fleet
 
-        batchers = [_make_batcher() for _ in range(n_fleet)]
+        with obs_trace.span("batcher", "setup"):
+            batchers = [_make_batcher() for _ in range(n_fleet)]
         if args.warmup:
             t0 = time.perf_counter()
-            n = sum(b.warmup() for b in batchers)
+            with obs_trace.span("warmup", "setup") as warm:
+                n = sum(b.warmup() for b in batchers)
+                warm.set(programs=n)
             print(f"[serve] warmup: {n} executables in "
                   f"{time.perf_counter() - t0:.1f}s")
         engines = [
@@ -1695,10 +1709,13 @@ def build_engine(args, force_single: bool = False):
             replica_restart_s=getattr(args, "fleet_restart_s", 0) or None,
         )
     else:
-        batcher = _make_batcher()
+        with obs_trace.span("batcher", "setup"):
+            batcher = _make_batcher()
         if args.warmup:
             t0 = time.perf_counter()
-            n = batcher.warmup()
+            with obs_trace.span("warmup", "setup") as warm:
+                n = batcher.warmup()
+                warm.set(programs=n)
             print(f"[serve] warmup: {n} executables in "
                   f"{time.perf_counter() - t0:.1f}s")
         engine = _make_engine(batcher, hb_root)

@@ -160,6 +160,11 @@ METRIC_LABELS = {
         "rule": ("slo_burn", "queue_trend", "cause_shift", "breaker_flap",
                  "mem_shrink"),
     },
+    "egpt_compiles_total": {
+        # The persistent cache's verdict on one XLA compile (obs/trace.py's
+        # compile listener): loaded, compiled and written, or no cache.
+        "cache": ("hit", "miss", "off"),
+    },
 }
 
 
@@ -848,6 +853,17 @@ ALERT_TRANSITIONS = REGISTRY.counter(
     "egpt_alert_transitions_total",
     "Alert rule state transitions (firing and cleared both count; an "
     "odd count means the rule is currently active)")
+
+# -- XLA compiles (eventgpt_tpu/obs/trace.py's compile listener, counted
+#    while the trace ring is armed) --
+COMPILES = REGISTRY.counter(
+    "egpt_compiles_total",
+    "XLA compiles and persistent-cache loads JAX reported (one per "
+    "compile.xla span), by the cache's verdict: hit (loaded), miss "
+    "(compiled and written), off (compiled, no cache entry)")
+COMPILE_SECONDS = REGISTRY.counter(
+    "egpt_compile_seconds_total",
+    "Seconds inside those compiles and loads (the compile.xla spans)")
 
 # -- fault injection (eventgpt_tpu/faults.py) --
 FAULT_TRIPS = REGISTRY.counter(

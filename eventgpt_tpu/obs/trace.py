@@ -26,6 +26,24 @@ chrome://tracing:
     which is how a single request's timeline reads across overlapping
     scheduler spans;
   * ``i`` instants: point happenings (faults, breaker trips).
+    ``configure()`` writes ``ring_start`` as a fresh ring's first event: a
+    reader that finds it knows that nothing since the arming (set-up
+    included) was overwritten.
+
+Compile spans. ``configure()`` also registers, once a process, a
+``jax.monitoring`` time-span listener and event listener. JAX reports each
+trace, lowering and XLA compile (or persistent-cache load) after the fact,
+with wall-clock ends, so these three rows of ``SPANS`` are not ``with``
+blocks: the listener writes each interval as an ``X`` event, its ends put
+on ``perf_counter`` by an offset taken at ``configure()`` (the clock of
+every other span). ``args.fun`` is JAX's ``fun_name``, ``args.parent`` the
+span open on the compiling thread when the interval ends (the set-up phase
+or admission stage that asked for the program) and ``compile.xla``'s
+``args.cache`` is ``hit`` or ``miss`` from the persistent cache's events on
+the same thread inside the interval, ``off`` where neither fired. Each
+``compile.xla`` also counts into ``egpt_compiles_total{cache}`` and
+``egpt_compile_seconds_total``. Disarmed, both callbacks are the one
+``_tracer is None`` check.
 
 Disarmed (the default) every probe is one module-global ``is None``
 check, the ``faults.py`` discipline; no timestamps are read and no
@@ -51,6 +69,7 @@ import threading
 import time
 from typing import Any, Dict, List, NamedTuple, Optional
 
+from eventgpt_tpu.obs import metrics as obs_metrics
 from eventgpt_tpu.obs import profiling as obs_profiling
 
 _US = 1e6
@@ -62,11 +81,14 @@ class SpanDef(NamedTuple):
     layer: str     # PERF.md's list of layers
     thread: str
     brackets: str
+    listener: bool = False   # written by the compile listener, not a probe
 
 
 _FRONT = "HTTP front end and engine thread"
 _SCHED = "scheduler"
 _STEPS = "model steps"
+_SETUP = "set-up (cli/serve.py build_engine; the scheduler's first admissions)"
+_COMPILING = "the compiling thread"
 
 # Every interval the program marks, by the one probe below. The documents
 # (OBSERVABILITY.md "Tracing", PERF.md section 3) list these rows and
@@ -129,8 +151,26 @@ SPANS = (
             "_scatter_wave / _finish_admission: the logits readback (NaN "
             "quarantine), prefix insertion, scatter, activation (n, rid or "
             "rids)"),
-    SpanDef("batch_to_device", "train", "trainer", "trainer",
-            "train/steps.py batch_to_device: host batch to device"),
+    SpanDef("build", "setup", _SETUP, "main",
+            "cli/serve.py build_engine after the ring is armed: load, "
+            "place, batchers, warmup, engines"),
+    SpanDef("load", "setup", _SETUP, "main",
+            "cli.infer.load_model: the checkpoint (or seeded tree) to host"),
+    SpanDef("place", "setup", _SETUP, "main",
+            "cli.infer.prepare_model: quantize, fuse, place on the mesh"),
+    SpanDef("batcher", "setup", _SETUP, "main",
+            "ContinuousBatcher construction: resident caches and buffers"),
+    SpanDef("warmup", "setup", _SETUP, "main",
+            "the batcher.warmup() calls (programs: the count they return)"),
+    SpanDef("jaxpr", "compile", _SETUP, _COMPILING,
+            "JAX's jaxpr_trace_duration: tracing a function to a jaxpr "
+            "(fun, parent; nested for nested functions)", True),
+    SpanDef("lower", "compile", _SETUP, _COMPILING,
+            "JAX's jaxpr_to_mlir_module_duration: lowering to MLIR "
+            "(fun, parent)", True),
+    SpanDef("xla", "compile", _SETUP, _COMPILING,
+            "JAX's backend_compile_duration: one XLA compile or persistent-"
+            "cache load (fun, parent, cache: hit, miss or off)", True),
 )
 
 
@@ -238,6 +278,10 @@ class Tracer:
 
     def __init__(self, capacity: int = 65536):
         self.capacity = max(int(capacity), 1)
+        # time.time() + wall_offset = perf_counter: JAX's compile spans
+        # come with wall-clock ends.
+        # egpt-check: ignore[tele-clock] -- JAX reports compile intervals on the wall clock; this one read, at arming, is the offset that puts them on perf_counter
+        self.wall_offset = time.perf_counter() - time.time()
         self._buf: List[Optional[dict]] = [None] * self.capacity
         self._head = 0   # next write slot
         self._n = 0      # events ever added
@@ -340,14 +384,17 @@ _tracer: Optional[Tracer] = None
 
 
 def configure(capacity: int = 65536) -> Tracer:
-    """Arm tracing with a ring of ``capacity`` events; returns the
-    tracer. ``capacity <= 0`` disarms."""
+    """Arm tracing with a ring of ``capacity`` events, ``ring_start`` its
+    first; returns the tracer. ``capacity <= 0`` disarms."""
     global _tracer
     if capacity <= 0:
         _tracer = None
         return None  # type: ignore[return-value]
-    _tracer = Tracer(capacity)
-    return _tracer
+    _listen()
+    tracer = Tracer(capacity)
+    tracer.instant("ring_start", cat="trace")
+    _tracer = tracer
+    return tracer
 
 
 def disable() -> None:
@@ -389,3 +436,60 @@ def async_end(name: str, id: int, cat: str = "request", **args) -> None:
     t = _tracer
     if t is not None:
         t.async_end(name, id, cat=cat, args=args or None)
+
+
+# -- the compile listener (JAX reports the interval after the fact) --------
+
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jaxpr",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "xla",
+}
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
+                 "/jax/compilation_cache/cache_misses": "miss"}
+_listening = False
+
+
+def _listen() -> None:
+    """Register the two callbacks with ``jax.monitoring``, once a process
+    (JAX keeps a listener for good)."""
+    global _listening
+    if _listening:
+        return
+    from jax import monitoring
+
+    monitoring.register_event_time_span_listener(_on_time_span)
+    monitoring.register_event_listener(_on_event)
+    _listening = True
+
+
+def _on_event(event: str, **kw) -> None:
+    """A persistent-cache verdict, kept on the compiling thread for the
+    ``compile.xla`` interval that is still open around it."""
+    if _tracer is None:
+        return
+    verdict = _CACHE_EVENTS.get(event)
+    if verdict is not None:
+        _open.cache = verdict
+
+
+def _on_time_span(event: str, start: float, end: float, **kw) -> None:
+    t = _tracer
+    if t is None:
+        return
+    name = _COMPILE_EVENTS.get(event)
+    if name is None:
+        return
+    args = {"fun": str(kw.get("fun_name", "?"))}
+    stack = getattr(_open, "stack", None)
+    if stack:
+        args["parent"] = stack[-1].name
+    if name == "xla":
+        # JAX fires the verdict inside compile_or_get_cached, which only
+        # runs inside this interval: the one kept is this compile's.
+        args["cache"] = getattr(_open, "cache", None) or "off"
+        _open.cache = None
+        obs_metrics.COMPILES.inc(cache=args["cache"])
+        obs_metrics.COMPILE_SECONDS.inc(max(end - start, 0.0))
+    t.complete(name, start + t.wall_offset, end + t.wall_offset,
+               cat="compile", args=args)

@@ -25,7 +25,6 @@ from eventgpt_tpu import faults
 from eventgpt_tpu.config import EventChatConfig, MeshConfig
 from eventgpt_tpu.obs import metrics as obs_metrics
 from eventgpt_tpu.obs import profiling as obs_profiling
-from eventgpt_tpu.obs import trace as obs_trace
 from eventgpt_tpu.parallel import best_mesh_config, make_mesh, shard_params
 from eventgpt_tpu.parallel.dist import is_primary
 from eventgpt_tpu.parallel.sharding import (
@@ -323,12 +322,7 @@ class Trainer:
         if train_args.profile_dir:
             # Arms StepTraceAnnotation around every micro-step; the actual
             # capture window opens at profile_start_step (_maybe_profile).
-            # Spans carry their annotation only while the ring is armed
-            # (--trace_out arms it too), so the profile names
-            # batch_to_device.
             obs_profiling.configure(train_args.profile_dir)
-            if not obs_trace.enabled():
-                obs_trace.configure(4096)
         self.heartbeat = Heartbeat(train_args.output_dir)
         self._last_ckpt: Optional[str] = None
         if train_args.on_divergence not in ("raise", "rewind"):

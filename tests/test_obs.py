@@ -157,7 +157,7 @@ def test_trace_roundtrip_nesting_and_durations(tmp_path):
     path = str(tmp_path / "t.trace")
     n = tracer.write(path)
     evs = obs_trace.load_trace(path)
-    assert len(evs) == n == 4
+    assert len(evs) == n == 5 and evs[0]["name"] == "ring_start"
     by_name = {e["name"]: e for e in evs}
     outer, inner = by_name["outer"], by_name["inner"]
     assert all(e["dur"] >= 0 for e in evs if e["ph"] == "X")
@@ -176,7 +176,7 @@ def test_trace_ring_is_bounded():
         obs_trace.instant(f"e{i}")
     evs = tracer.events()
     assert [e["name"] for e in evs] == ["e6", "e7", "e8", "e9"]
-    assert tracer.dropped() == 6
+    assert tracer.dropped() == 7          # ring_start went first
 
 
 def test_disarmed_probes_are_noops():

@@ -26,6 +26,7 @@ from eventgpt_tpu.obs import trace as obs_trace
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SPAN_NAMES = {(d.cat, d.name) for d in obs_trace.SPANS}
+SET_UP = ("setup", "compile")     # categories recorded before the first request
 
 
 @pytest.fixture(autouse=True)
@@ -42,6 +43,12 @@ def _x(events, name):
     return [e for e in events if e["ph"] == "X" and e["name"] == name]
 
 
+def _serving(events):
+    """The ``X`` spans of served requests: set-up's phases and the compile
+    listener's spans left out."""
+    return [e for e in events if e["ph"] == "X" and e["cat"] not in SET_UP]
+
+
 # -- the probe ------------------------------------------------------------------
 
 
@@ -54,7 +61,8 @@ def test_nested_span_records_parent_rid_and_args_set_late():
             gone.drop()
         outer.set(path="wave")
     inner.set(late=True)                   # after it closed: same event
-    evs = tracer.events()
+    assert tracer.events()[0]["name"] == "ring_start"
+    evs = _serving(tracer.events())
     assert [e["name"] for e in evs] == ["inner", "outer"]
     inner_ev, outer_ev = evs
     assert inner_ev["args"] == {"parent": "outer", "rid": 7, "n": 2,
@@ -65,7 +73,7 @@ def test_nested_span_records_parent_rid_and_args_set_late():
     # a snapshot is a copy: a later set() does not reach into it
     outer.set(more=1)
     assert "more" not in outer_ev["args"]
-    assert tracer.events()[1]["args"]["more"] == 1
+    assert _serving(tracer.events())[1]["args"]["more"] == 1
 
 
 def test_close_ends_the_interval_before_the_block_does():
@@ -154,6 +162,93 @@ def test_no_annotation_is_made_unless_the_profiler_is_armed(monkeypatch):
                     ("sched.admit", {"parent": "step"})]
 
 
+def _compile_fresh(k):
+    """Compile and run a program JAX has not seen in this process."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.jit(lambda x: x * k + 1.0)(jnp.ones(3)).block_until_ready()
+
+
+def test_a_compile_leaves_its_three_spans_on_the_rings_clock():
+    tracer = obs_trace.configure(256)
+    obs_metrics.configure(True)
+    n0, s0 = obs_metrics.COMPILES.total(), obs_metrics.COMPILE_SECONDS.total()
+    a = time.perf_counter()
+    with obs_trace.span("warmup", "setup"):
+        _compile_fresh(3.5)
+    b = time.perf_counter()
+    evs = tracer.events()
+    assert evs[0]["name"] == "ring_start" and evs[0]["ph"] == "i"
+    compiles = [e for e in evs if e["cat"] == "compile"]
+    assert {e["name"] for e in compiles} == {"jaxpr", "lower", "xla"}
+    for e in compiles:
+        assert e["ph"] == "X" and a * 1e6 <= e["ts"]
+        assert e["ts"] + e["dur"] <= b * 1e6 + 1.0
+        assert e["args"]["parent"] == "warmup"
+    xla = [e for e in compiles if e["name"] == "xla"]
+    assert "jit(<lambda>)" in {e["args"]["fun"] for e in xla}
+    assert all(e["args"]["cache"] in ("hit", "miss", "off") for e in xla)
+    # the counters rise by one a compile.xla span, by its seconds
+    assert obs_metrics.COMPILES.total() - n0 == len(xla)
+    assert obs_metrics.COMPILE_SECONDS.total() - s0 == pytest.approx(
+        sum(e["dur"] for e in xla) / 1e6, rel=1e-6, abs=1e-9)
+    # the span that asked for the programs closes after them
+    (warm,) = _x(evs, "warmup")
+    assert all(warm["ts"] <= e["ts"] for e in compiles)
+
+
+def test_the_persistent_cache_verdict_rides_on_compile_xla(tmp_path):
+    """A first compile under a fresh persistent cache reads ``miss``; the
+    same program after ``jax.clear_caches()`` is loaded and reads ``hit``."""
+    import jax
+    from jax._src import compilation_cache
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_entry_size_bytes",
+            "jax_persistent_cache_min_compile_time_secs")
+    prev = {k: getattr(jax.config, k) for k in keys}
+    tracer = obs_trace.configure(512)
+    compilation_cache.reset_cache()
+    try:
+        jax.config.update(keys[0], str(tmp_path / "xla"))
+        jax.config.update(keys[1], -1)
+        jax.config.update(keys[2], 0.0)
+        _compile_fresh(7.25)
+        jax.clear_caches()
+        _compile_fresh(7.25)
+    finally:
+        for k, v in prev.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+    verdicts = [e["args"]["cache"] for e in _x(tracer.events(), "xla")
+                if e["args"]["fun"] == "jit(<lambda>)"]
+    assert verdicts == ["miss", "hit"]
+
+
+def test_disarmed_compile_callbacks_allocate_nothing():
+    obs_trace.disable()
+    span_event = "/jax/core/compile/backend_compile_duration"
+    hit = "/jax/compilation_cache/cache_hits"
+
+    def probe():
+        obs_trace._on_event(hit)
+        obs_trace._on_time_span(span_event, 1.0, 2.0, fun_name="jit(f)")
+
+    for _ in range(64):
+        probe()
+    tracemalloc.start()
+    before = tracemalloc.take_snapshot()
+    for _ in range(2000):
+        probe()
+    after = tracemalloc.take_snapshot()
+    tracemalloc.stop()
+    grown = sum(s.size_diff for s in after.compare_to(before, "filename")
+                if s.traceback[0].filename.endswith(
+                    os.path.join("obs", "trace.py")))
+    assert grown <= 0, f"{grown} bytes kept by 2000 disarmed callbacks"
+
+
 def test_a_capture_holds_the_rings_spans_on_one_clock(tmp_path):
     """With the profiler armed a jax.profiler capture's host plane holds
     ``<cat>.<name>`` for every span of the ring, and the offset between the
@@ -183,7 +278,7 @@ def test_a_capture_holds_the_rings_spans_on_one_clock(tmp_path):
                 if ev.name in ("engine.step", "sched.dispatch"):
                     found.setdefault(ev.name, []).append(
                         (ev.start_ns, ev.duration_ns, dict(ev.stats)))
-    ring = [e for e in tracer.events() if e["ph"] == "X"]
+    ring = _serving(tracer.events())
     assert len(ring) == 24
     offsets = []
     for name in ("step", "dispatch"):
@@ -221,7 +316,10 @@ def _span_calls():
 
 def test_every_call_site_is_a_row_of_the_table_and_every_row_has_one():
     calls = _span_calls()
-    assert {(c, n) for _, c, n in calls} == SPAN_NAMES
+    assert {(c, n) for _, c, n in calls} == {
+        (d.cat, d.name) for d in obs_trace.SPANS if not d.listener}
+    assert {(d.cat, d.name) for d in obs_trace.SPANS if d.listener} == {
+        ("compile", n) for n in obs_trace._COMPILE_EVENTS.values()}
     assert len({d.name for d in obs_trace.SPANS}) == len(obs_trace.SPANS), \
         "names are read without their category (GET /trace, the benchmark)"
 
@@ -368,10 +466,32 @@ def test_chains_are_identical_with_every_span_armed(served):
     assert not late, "a disarmed probe wrote to the old ring"
 
 
+def test_build_engine_records_its_phases_in_order(served):
+    """``setup.build`` holds ``setup.load``, ``setup.place`` and
+    ``setup.batcher``, one after the other on the thread that built the
+    server; the ring began before them, so nothing of set-up was lost."""
+    ring = served["ring"]
+    assert ring[0]["name"] == "ring_start"
+    (build,) = _x(ring, "build")
+    assert build["cat"] == "setup" and "parent" not in build["args"]
+    phases = [_x(ring, name)[0] for name in ("load", "place", "batcher")]
+    end = build["ts"] + build["dur"]
+    for a, b in zip([build] + phases, phases + [None]):
+        if b is not None:
+            assert b["args"]["parent"] == "build" and b["tid"] == build["tid"]
+            assert b["ts"] >= (a["ts"] if a is build else a["ts"] + a["dur"])
+    assert phases[-1]["ts"] + phases[-1]["dur"] <= end + 1.0
+    assert not _x(ring, "warmup")          # this server was not warmed
+    # what this process had not compiled yet compiled inside a phase or an
+    # admission
+    assert all(e["cat"] == "compile" and e["args"].get("parent")
+               for e in _x(ring, "xla"))
+
+
 def test_every_table_row_of_the_serving_path_was_recorded(served):
     seen = {(e["cat"], e["name"]) for e in served["ring"] if e["ph"] == "X"}
     want = {(d.cat, d.name) for d in obs_trace.SPANS
-            if d.cat != "train" and not d.name.startswith("prefix_")}
+            if d.cat not in SET_UP and not d.name.startswith("prefix_")}
     assert want <= seen, want - seen
 
 
@@ -398,7 +518,7 @@ def test_each_request_has_its_front_end_spans_and_trace_rid_returns_them(served)
 
 
 def test_every_span_has_a_request_and_every_inner_one_a_parent(served):
-    spans = [e for e in served["ring"] if e["ph"] == "X"]
+    spans = _serving(served["ring"])
     outermost = {"http_read", "host_prep", "lock_wait", "step", "idle_wait"}
     for e in spans:
         args = e.get("args") or {}
@@ -414,7 +534,7 @@ def test_every_span_has_a_request_and_every_inner_one_a_parent(served):
 
 
 def test_the_children_of_a_step_lie_inside_it(served):
-    spans = [e for e in served["ring"] if e["ph"] == "X"]
+    spans = _serving(served["ring"])
     steps = sorted(_x(spans, "step"), key=lambda e: e["ts"])
     assert len(steps) >= 4
     tid = steps[0]["tid"]
