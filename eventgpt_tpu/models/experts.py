@@ -16,8 +16,9 @@ projections wraps the routed experts (``models/nemotron_h.py``) or does not
 token count ``dense_up_to`` that parts them, are the caller's to choose at
 its own load (PERF.md): up to it, one batched product over every held
 expert, each token weighted 0 where it did not choose the expert; above it,
-a grouped product over the assignments sorted by expert
-(``lax.ragged_dot``).
+a grouped product over the assignments sorted by expert: on the chip the
+Pallas kernel ``ops/grouped_matmul.gmm`` (row tiles of 128), on the CPU
+``lax.ragged_dot`` (``_product``).
 
 **The grouped product's capacity.** The sort puts the assignments that fall
 on held experts first, and of a prompt's they are few (``held`` of the
@@ -46,19 +47,23 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from eventgpt_tpu.ops.grouped_matmul import ROW_TILE, gmm, tiles
 from eventgpt_tpu.ops.quant import matmul as _mm, matmul_f32_out as _mm_f32
+from eventgpt_tpu.utils.platform import backend_platform
 
 # Held experts that received a token, the tokens of the fullest held expert,
 # the assignments that fell on held experts, the tokens routed, and whether
 # the held assignments of every token computed passed the grouped product's
-# capacity, so that a second slab ran (0 where the call has no capacity).
-STATS = ("touched", "fullest", "held_assignments", "tokens", "over_capacity")
+# capacity, so that a second slab ran (0 where the call has no capacity),
+# and the (row tile, group) pairs the grouped product visits over every
+# assignment computed (``ops/grouped_matmul.tiles``; 0 in the batched form).
+STATS = ("touched", "fullest", "held_assignments", "tokens", "over_capacity",
+         "tiles")
 
 # A slab of the grouped product holds this many times the assignments that
 # would fall on the held experts were the router even (Trinity's prompts
 # read 0.52-1.62 times that share a layer: PERF.md), in whole row tiles.
 CAPACITY_FACTOR = 2
-ROW_TILE = 128
 
 
 class Routing(NamedTuple):
@@ -106,21 +111,32 @@ def _batched(u, w: Dict[str, Any]):
     return jnp.einsum("etf,efl->etl", a, w["down"])
 
 
+def _product():
+    """The grouped product: the Pallas kernel on the chip, at every shape a
+    served cell calls it with (faster than ``lax.ragged_dot`` at Trinity's
+    prefill slab and decode step and at the hybrid's prefill wave: PERF.md
+    section 6); ``lax.ragged_dot`` on the CPU, where the kernel would
+    run in the interpreter (as dense attention stands in for flash)."""
+    return gmm if backend_platform() == "tpu" else lax.ragged_dot
+
+
 def _grouped(x, w: Dict[str, Any], sizes):
-    """Assignments sorted by held expert: x (A, in) -> (A, out)."""
+    """Assignments sorted by held expert: x (A, in) -> (A, out); a row past
+    ``sum(sizes)`` holds nothing that may be read."""
+    product = _product()
     if "gate" in w:
-        a = (jax.nn.silu(lax.ragged_dot(x, w["gate"], sizes))
-             * lax.ragged_dot(x, w["up"], sizes))
+        a = (jax.nn.silu(product(x, w["gate"], sizes))
+             * product(x, w["up"], sizes))
     else:
-        a = relu2(lax.ragged_dot(x, w["up"], sizes))
-    return lax.ragged_dot(a, w["down"], sizes)
+        a = relu2(product(x, w["up"], sizes))
+    return product(a, w["down"], sizes)
 
 
 def capacity(assignments: int, held: int, width: int) -> int:
     """The sorted rows one slab of the grouped product holds, for a call of
     ``assignments`` over ``held`` experts of a router ``width`` wide."""
-    tiles = -(-CAPACITY_FACTOR * assignments * held // (width * ROW_TILE))
-    return max(1, tiles) * ROW_TILE
+    n = -(-CAPACITY_FACTOR * assignments * held // (width * ROW_TILE))
+    return max(1, n) * ROW_TILE
 
 
 def _in_slabs(u, w: Dict[str, Any], weight, mine, order, sizes, c: int):
@@ -172,13 +188,13 @@ def sparse_experts(routing: Routing, y, counted, dtype, *, router, bias,
     the held experts' stacked weights ``{"up", "down"}`` or ``{"gate", "up",
     "down"}`` (E, in, out); ``shared``: one expert's, unstacked; ``latent``:
     the ``(down, up)`` projections around the routed experts, or None.
-    Returns (the layer's output (T, D) float32, its ``STATS`` (5,) int32).
+    Returns (the layer's output (T, D) float32, its ``STATS`` (6,) int32).
     Every token is computed; only the counted ones are counted."""
     t = y.shape[0]
     k, held = routing.top_k, routing.held
     chosen, w = route(routing, router, bias, y)
     y = y.astype(dtype)
-    over = False
+    over, visits = False, 0
     with jax.named_scope("moe_experts"):
         u = _mm(y, latent[0]) if latent is not None else y
         local = chosen - routing.offset
@@ -198,6 +214,9 @@ def sparse_experts(routing: Routing, y, counted, dtype, *, router, bias,
             order = jnp.argsort(key)
             sizes = _per_expert(key, held)
             c = capacity(t * k, held, router.shape[-1])
+            # A slab starts on a row tile (``c`` is whole tiles), so the
+            # slabs visit the pairs the one product over them all would.
+            visits = tiles(sizes)
             if 2 * c < t * k:
                 routed, over = _in_slabs(u, experts, w, mine, order, sizes,
                                          c)
@@ -215,5 +234,5 @@ def sparse_experts(routing: Routing, y, counted, dtype, *, router, bias,
     load = _per_expert(jnp.where(mine & counted[:, None], local, held)
                        .reshape(t * k), held)
     stats = jnp.stack([jnp.sum(load > 0), jnp.max(load), jnp.sum(load),
-                       jnp.sum(counted), over]).astype(jnp.int32)
+                       jnp.sum(counted), over, visits]).astype(jnp.int32)
     return routed + shared_out, stats

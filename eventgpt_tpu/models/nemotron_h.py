@@ -78,7 +78,7 @@ Params = Dict[str, Any]
 Cache = Dict[str, jnp.ndarray]
 
 # What an expert layer counts in one call (``models/experts.STATS``), stacked
-# over the ``E`` layers: ``cache["moe_stats"]``, (E layers, 5) int32.
+# over the ``E`` layers: ``cache["moe_stats"]``, (E layers, 6) int32.
 STATS = experts_mod.STATS
 
 # Up to this many tokens (a decode step's rows) the held experts are computed
@@ -387,7 +387,7 @@ def _routing(cfg: HybridConfig) -> experts_mod.Routing:
 def _moe_block(cfg: HybridConfig, layer: Params, y, counted, dtype):
     """y (T, D) float32, normed; ``counted`` (T,) bool: the tokens that are
     real or live; ``dtype``: the compute type of the products. Returns (the
-    layer's output (T, D) float32, its ``STATS`` (5,) int32):
+    layer's output (T, D) float32, its ``STATS`` (6,) int32):
     ``models/experts.sparse_experts`` with relu^2 experts inside the latent
     projections."""
     return experts_mod.sparse_experts(

@@ -357,12 +357,16 @@ def test_the_counters_leave_with_the_segment():
         steps = len(args["routed_tokens"])
         assert 1 <= steps <= 3
         for name in ("experts_touched", "expert_fullest", "held_assignments",
-                     "experts_over_capacity"):
+                     "experts_over_capacity", "expert_tiles"):
             assert len(args[name]) == steps
             assert all(len(step) == n_e for step in args[name])
         for held, tokens in zip(args["held_assignments"],
                                 args["routed_tokens"]):
             assert all(h <= 4 * tokens for h in held)
+        # grouped at every size: a layer that held an assignment visited a
+        # row tile of it
+        for held, tiles in zip(args["held_assignments"], args["expert_tiles"]):
+            assert all(t >= 1 for t, h in zip(tiles, held) if h > 0)
         # every live row's prompt is past the ring of 16
         assert args["past_window"] == args["live"] >= 1
     prompts = [e["args"] for e in ring if e.get("ph") == "X"
@@ -460,4 +464,4 @@ def test_the_memory_estimate_counts_ring_and_plane():
     slot = 2 * lc.num_kv_heads * lc.resolved_head_dim() * 4
     ring = lc.count("sliding_attention") * lc.sliding_window * slot
     plane = lc.count("full_attention") * 256 * slot
-    assert est["kv_cache"] == 3 * (ring + plane + 4) + 4 * 20
+    assert est["kv_cache"] == 3 * (ring + plane + 4) + 4 * 24

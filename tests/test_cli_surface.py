@@ -63,9 +63,9 @@ def test_run_script_flags_exist(script):
 
 # The Python scripts left in scripts/: with a parser, or reading sys.argv
 # by hand under their ``__main__`` check.
-_WITH_PARSER = ["egpt_check", "medusa_acceptance", "serve_demo",
-                "spec_acceptance_sim", "stream_demo", "trace_scopes",
-                "train_medusa"]
+_WITH_PARSER = ["egpt_check", "gmm_timing", "medusa_acceptance",
+                "serve_demo", "spec_acceptance_sim", "stream_demo",
+                "trace_scopes", "train_medusa"]
 _BY_HAND = ["expert_flips", "lint_telemetry"]
 
 

@@ -71,6 +71,8 @@ def both_paths(layer, bias, counted, monkeypatch):
                                  whole=True)
     assert want_stats[4] == 0  # the whole path has no capacity to pass
     np.testing.assert_array_equal(stats[:4], want_stats[:4])
+    # Slabs start on row tiles: they visit the whole product's pairs.
+    assert stats[5] == want_stats[5]
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
     return got, stats
 
@@ -99,6 +101,8 @@ def test_an_even_router_fills_one_slab(kind, latent, monkeypatch):
     _, stats = both_paths(layer_of(kind, latent), biased(0.0), counted,
                           monkeypatch)
     assert 0 < stats[2] <= 512 and stats[4] == 0 and stats[3] == T
+    tiles = -(-stats[2] // ex.ROW_TILE)
+    assert tiles <= stats[5] <= tiles + HELD - 1
 
 
 @pytest.mark.parametrize("bias", [10.0, 0.2], ids=["all-held", "some-over"])
@@ -121,7 +125,7 @@ def test_no_held_assignment_at_all(kind, latent, monkeypatch):
     counted = jnp.ones((T,), bool)
     layer = layer_of(kind, latent)
     got, stats = both_paths(layer, biased(-10.0), counted, monkeypatch)
-    assert stats.tolist() == [0, 0, 0, T, 0]
+    assert stats.tolist() == [0, 0, 0, T, 0, 0]
     y = jax.random.normal(jax.random.PRNGKey(3), (T, D), jnp.float32)
     np.testing.assert_allclose(got, np.asarray(ex._one(y, layer["shared"])),
                                rtol=1e-5, atol=1e-5)
@@ -133,11 +137,13 @@ def test_padded_tokens_are_computed_and_not_counted(kind, latent,
     """A hundred real tokens of 500, every assignment held: the padded rows'
     outputs are the whole path's too, the four counters count the real
     tokens alone (400 held assignments, under the capacity), and the fifth
-    counts what was computed: 2,000 rows, four slabs."""
+    and sixth count what was computed: 2,000 rows, four slabs, at least 16
+    row tiles."""
     counted = jnp.arange(T) < 100
     layer = layer_of(kind, latent)
     got, stats = both_paths(layer, biased(10.0), counted, monkeypatch)
-    assert stats.tolist() == [HELD, stats[1], 100 * K, 100, 1]
+    assert stats.tolist() == [HELD, stats[1], 100 * K, 100, 1, stats[5]]
+    assert 2000 // ex.ROW_TILE < stats[5] <= 2000 // ex.ROW_TILE + HELD
     alone, _ = both_paths(layer, biased(10.0), jnp.ones((T,), bool),
                           monkeypatch)
     np.testing.assert_array_equal(got, alone)
@@ -160,9 +166,24 @@ def test_a_call_with_nothing_to_cut_is_the_one_grouped_product(
 
 def test_the_span_args_carry_the_fifth_counter():
     counted = np.zeros((3, 2, len(ex.STATS)), np.int32)
-    counted[0] = [[3, 5, 9, 4, 1], [2, 4, 7, 4, 0]]
-    counted[2] = [[1, 1, 1, 1, 0], [1, 1, 1, 1, 0]]  # step 1 ran for no row
+    counted[0] = [[3, 5, 9, 4, 1, 5], [2, 4, 7, 4, 0, 2]]
+    counted[2] = [[1, 1, 1, 1, 0, 1], [1, 1, 1, 1, 0, 0]]  # step 1: no row
     args = _expert_counts(counted)
     assert args["experts_over_capacity"] == [[1, 0], [0, 0]]
     assert args["held_assignments"] == [[9, 7], [1, 1]]
     assert args["routed_tokens"] == [4, 1]
+    assert args["expert_tiles"] == [[5, 2], [1, 0]]
+
+
+@pytest.mark.parametrize("kind, latent", FORMS, ids=IDS)
+def test_the_slabs_through_the_kernel(kind, latent, monkeypatch):
+    """A router that passes the capacity, so that the loop runs a second
+    slab, with the chip's grouped product (the kernel, here in the
+    interpreter) against the CPU's ``lax.ragged_dot``."""
+    layer, counted = layer_of(kind, latent), jnp.ones((T,), bool)
+    want, want_stats, _, _ = run(layer, biased(0.2), counted, monkeypatch)
+    monkeypatch.setattr(ex, "backend_platform", lambda: "tpu")
+    got, stats, fn, y = run(layer, biased(0.2), counted, monkeypatch)
+    assert "expert_gmm" in str(jax.make_jaxpr(fn)(y, biased(0.2)))
+    assert stats[4] == 1 and stats.tolist() == want_stats.tolist()
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)

@@ -396,7 +396,7 @@ def test_the_counters_leave_with_the_segment():
         assert 1 <= steps <= 3
         assert all(1 <= t <= 2 for t in args["routed_tokens"])
         for name in ("experts_touched", "expert_fullest", "held_assignments",
-                     "experts_over_capacity"):
+                     "experts_over_capacity", "expert_tiles"):
             assert len(args[name]) == steps
             assert all(len(step) == cfg.llama.count("E") for step in args[name])
         for touched, held, tokens in zip(args["experts_touched"],
@@ -404,8 +404,10 @@ def test_the_counters_leave_with_the_segment():
                                          args["routed_tokens"]):
             assert all(0 <= t <= min(8, h) for t, h in zip(touched, held))
             assert all(h <= 6 * tokens for h in held)
+        # a decode step is batched: no grouped product, no row tile
+        assert all(t == 0 for step in args["expert_tiles"] for t in step)
     keys = ("experts_touched", "expert_fullest", "held_assignments",
-            "routed_tokens", "experts_over_capacity")
+            "routed_tokens", "experts_over_capacity", "expert_tiles")
     assert ([[a[k] for k in keys] for a in by_name["dispatch"]]
             == [[a[k] for k in keys] for a in by_name["harvest"]])
     (wave,) = by_name["prefill"]  # both requests met at one boundary
@@ -496,4 +498,4 @@ def test_the_memory_estimate_counts_both_kinds_of_state():
     h = lc.mamba_num_heads * lc.mamba_head_dim * lc.ssm_state_size * 4
     conv = (lc.conv_kernel - 1) * lc.conv_channels * 4
     kv = 2 * lc.num_kv_heads * lc.resolved_head_dim() * 4 * 256
-    assert est["kv_cache"] == 3 * (5 * (h + conv) + kv + 4) + 5 * 20
+    assert est["kv_cache"] == 3 * (5 * (h + conv) + kv + 4) + 5 * 24
