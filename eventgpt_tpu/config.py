@@ -136,11 +136,25 @@ class LlamaConfig:
 @dataclass(frozen=True)
 class HybridConfig:
     """A decoder whose blocks are of several kinds (``models/nemotron_h.py``):
-    each block is ``x + mixer(rmsnorm(x))`` with exactly one mixer, chosen
-    by its character of ``pattern``: ``M`` a Mamba-2 state-space mixer,
-    ``E`` sparse experts in a latent space beside one shared expert, ``*``
-    GQA attention without positional embedding and without an MLP. Field
-    names follow the published ``nemotron_h`` ``config.json``.
+    each block is ``x + residual_multiplier * mixer(rmsnorm(x))`` with
+    exactly one mixer, chosen by its character of ``pattern``: ``M`` a
+    Mamba-2 state-space mixer, ``E`` sparse experts (in a latent space where
+    ``moe_latent_size`` is set) beside one shared expert, ``*`` GQA attention
+    without positional embedding and without an MLP. Field names follow the
+    published ``nemotron_h`` ``config.json``; a ``granitemoehybrid`` file
+    (``granite_from_hf``) is read into the same fields, each of its layers a
+    mixer block followed by an ``E`` block.
+
+    What the two files differ in is a field each, whose default is
+    ``nemotron_h``'s: the scalars ``embedding_multiplier`` (the input
+    embeddings), ``residual_multiplier`` (every block's output),
+    ``attention_multiplier`` (the attention scores; None: ``1 /
+    sqrt(head_dim)``) and ``logits_scaling`` (the logits are divided by it);
+    the ``router`` (``sigmoid``: sigmoid scores, the top ``k`` of scores
+    plus a correction bias; ``softmax_topk``: the top ``k`` logits and a
+    softmax over them); ``mlp_hidden_act`` (``relu2``: ``down(relu(up x)^2)``
+    experts; ``silu``: gated ``down(silu(gate x) * up x)`` experts); and
+    ``tie_word_embeddings`` (the head reads the embedding table).
 
     ``n_routed_experts`` is the router's width (every expert of the
     deployment); this process holds ``experts_held`` of them from
@@ -169,12 +183,22 @@ class HybridConfig:
     experts_offset: int = 0
     num_experts_per_tok: int = 22
     moe_intermediate_size: int = 2688
-    moe_latent_size: int = 1024
+    moe_latent_size: Optional[int] = 1024
     moe_shared_expert_intermediate_size: int = 5376
     routed_scaling_factor: float = 5.0
     norm_topk_prob: bool = True
+    router: str = "sigmoid"
+    mlp_hidden_act: str = "relu2"
+    # The scalars of a muP-parametrised file (1 / None: none applied).
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
+    tie_word_embeddings: bool = False
 
     _KINDS = "ME*"
+    _ROUTERS = ("sigmoid", "softmax_topk")
+    _ACTS = ("relu2", "silu")
 
     def __post_init__(self):
         if not self.pattern or set(self.pattern) - set(self._KINDS):
@@ -191,6 +215,11 @@ class HybridConfig:
                 f"not among the router's {self.n_routed_experts}")
         if self.mamba_num_heads % self.n_groups:
             raise ValueError("mamba_num_heads must divide by n_groups")
+        if self.router not in self._ROUTERS:
+            raise ValueError(f"router {self.router!r}: one of {self._ROUTERS}")
+        if self.mlp_hidden_act not in self._ACTS:
+            raise ValueError(f"mlp_hidden_act {self.mlp_hidden_act!r}: one "
+                             f"of {self._ACTS}")
 
     @property
     def num_layers(self) -> int:
@@ -460,13 +489,16 @@ def from_hf_config(hf: dict, attn_impl: Optional[str] = None) -> EventChatConfig
     ``event_feature_adaptor`` / ``mm_use_im_start_end`` / ``mm_use_im_patch_token``
     (``model/EventChatModel.py:75``, ``inference.py:33-34``).
     ``attn_impl=None`` resolves per platform (``default_attn_impl``).
-    ``model_type`` ``nemotron_h`` builds the hybrid decoder's configuration
-    (``hybrid_from_hf``), ``afmoe`` the window / global decoder's
-    (``afmoe_from_hf``); every other file a dense one.
+    ``model_type`` ``nemotron_h`` or ``granitemoehybrid`` builds the hybrid
+    decoder's configuration (``hybrid_from_hf``, ``granite_from_hf``),
+    ``afmoe`` the window / global decoder's (``afmoe_from_hf``); every other
+    file a dense one.
     """
     attn_impl = attn_impl if attn_impl is not None else default_attn_impl()
     if hf.get("model_type") == "nemotron_h":
         llama = hybrid_from_hf(hf, attn_impl)
+    elif hf.get("model_type") == "granitemoehybrid":
+        llama = granite_from_hf(hf, attn_impl)
     elif hf.get("model_type") == "afmoe":
         llama = afmoe_from_hf(hf, attn_impl)
     else:
@@ -516,6 +548,78 @@ def hybrid_from_hf(hf: dict, attn_impl: str) -> HybridConfig:
             "moe_shared_expert_intermediate_size"],
         routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
         norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+    )
+
+
+def granite_from_hf(hf: dict, attn_impl: str) -> HybridConfig:
+    """The published ``granitemoehybrid`` keys -> ``HybridConfig``. Each of
+    the first ``num_hidden_layers`` entries of ``layer_types`` is a mixer
+    block (``mamba``: ``M``, ``attention``: ``*``) followed by an expert
+    block (``E``): softmax over the top ``num_experts_per_tok`` router
+    logits, gated SwiGLU experts of ``intermediate_size`` on the full width
+    beside a gated shared expert of ``shared_intermediate_size``. A file
+    that holds a share of the experts gives the count it holds under
+    ``num_local_experts`` and the router's width under
+    ``published.num_local_experts`` (``experts_offset``: where the share
+    starts, 0 unless stated). What the decoder does not implement is
+    refused by the key's name."""
+    depth = int(hf["num_hidden_layers"])
+    types = list(hf["layer_types"])[:depth]
+    if len(types) != depth:
+        raise ValueError(f"layer_types has {len(types)} layers, "
+                         f"num_hidden_layers asks for {depth}")
+    mixers = {"mamba": "M", "attention": "*"}
+    unknown = sorted(set(types) - set(mixers))
+    if unknown:
+        raise ValueError(f"layer_types {unknown}: only 'mamba' and "
+                         f"'attention'")
+    refused = {
+        "position_embedding_type": ("nope", "only 'nope' (no positional "
+                                    "embedding in the attention layers)"),
+        "rope_scaling": (None, "positions are not embedded"),
+        "mamba_proj_bias": (False, "the mixer's projections have no bias"),
+        "mamba_conv_bias": (True, "the convolution has a bias"),
+        "attention_bias": (False, "the attention projections have no bias"),
+        "hidden_act": ("silu", "only silu (gated SwiGLU experts)"),
+        "normalization_function": ("rmsnorm", "only rmsnorm"),
+    }
+    for key, (served, why) in refused.items():
+        if key in hf and hf[key] != served:
+            raise ValueError(f"{key} {hf[key]!r} is not implemented: {why}")
+    heads, head = int(hf["mamba_n_heads"]), int(hf["mamba_d_head"])
+    expand = int(hf.get("mamba_expand", 2))
+    if expand * int(hf["hidden_size"]) != heads * head:
+        raise ValueError(f"mamba_expand {expand} x hidden_size "
+                         f"{hf['hidden_size']} is not mamba_n_heads x "
+                         f"mamba_d_head = {heads * head}")
+    if not int(hf.get("shared_intermediate_size", 0)):
+        raise ValueError("shared_intermediate_size 0 (no shared expert) is "
+                         "not implemented")
+    held = int(hf["num_local_experts"])
+    return HybridConfig(
+        pattern="".join(mixers[t] + "E" for t in types), attn_impl=attn_impl,
+        vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"], head_dim=hf.get("head_dim"),
+        rms_norm_eps=hf.get("rms_norm_eps", 1e-5),
+        max_seq_len=hf.get("max_position_embeddings", 2048),
+        mamba_num_heads=heads, mamba_head_dim=head,
+        n_groups=hf["mamba_n_groups"], ssm_state_size=hf["mamba_d_state"],
+        conv_kernel=hf["mamba_d_conv"], chunk_size=hf["mamba_chunk_size"],
+        n_routed_experts=int(hf.get("published", {}).get(
+            "num_local_experts", held)),
+        experts_held=held, experts_offset=int(hf.get("experts_offset", 0)),
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        moe_intermediate_size=hf["intermediate_size"], moe_latent_size=None,
+        moe_shared_expert_intermediate_size=hf["shared_intermediate_size"],
+        routed_scaling_factor=1.0, norm_topk_prob=False,
+        router="softmax_topk", mlp_hidden_act="silu",
+        embedding_multiplier=float(hf.get("embedding_multiplier", 1.0)),
+        residual_multiplier=float(hf.get("residual_multiplier", 1.0)),
+        attention_multiplier=(float(hf["attention_multiplier"])
+                              if "attention_multiplier" in hf else None),
+        logits_scaling=float(hf.get("logits_scaling", 1.0)),
+        tie_word_embeddings=bool(hf.get("tie_word_embeddings", False)),
     )
 
 

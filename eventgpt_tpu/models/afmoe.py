@@ -90,6 +90,13 @@ def fixed_state(cfg: AfmoeConfig) -> Tuple[str, ...]:
 # the prompts this decoder is served for are three windows long, so a wave
 # is one such prompt (12,288 positions), or up to 16 short ones.
 WAVE_TOKENS = 16384
+
+
+def wave_tokens(cfg: AfmoeConfig) -> int:
+    """The most positions one admission wave may prefill."""
+    return WAVE_TOKENS
+
+
 # Up to this many tokens the held experts are computed as one batched product
 # over every held expert, above it as a grouped product over the assignments
 # sorted by expert (``models/experts.py``). At the served load (32 rows x 4

@@ -45,7 +45,7 @@ def decoder_of(cfg: EventChatConfig):
     the decoder's subtree of the parameters is ``params["llama"]`` whichever
     it is. Each also says what ``ContinuousBatcher`` has to know of it:
     ``fixed_state`` (the planes of a row's state that do not grow with its
-    position), ``WAVE_TOKENS`` (the most positions one admission wave may
+    position), ``wave_tokens`` (the most positions one admission wave may
     prefill; 0: no cap), ``span_counts`` and ``prefill_counts`` (what a
     dispatch span and a prefill span carry of the decoder's own) and ``REFUSES`` / ``REFUSED_AS`` (the flags it cannot
     serve yet, each with its reason: ``refuse_unserved``). What only the
@@ -192,7 +192,11 @@ def splice_embeddings(
             f"{num_events} event sentinel(s) in prompt but "
             f"{event_tokens.shape[0]} event clip(s) provided"
         )
-    embed_dtype = params["llama"]["embed_tokens"].dtype
+    # The embedding's type: a decoder whose table is tied to its head has no
+    # ``embed_tokens`` leaf (``nemotron_h.embed_tokens``).
+    table = params["llama"].get("embed_tokens")
+    embed_dtype = (params["llama"]["lm_head"] if table is None
+                   else table).dtype
     parts: List[jnp.ndarray] = []
     with jax.named_scope("splice"):
         for kind, val in _interleave_segments(segments):

@@ -2,11 +2,13 @@
 
 ``s = sigmoid(y W_r)`` in float32 over every expert of the deployment; the
 ``top_k`` largest of ``s + bias`` (the bias chooses only); weights ``s`` at
-the chosen, divided by their sum where ``normalise``, times ``scale``; the
-result is the weighted sum of the chosen experts **over the experts held
-here** (``offset .. offset + held``: expert parallelism without its
-exchange, what the absent experts would add is left out) plus one shared
-expert on the full width.
+the chosen, divided by their sum where ``normalise``, times ``scale``. Or,
+where the router's form is ``softmax_topk``: the ``top_k`` largest logits
+``y W_r`` and a softmax over those, times ``scale``. The result is the
+weighted sum of the chosen experts **over the experts held here**
+(``offset .. offset + held``: expert parallelism without its exchange, what
+the absent experts would add is left out) plus one shared expert on the
+full width.
 
 What differs between the decoders is told by what a caller hands over, not
 by a flag: an expert whose weights have a ``gate`` is ``down(silu(gate x) *
@@ -69,12 +71,14 @@ CAPACITY_FACTOR = 2
 class Routing(NamedTuple):
     """The router's constants: experts a token, experts held here and where
     the share starts among the deployment's, whether the chosen weights are
-    divided by their sum, and what they are multiplied by."""
+    divided by their sum, what they are multiplied by, and the router's
+    form (``sigmoid`` or ``softmax_topk``)."""
     top_k: int
     held: int
     offset: int
     normalise: bool
     scale: float
+    form: str = "sigmoid"
 
 
 def relu2(x):
@@ -83,8 +87,14 @@ def relu2(x):
 
 def route(routing: Routing, router, bias, y):
     """y (T, D) float32 -> (experts (T, K) int32 over the whole deployment,
-    weights (T, K) float32). Scores, choice and weights in float32."""
+    weights (T, K) float32). Scores, choice and weights in float32; ``bias``
+    is None for the ``softmax_topk`` form, which has none."""
     with jax.named_scope("moe_route"):
+        if routing.form == "softmax_topk":
+            with jax.default_matmul_precision("highest"):
+                logits = y @ router.astype(jnp.float32)
+            top, experts = lax.top_k(logits, routing.top_k)
+            return experts, jax.nn.softmax(top, axis=-1) * routing.scale
         with jax.default_matmul_precision("highest"):
             s = jax.nn.sigmoid(y @ router.astype(jnp.float32))
         _, experts = lax.top_k(s + bias, routing.top_k)

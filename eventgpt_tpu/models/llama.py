@@ -101,6 +101,11 @@ WAVE_TOKENS = 0
 REFUSES: Dict[str, str] = {}
 
 
+def wave_tokens(cfg: LlamaConfig) -> int:
+    """The most positions one admission wave may prefill; 0: no cap."""
+    return WAVE_TOKENS
+
+
 def fixed_state(cfg: LlamaConfig) -> Tuple[str, ...]:
     """No plane of a row's state but keys and values by position."""
     return ()
@@ -237,7 +242,8 @@ def _attn_block(cfg: LlamaConfig, q_proj: jnp.ndarray, layer: Params,
                 use_flash: bool = False,
                 ring_fn=None,
                 flash_fn=None,
-                scope: str = "attn") -> jnp.ndarray:
+                scope: str = "attn",
+                scale: Optional[float] = None) -> jnp.ndarray:
     """Shared attention plumbing (RoPE on the precomputed q projection + o
     proj) with a score-computation switch: dense additive ``mask``
     (B,1,Q,S), the Pallas flash kernel with a (B,S) ``valid`` padding mask
@@ -250,7 +256,8 @@ def _attn_block(cfg: LlamaConfig, q_proj: jnp.ndarray, layer: Params,
     reads K / V as they are; the kernels and the ring take K / V repeated to
     H heads (``_repeat_kv``). ``scope`` names scores, softmax and value
     product (and a kernel's GQA repeat) on a device trace (``prefill_attn``
-    / ``decode_attn``; metadata only)."""
+    / ``decode_attn``; metadata only). ``scale`` multiplies the scores of the
+    dense branch and the flash kernel (None: ``1 / sqrt(head_dim)``)."""
     b, q_len, _ = q_proj.shape
     h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim()
 
@@ -273,26 +280,31 @@ def _attn_block(cfg: LlamaConfig, q_proj: jnp.ndarray, layer: Params,
         elif use_flash:
             from eventgpt_tpu.ops.flash_attention import flash_attention
 
-            ctx = flash_attention(q, k, v, valid=valid, causal=True)
+            ctx = flash_attention(q, k, v, valid=valid, causal=True,
+                                  scale=scale)
         else:
-            ctx = grouped_attention(q, k_full, v_full, mask)
+            ctx = grouped_attention(q, k_full, v_full, mask, scale)
     return _mm(ctx.reshape(b, q_len, h * hd), layer["attn"]["o"])
 
 
 def grouped_attention(q: jnp.ndarray, k_full: jnp.ndarray,
-                      v_full: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
+                      v_full: jnp.ndarray, mask: jnp.ndarray,
+                      scale: Optional[float] = None) -> jnp.ndarray:
     """Dense attention of q (B, Q, H, hd) over K / V as stored (B, S, KV,
     hd) under an additive ``mask`` (B, 1, Q, S): the read every decode step
     takes. Queries regrouped per KV head (h = g * rep + r, _repeat_kv's
     order) and contracted against K / V as they are: M = rep * Q rows per
-    (b, g) product, no repeated and no f32 copy of K / V. Returns (B, Q,
-    KV, rep, hd) in q's type."""
+    (b, g) product, no repeated and no f32 copy of K / V. Scores times
+    ``scale`` (None: ``1 / sqrt(hd)``). Returns (B, Q, KV, rep, hd) in q's
+    type."""
     b, q_len, h, hd = q.shape
     kvh = k_full.shape[2]
     qg = q.reshape(b, q_len, kvh, h // kvh, hd)
     scores = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k_full,
                         preferred_element_type=jnp.float32)
-    scores = scores * (1.0 / math.sqrt(hd)) + mask[:, :, None]
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
+    scores = scores * scale + mask[:, :, None]
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("bgrqk,bkgd->bqgrd", probs, v_full)
 

@@ -17,6 +17,21 @@ mixer, chosen by the block's character of ``HybridConfig.pattern``:
   ``shared`` one relu^2 MLP on the full width.
 * ``*``, attention: GQA, causal, no positional embedding, no MLP.
 
+A ``granitemoehybrid`` file (``config.granite_from_hf``) is walked by the same
+code: each of its layers is an ``M`` or ``*`` block followed by an ``E`` block
+whose experts are gated (``down(silu(gate x) * up x)``), on the full width
+(no latent) and routed by a softmax over the top ``k`` logits
+(``experts.route``), beside a gated shared expert; its muP scalars are
+applied where the configuration sets them, each as a Python branch on the
+static field, so a configuration without them traces the programs it traced
+before: the input embeddings times ``embedding_multiplier``, every block's
+output times ``residual_multiplier``, the attention scores times
+``attention_multiplier`` in place of ``1 / sqrt(head_dim)``, the logits
+divided by ``logits_scaling``. With ``tie_word_embeddings`` one table is
+both the input embedding and the head: one leaf, ``lm_head`` (hidden,
+vocab), whose column of a token is its embedding (``embed_tokens``), so that
+what reads or writes a head's columns reads or writes the table's rows.
+
 The parameters are a list of blocks in the pattern's order, each a dict of
 its kind's leaves, walked by a Python loop (three bodies, eleven blocks as
 served). They are **not** stacked on a leading axis as ``models/llama.py``
@@ -69,7 +84,7 @@ from jax import lax
 from eventgpt_tpu.config import HybridConfig
 from eventgpt_tpu.models import experts as experts_mod
 from eventgpt_tpu.models.llama import (
-    _attn_block, _cache_write, _lm_head, embed_tokens, rms_norm,
+    _attn_block, _cache_write, _lm_head, embed_tokens as _embed_rows, rms_norm,
 )
 from eventgpt_tpu.ops.quant import matmul as _mm, matmul_f32_out as _mm_f32
 from eventgpt_tpu.ops.ssm_step import ssm_step
@@ -102,8 +117,27 @@ def fixed_state(cfg: HybridConfig) -> Tuple[str, ...]:
 
 # The most positions one admission wave may prefill at once (rows x bucket):
 # the expert layer sorts ``num_experts_per_tok`` assignments a position, and
-# its buffers grow with them. ``ContinuousBatcher`` cuts a wave here.
+# its buffers grow with them. ``ContinuousBatcher`` cuts a wave here
+# (``wave_tokens``).
 WAVE_TOKENS = 8192
+# What a wave's sorted rows may hold, in elements: WAVE_TOKENS positions of
+# 22 assignments into a 1,024-wide latent, which fit one v5e beside 64 rows
+# of state (PERF.md section 4).
+WAVE_SORTED_ROWS = WAVE_TOKENS * 22 * 1024
+
+
+def wave_tokens(cfg: HybridConfig) -> int:
+    """``WAVE_TOKENS``, halved while a wave's sorted rows (positions x
+    ``num_experts_per_tok``, each as wide as the routed experts' input: the
+    latent, or the full width where there is none) would pass
+    ``WAVE_SORTED_ROWS``."""
+    width = cfg.moe_latent_size or cfg.hidden_size
+    n = WAVE_TOKENS
+    while n > 1 and n * cfg.num_experts_per_tok * width > WAVE_SORTED_ROWS:
+        n //= 2
+    return n
+
+
 # A decoder with recurrent layers keeps, beside keys and values by position,
 # a state a row that cannot be sliced at a position, rolled back by
 # ``length`` or shared between rows. The mechanisms below move, share or
@@ -185,18 +219,27 @@ def init_params(cfg: HybridConfig, key: jax.Array, dtype=jnp.float32) -> Params:
                 "out_proj": dense(inner, (inner, d), residual),
             }
         if kind == "E":
-            return {
-                "norm": jnp.ones((d,), dtype),
-                "router": dense(d, (d, cfg.n_routed_experts)),
-                "e_score_correction_bias": jnp.zeros(
-                    (cfg.n_routed_experts,), jnp.float32),
-                "latent_down": dense(d, (d, lat)),
-                "experts_up": dense(lat, (cfg.experts_held, lat, f)),
-                "experts_down": dense(f, (cfg.experts_held, f, lat)),
-                "latent_up": dense(lat, (lat, d), residual),
-                "shared_up": dense(d, (d, fs)),
-                "shared_down": dense(fs, (fs, d), residual),
-            }
+            gated = cfg.mlp_hidden_act == "silu"
+            e = lat if lat is not None else d  # the routed experts' width
+            out = {"norm": jnp.ones((d,), dtype),
+                   "router": dense(d, (d, cfg.n_routed_experts))}
+            if cfg.router == "sigmoid":
+                out["e_score_correction_bias"] = jnp.zeros(
+                    (cfg.n_routed_experts,), jnp.float32)
+            if lat is not None:
+                out["latent_down"] = dense(d, (d, lat))
+            if gated:
+                out["experts_gate"] = dense(e, (cfg.experts_held, e, f))
+            out["experts_up"] = dense(e, (cfg.experts_held, e, f))
+            out["experts_down"] = dense(
+                f, (cfg.experts_held, f, e), 1.0 if lat is not None else residual)
+            if lat is not None:
+                out["latent_up"] = dense(lat, (lat, d), residual)
+            if gated:
+                out["shared_gate"] = dense(d, (d, fs))
+            out["shared_up"] = dense(d, (d, fs))
+            out["shared_down"] = dense(fs, (fs, d), residual)
+            return out
         return {
             "norm": jnp.ones((d,), dtype),
             "q_proj": dense(d, (d, qd)),
@@ -205,13 +248,23 @@ def init_params(cfg: HybridConfig, key: jax.Array, dtype=jnp.float32) -> Params:
             "o_proj": dense(qd, (qd, d), residual),
         }
 
-    return {
-        "embed_tokens": jax.random.normal(next(keys), (cfg.vocab_size, d),
-                                          dtype) * 0.02,
-        "layers": [block(kind) for kind in cfg.pattern],
-        "final_norm": jnp.ones((d,), dtype),
-        "lm_head": dense(d, (d, cfg.vocab_size)),
-    }
+    table = jax.random.normal(next(keys), (cfg.vocab_size, d), dtype) * 0.02
+    params = {"layers": [block(kind) for kind in cfg.pattern],
+              "final_norm": jnp.ones((d,), dtype)}
+    if cfg.tie_word_embeddings:
+        params["lm_head"] = table.T
+    else:
+        params["embed_tokens"] = table
+        params["lm_head"] = dense(d, (d, cfg.vocab_size))
+    return params
+
+
+def embed_tokens(params: Params, input_ids: jnp.ndarray) -> jnp.ndarray:
+    """The ids' rows of the embedding table; where the table is tied to the
+    head (no ``embed_tokens`` leaf), the ids' columns of ``lm_head``."""
+    if "embed_tokens" in params:
+        return _embed_rows(params, input_ids)
+    return jnp.moveaxis(jnp.take(params["lm_head"], input_ids, axis=1), 0, -1)
 
 
 def init_cache(cfg: HybridConfig, batch: int, max_len: int,
@@ -381,22 +434,53 @@ def _routing(cfg: HybridConfig) -> experts_mod.Routing:
     return experts_mod.Routing(
         top_k=cfg.num_experts_per_tok, held=cfg.experts_held,
         offset=cfg.experts_offset, normalise=cfg.norm_topk_prob,
-        scale=cfg.routed_scaling_factor)
+        scale=cfg.routed_scaling_factor, form=cfg.router)
 
 
 def _moe_block(cfg: HybridConfig, layer: Params, y, counted, dtype):
     """y (T, D) float32, normed; ``counted`` (T,) bool: the tokens that are
     real or live; ``dtype``: the compute type of the products. Returns (the
     layer's output (T, D) float32, its ``STATS`` (6,) int32):
-    ``models/experts.sparse_experts`` with relu^2 experts inside the latent
-    projections."""
+    ``models/experts.sparse_experts`` with the experts the block's leaves
+    hold: relu^2 experts inside the latent projections (``nemotron_h``), or
+    gated experts on the full width (``granitemoehybrid``)."""
+    def weights(prefix):
+        return {name: layer[f"{prefix}_{name}"] for name in
+                ("gate", "up", "down") if f"{prefix}_{name}" in layer}
+
+    latent = ((layer["latent_down"], layer["latent_up"])
+              if "latent_down" in layer else None)
     return experts_mod.sparse_experts(
         _routing(cfg), y, counted, dtype,
-        router=layer["router"], bias=layer["e_score_correction_bias"],
-        experts={"up": layer["experts_up"], "down": layer["experts_down"]},
-        shared={"up": layer["shared_up"], "down": layer["shared_down"]},
-        latent=(layer["latent_down"], layer["latent_up"]),
+        router=layer["router"], bias=layer.get("e_score_correction_bias"),
+        experts=weights("experts"), shared=weights("shared"), latent=latent,
         dense_up_to=DENSE_EXPERTS_UP_TO)
+
+
+def _head(cfg: HybridConfig, params: Params, x):
+    """Logits in float32: the head's product, divided by ``logits_scaling``
+    where it is set."""
+    logits = _lm_head(params, x)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
+
+
+def _residual(cfg: HybridConfig, x, out):
+    """The block's output added to the stream, times
+    ``residual_multiplier`` where it is set."""
+    if cfg.residual_multiplier != 1.0:
+        out = out * cfg.residual_multiplier
+    return x + out
+
+
+def _stream(cfg: HybridConfig, embeds):
+    """The residual stream's start: the input embeddings in float32, times
+    ``embedding_multiplier`` where it is set."""
+    x = embeds.astype(jnp.float32)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    return x
 
 
 # -- the four entry points -----------------------------------------------------
@@ -443,7 +527,7 @@ def prefill(
         mask = jnp.where(visible, 0.0, jnp.finfo(jnp.float32).min)
 
     dtype = inputs_embeds.dtype
-    x = inputs_embeds.astype(jnp.float32)
+    x = _stream(cfg, inputs_embeds)
     k_buf, v_buf, conv_buf, h_buf = (cache["k"], cache["v"], cache["conv"],
                                      cache["h"])
     stats = []
@@ -468,10 +552,11 @@ def prefill(
             q_proj, k, v = _project_qkv(cfg, y, layer)
             out = _attn_block(cfg, q_proj, {"attn": {"o": layer["o_proj"]}}, cos, sin, k, v,
                               mask=mask, valid=attention_mask,
-                              use_flash=use_flash, scope="prefill_attn")
+                              use_flash=use_flash, scope="prefill_attn",
+                              scale=cfg.attention_multiplier)
             k_buf = k_buf.at[i, :, :t].set(k.astype(k_buf.dtype))
             v_buf = v_buf.at[i, :, :t].set(v.astype(v_buf.dtype))
-        x = x + out
+        x = _residual(cfg, x, out)
 
     new_cache = {"k": k_buf, "v": v_buf, "conv": conv_buf, "h": h_buf,
                  "moe_stats": (jnp.stack(stats) if stats
@@ -482,9 +567,9 @@ def prefill(
         last = jnp.take_along_axis(
             x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)[:, 0]
         if return_hidden:
-            return _lm_head(params, last), last, new_cache
-        return _lm_head(params, last), new_cache
-    logits = _lm_head(params, x)
+            return _head(cfg, params, last), last, new_cache
+        return _head(cfg, params, last), new_cache
+    logits = _head(cfg, params, x)
     if return_hidden:
         return logits, x, new_cache
     return logits, new_cache
@@ -513,7 +598,7 @@ def decode_step(
     counted = live if live is not None else jnp.ones((b,), bool)
 
     dtype = token_embeds.dtype
-    x = token_embeds.astype(jnp.float32)
+    x = _stream(cfg, token_embeds)
     k_buf, v_buf, conv_buf, h_buf = (cache["k"], cache["v"], cache["conv"],
                                      cache["h"])
     stats = []
@@ -539,15 +624,16 @@ def decode_step(
             v_buf = _cache_write(v_buf, i, batch_idx, pos, v_new[:, 0], False)
             out = _attn_block(cfg, q_proj, {"attn": {"o": layer["o_proj"]}}, cos, sin,
                               k_buf[i].astype(dtype), v_buf[i].astype(dtype),
-                              mask, scope="decode_attn")
-        x = x + out
+                              mask, scope="decode_attn",
+                              scale=cfg.attention_multiplier)
+        x = _residual(cfg, x, out)
 
     new_cache = {"k": k_buf, "v": v_buf, "conv": conv_buf, "h": h_buf,
                  "moe_stats": (jnp.stack(stats) if stats
                                else cache["moe_stats"]),
                  "length": cache["length"] + 1}
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps).astype(dtype)
-    return _lm_head(params, x[:, 0]), new_cache
+    return _head(cfg, params, x[:, 0]), new_cache
 
 
 def forward(

@@ -251,12 +251,15 @@ def flash_block_census(positions: int, window, lengths,
     return computed, interior
 
 
-@functools.partial(jax.jit, static_argnames=("window", "block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("window", "block", "interpret",
+                                             "scale"))
 def _flash_blocked_forward(q, k, v, valid, window=None, block: int = BLOCK,
-                           interpret: bool | None = None):
+                           interpret: bool | None = None,
+                           scale: float | None = None):
     """Causal attention with K and V blocked through the grid. q (B, S, H,
     hd); k, v (B, S, KV, hd), KV dividing H; ``window``: None, or the
-    positions a query sees (itself included)."""
+    positions a query sees (itself included); ``scale``: the scores'
+    factor (None: ``1 / sqrt(hd)``)."""
     b, s, h, hd = q.shape
     kvh = k.shape[2]
     rep = h // kvh
@@ -287,7 +290,7 @@ def _flash_blocked_forward(q, k, v, valid, window=None, block: int = BLOCK,
 
     kernel = functools.partial(
         _flash_blocked_kernel, heads=h, block_q=block, block_k=block,
-        window=window, scale=1.0 / math.sqrt(hd))
+        window=window, scale=1.0 / math.sqrt(hd) if scale is None else scale)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -322,16 +325,18 @@ def _flash_blocked_forward(q, k, v, valid, window=None, block: int = BLOCK,
 
 
 def flash_attention_blocked(q, k, v, valid=None, window: int | None = None,
-                            interpret: bool | None = None) -> jnp.ndarray:
+                            interpret: bool | None = None,
+                            scale: float | None = None) -> jnp.ndarray:
     """Causal fused attention for long or windowed prompts (forward only).
     q (B, S, H, hd); k, v (B, S, KV, hd) at their own head count; ``valid``
     (B, S) bool padding mask; ``window``: query ``i`` sees keys ``j`` with
-    ``0 <= i - j < window`` (None: every ``j <= i``). Returns (B, S, H, hd)
-    in q.dtype; padded queries' rows are zero."""
+    ``0 <= i - j < window`` (None: every ``j <= i``); ``scale``: the scores'
+    factor (None: ``1 / sqrt(hd)``). Returns (B, S, H, hd) in q.dtype;
+    padded queries' rows are zero."""
     if valid is None:
         valid = jnp.ones(q.shape[:2], bool)
     return _flash_blocked_forward(q, k, v, valid, window=window,
-                                  interpret=interpret)
+                                  interpret=interpret, scale=scale)
 
 
 def flash_attention(
@@ -343,9 +348,11 @@ def flash_attention(
     block_q: int = 128,
     block_k: int = 128,
     interpret: bool | None = None,
+    scale: float | None = None,
 ) -> jnp.ndarray:
     """Fused attention. q/k/v: (B, S, H, hd) with KV already head-repeated;
-    ``valid``: (B, S) bool padding mask. Returns (B, S, H, hd) in q.dtype.
+    ``valid``: (B, S) bool padding mask; ``scale``: the scores' factor
+    (None: ``1 / sqrt(hd)``). Returns (B, S, H, hd) in q.dtype.
 
     Differentiable: the forward pass is the Pallas kernel; the backward pass
     recomputes attention densely (standard softmax-attention VJP) — at the
@@ -358,22 +365,27 @@ def flash_attention(
     b, s, h, hd = q.shape
     if valid is None:
         valid = jnp.ones((b, s), bool)
-    return _flash_vjp(q, k, v, valid, causal, block_q, block_k, interpret)
+    return _flash_vjp(q, k, v, valid, causal, block_q, block_k, interpret,
+                      scale)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash_vjp(q, k, v, valid, causal, block_q, block_k, interpret):
-    return _flash_forward(q, k, v, valid, causal, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_vjp(q, k, v, valid, causal, block_q, block_k, interpret, scale):
+    return _flash_forward(q, k, v, valid, causal, block_q, block_k, interpret,
+                          scale)
 
 
-def _flash_vjp_fwd(q, k, v, valid, causal, block_q, block_k, interpret):
-    out = _flash_forward(q, k, v, valid, causal, block_q, block_k, interpret)
+def _flash_vjp_fwd(q, k, v, valid, causal, block_q, block_k, interpret,
+                   scale):
+    out = _flash_forward(q, k, v, valid, causal, block_q, block_k, interpret,
+                         scale)
     return out, (q, k, v, valid)
 
 
-def _flash_vjp_bwd(causal, block_q, block_k, interpret, res, g):
+def _flash_vjp_bwd(causal, block_q, block_k, interpret, scale, res, g):
     q, k, v, valid = res
-    scale = 1.0 / math.sqrt(q.shape[-1])
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
     qf = q.astype(jnp.float32)
     kf = k.astype(jnp.float32)
     vf = v.astype(jnp.float32)
@@ -412,7 +424,8 @@ def _lcm(a: int, b: int) -> int:
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret")
+    jax.jit,
+    static_argnames=("causal", "block_q", "block_k", "interpret", "scale")
 )
 def _flash_forward(
     q: jnp.ndarray,
@@ -423,6 +436,7 @@ def _flash_forward(
     block_q: int = 128,
     block_k: int = 128,
     interpret: bool | None = None,
+    scale: float | None = None,
 ) -> jnp.ndarray:
     b, s, h, hd = q.shape
     if interpret is None:
@@ -446,7 +460,8 @@ def _flash_forward(
     qh, kh, vh = to_bh(q), to_bh(k), to_bh(v)
     valid_i = jnp.repeat(valid.astype(jnp.int32), h, axis=0)[:, None, :]  # (B*H,1,S)
 
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
     kernel = functools.partial(
         _flash_kernel, block_k=block_k, causal=causal, scale=scale
     )

@@ -1755,7 +1755,7 @@ class ContinuousBatcher:
         self.params, self.cfg = params, cfg
         # The most positions (rows x bucket) one admission wave may prefill;
         # 0: as many as there are free rows.
-        self._wave_tokens = int(self._dec.WAVE_TOKENS)
+        self._wave_tokens = int(self._dec.wave_tokens(cfg.llama))
         self._fixed_state = tuple(self._dec.fixed_state(cfg.llama))
         # Admission pads prompts to the serving bucket grain; a max_len off
         # the grain would let a bucketed row_cache outgrow the shared cache
@@ -5412,7 +5412,7 @@ class ContinuousBatcher:
     def _wave_takes(self, wave: List[tuple], req: _Request) -> bool:
         """Whether one more member keeps the wave's prefill (members padded
         to a power of two, prompts to the widest member's bucket) within
-        the decoder's ``WAVE_TOKENS``."""
+        the decoder's ``wave_tokens``."""
         if not self._wave_tokens:
             return True
         grain = 2 * SEQ_BUCKET

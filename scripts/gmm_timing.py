@@ -15,7 +15,13 @@ Shapes (rows, experts, in, out; the held rows' group sizes drawn from
 * ``hybrid_wave``, ``hybrid_wave_full``: the hybrid's prefill wave, 3,584
   and 8,192 tokens x 22, a quarter held over its 128 experts, 1,024 ->
   2,688 (up) and 2,688 -> 1,024 (down);
-* ``hybrid_decode``: 64 rows x 22, 352 held over ~115 of 128, up and down.
+* ``hybrid_decode``: 64 rows x 22, 352 held over ~115 of 128, up and down;
+* the whole decode-step expert layer at 64 tokens, batched over every held
+  expert, grouped through the kernel and through ``ragged_dot``: the
+  hybrid's (``layer_*``: 22 of 512 into a 1,024-wide latent, 128 held,
+  relu^2) and the one of full-width gated experts (``gated_layer_*``: a
+  softmax over the top 10 of 72 logits, 36 held, 4,096 -> 768, a gated
+  shared expert of 1,536).
 
 Each program runs ``--calls`` times under the profiler; its time is the
 device self time of its operations over the calls
@@ -150,31 +156,49 @@ def on_chip() -> bool:
     return backend_platform() == "tpu"
 
 
-def hybrid_layer(toy: bool):
-    """The hybrid's decode-step expert layer, 64 tokens: batched over every
-    held expert, grouped through the kernel, grouped through ragged_dot."""
-    d, lat, f, held, width, k = ((64, 32, 256, 8, 32, 4) if toy
-                                 else (4096, 1024, 2688, 128, 512, 22))
+def hybrid_layer(toy: bool, gated: bool = False):
+    """A decode-step expert layer, 64 tokens: batched over every held
+    expert, grouped through the kernel, grouped through ragged_dot. The
+    hybrid's (relu^2 in a latent, sigmoid router), or, ``gated``, gated
+    experts on the full width routed by a softmax over the top k logits."""
+    if gated:
+        d, lat, f, held, width, k = ((64, None, 48, 8, 16, 5) if toy
+                                     else (4096, None, 768, 36, 72, 10))
+    else:
+        d, lat, f, held, width, k = ((64, 32, 256, 8, 32, 4) if toy
+                                     else (4096, 1024, 2688, 128, 512, 22))
     keys = iter(jax.random.split(jax.random.PRNGKey(11), 12))
 
     def dense(*shape):
         return (jax.random.normal(next(keys), shape, jnp.float32)
                 / np.sqrt(shape[-2])).astype(jnp.bfloat16)
 
-    routing = ex.Routing(top_k=k, held=held, offset=0, normalise=True,
-                         scale=5.0)
-    layer = dict(router=jax.random.normal(next(keys), (d, width)) / 64,
-                 bias=jnp.zeros((width,)),
-                 experts={"up": dense(held, lat, f),
-                          "down": dense(held, f, lat)},
-                 shared={"up": dense(d, 2 * f), "down": dense(2 * f, d)},
-                 latent=(dense(d, lat), dense(lat, d)))
+    e = lat or d  # the routed experts' input width
+    if gated:
+        routing = ex.Routing(top_k=k, held=held, offset=0, normalise=False,
+                             scale=1.0, form="softmax_topk")
+        layer = dict(router=jax.random.normal(next(keys), (d, width)) / 64,
+                     bias=None,
+                     experts={"gate": dense(held, e, f), "up": dense(held, e, f),
+                              "down": dense(held, f, e)},
+                     shared={"gate": dense(d, 2 * f), "up": dense(d, 2 * f),
+                             "down": dense(2 * f, d)})
+    else:
+        routing = ex.Routing(top_k=k, held=held, offset=0, normalise=True,
+                             scale=5.0)
+        layer = dict(router=jax.random.normal(next(keys), (d, width)) / 64,
+                     bias=jnp.zeros((width,)),
+                     experts={"up": dense(held, e, f),
+                              "down": dense(held, f, e)},
+                     shared={"up": dense(d, 2 * f), "down": dense(2 * f, d)},
+                     latent=(dense(d, lat), dense(lat, d)))
     y = jax.random.normal(next(keys), (64, d), jnp.float32)
     counted = jnp.ones((64,), bool)
     out = {}
+    prefix = "gated_layer" if gated else "layer"
     for name, dense_up_to, product in (
-            ("layer_batched", 128, None), ("layer_gmm", 0, gm.gmm),
-            ("layer_ragged", 0, lax.ragged_dot)):
+            (prefix + "_batched", 128, None), (prefix + "_gmm", 0, gm.gmm),
+            (prefix + "_ragged", 0, lax.ragged_dot)):
         def fn(y, layer, dense_up_to=dense_up_to, product=product):
             saved = ex._product
             if product is not None:
@@ -205,6 +229,7 @@ def main(argv=None) -> int:
     rng = np.random.default_rng(args.seed)
     progs, checks = programs(rng, toy)
     progs.update(hybrid_layer(toy))
+    progs.update(hybrid_layer(toy, gated=True))
     # On the host, the held rows only: a wave's outputs are ~1 GB each.
     for name, (ragged, held) in checks.items():
         fn, a = progs[name]
@@ -215,14 +240,15 @@ def main(argv=None) -> int:
                                                      1e-30))
         print(f"[gmm] {name}: widest gap to ragged_dot over the held rows, "
               f"over the largest |value|: {gap:.3g}", flush=True)
-    fn, a = progs["layer_batched"]
-    base = np.asarray(fn(*a)[0], np.float32)
-    for name in ("layer_gmm", "layer_ragged"):
-        fn, a = progs[name]
-        got = np.asarray(fn(*a)[0], np.float32)
-        print(f"[gmm] {name}: widest gap to layer_batched "
-              f"{float(np.max(np.abs(got - base))):.3g} (largest |value| "
-              f"{float(np.max(np.abs(base))):.3g})", flush=True)
+    for prefix in ("layer", "gated_layer"):
+        fn, a = progs[prefix + "_batched"]
+        base = np.asarray(fn(*a)[0], np.float32)
+        for name in (prefix + "_gmm", prefix + "_ragged"):
+            fn, a = progs[name]
+            got = np.asarray(fn(*a)[0], np.float32)
+            print(f"[gmm] {name}: widest gap to {prefix}_batched "
+                  f"{float(np.max(np.abs(got - base))):.3g} (largest |value| "
+                  f"{float(np.max(np.abs(base))):.3g})", flush=True)
     if not chip:
         print("[gmm] rehearsal: no time on the CPU")
         return 0

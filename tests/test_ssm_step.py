@@ -158,3 +158,28 @@ def test_served_shape_compiles_for_a_v5e_in_place(one_chip):
     assert memory.alias_size_in_bytes == buffer_bytes
     assert memory.temp_size_in_bytes < buffer_bytes // planes // 8
     assert mod._groups_per_block(g, heads // g, p, n) == 4
+
+
+def test_one_group_of_every_head_compiles_for_a_v5e_in_place(one_chip):
+    """granite-4.0-h-small-event's state, nine planes of 64 rows x 128
+    heads x 64 x 128 in one group of B / C: a group is 4 MB, over
+    ``BLOCK_BYTES``, so a grid cell holds a row's every head (the
+    fallback of ``_groups_per_block``), and Mosaic takes it within the
+    kernel's scoped VMEM."""
+    planes, rows, heads, p, n, g = 9, 64, 128, 64, 128, 1
+    assert mod._groups_per_block(g, heads // g, p, n) == g
+    assert heads * p * n * 4 > mod.BLOCK_BYTES
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda h_buf, *rest: ssm_step(h_buf, 8, *rest, interpret=False),
+        donate_argnums=0,
+    ).lower(spec(planes, rows, heads, p, n), spec(rows, heads),
+            spec(rows, heads, p), spec(rows, g, n), spec(rows, g, n)).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == planes * rows * heads * p * n * 4
+    assert memory.temp_size_in_bytes < rows * heads * p * n * 4 // 8
