@@ -12,7 +12,9 @@ TPU-first choices:
     the stride=kernel conv),
   * all encoder layers stacked on a leading axis and driven by ``lax.scan``
     (O(1) compile time in depth, natural fsdp/tp sharding of the stack),
-  * f32 softmax accumulation inside attention regardless of param dtype.
+  * f32 softmax accumulation inside attention regardless of param dtype;
+    on the chip the scores, softmax and values run in one Pallas kernel
+    (``ops/clip_attention.py``) that keeps a head's scores out of HBM.
 """
 
 from __future__ import annotations
@@ -25,6 +27,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from eventgpt_tpu.config import VisionConfig
+from eventgpt_tpu.ops.clip_attention import xla_attention, clip_attention
+from eventgpt_tpu.utils.platform import backend_platform
 
 Params = Dict[str, Any]
 
@@ -89,24 +93,30 @@ def _embed_patches(params: Params, cfg: VisionConfig, pixel_values: jnp.ndarray)
     return tokens + params["embeddings"]["position_embedding"]
 
 
-def _attention(x: jnp.ndarray, attn: Params, cfg: VisionConfig) -> jnp.ndarray:
-    b, s, d = x.shape
-    h, hd = cfg.num_heads, cfg.head_dim
+def _attend(sharded: bool):
+    """The scores, softmax and values of every head, (N, S, H * hd) in and
+    out: the Pallas kernel on the chip; the XLA form on the CPU, and where
+    the tower is ``sharded`` over a mesh (the partitioner cannot split a
+    Mosaic call)."""
+    if not sharded and backend_platform() == "tpu":
+        return clip_attention
+    return xla_attention
+
+
+def _attention(x: jnp.ndarray, attn: Params, cfg: VisionConfig,
+               attend) -> jnp.ndarray:
+    hd = cfg.head_dim
 
     def proj(p):
-        return (x @ p["kernel"] + p["bias"]).reshape(b, s, h, hd)
+        return x @ p["kernel"] + p["bias"]
 
     q = proj(attn["q"]) * (1.0 / math.sqrt(hd))
-    k = proj(attn["k"])
-    v = proj(attn["v"])
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
-    probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v).reshape(b, s, d)
+    ctx = attend(q, proj(attn["k"]), proj(attn["v"]), cfg.num_heads)
     return ctx @ attn["o"]["kernel"] + attn["o"]["bias"]
 
 
 def clip_encode(params: Params, cfg: VisionConfig, pixel_values: jnp.ndarray,
-                pin=None) -> jnp.ndarray:
+                pin=None, sharded: bool = False) -> jnp.ndarray:
     """(B, C, H, W) pixels -> (B, num_tokens, D) last hidden state (no post-LN).
 
     ``pin``: optional sharding-constraint callable applied to the layer-scan
@@ -115,16 +125,19 @@ def clip_encode(params: Params, cfg: VisionConfig, pixel_values: jnp.ndarray,
     sharded weights on every scan iteration ("involuntary full
     rematerialization" — VERDICT r5 weak #1); pinning the carry keeps the
     whole tower batch-sharded. Identity when None (single-chip paths).
+    ``sharded``: the pixels or the weights are split over a mesh, so the
+    attention keeps its XLA form; a ``pin`` implies it.
     """
     x = _embed_patches(params, cfg, pixel_values)
     x = layer_norm(x, params["pre_layernorm"]["scale"], params["pre_layernorm"]["bias"],
                    cfg.layer_norm_eps)
     if pin is not None:
         x = pin(x)
+    attend = _attend(sharded or pin is not None)
 
     def block(carry, layer):
         y = layer_norm(carry, layer["ln1"]["scale"], layer["ln1"]["bias"], cfg.layer_norm_eps)
-        carry = carry + _attention(y, layer["attn"], cfg)
+        carry = carry + _attention(y, layer["attn"], cfg, attend)
         y = layer_norm(carry, layer["ln2"]["scale"], layer["ln2"]["bias"], cfg.layer_norm_eps)
         y = quick_gelu(y @ layer["mlp"]["fc1"]["kernel"] + layer["mlp"]["fc1"]["bias"])
         y = y @ layer["mlp"]["fc2"]["kernel"] + layer["mlp"]["fc2"]["bias"]

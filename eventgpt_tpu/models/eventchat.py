@@ -91,19 +91,20 @@ def init_eventchat_params(cfg: EventChatConfig, key: jax.Array, dtype=jnp.float3
 
 
 def _encode_feats(params: Params, cfg: EventChatConfig, frames: jnp.ndarray,
-                  pin=None) -> jnp.ndarray:
+                  pin=None, sharded: bool = False) -> jnp.ndarray:
     """(N, C, H, W) frames -> (N, num_tokens, D_lm) projected features:
     CLIP -> stop_gradient -> MLP projector -> feature adaptor. The
     stop_gradient is the exact JAX statement of the reference's
     detach-then-requires_grad trick that confines gradients to the
     projector stack (``model/EventChatModel.py:185-191``). ``pin``:
     optional batch-sharding constraint threaded through the CLIP layer
-    scan and applied after each projector stage (see ``clip_encode``)."""
+    scan and applied after each projector stage, and ``sharded`` (see
+    ``clip_encode``)."""
     # named_scope: metadata only (the scope path an operation carries on a
     # device trace); no program's arithmetic or name changes with it.
     with jax.named_scope("tower"):
         feats = clip_mod.clip_encode(params["clip"], cfg.vision, frames,
-                                     pin=pin)
+                                     pin=pin, sharded=sharded)
         feats = jax.lax.stop_gradient(feats)
     with jax.named_scope("projector"):
         feats = proj_mod.apply_projector(params["projector"], feats)
@@ -139,9 +140,10 @@ def encode_events(params: Params, cfg: EventChatConfig, pixel_values: jnp.ndarra
     return _encode_tail(params, cfg, _encode_feats(params, cfg, pixel_values))
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "mesh"))
+@functools.partial(jax.jit, static_argnames=("cfg", "mesh", "sharded"))
 def encode_events_batch(params: Params, cfg: EventChatConfig,
-                        pixel_values: jnp.ndarray, mesh=None) -> jnp.ndarray:
+                        pixel_values: jnp.ndarray, mesh=None,
+                        sharded: bool = False) -> jnp.ndarray:
     """(B, T, C, H, W) -> (B, num_event_tokens, D_lm).
 
     The CLIP tower and projector run batched over the flattened B*T frame
@@ -152,6 +154,10 @@ def encode_events_batch(params: Params, cfg: EventChatConfig,
     #1). ``mesh`` (static) additionally pins the tower's scan carry to
     the batch sharding so the sharded step's dryrun artifact is
     warning-free; None (the single-chip default) changes nothing.
+    ``sharded`` (static): the pixels or the weights are split over a mesh
+    (a serving mesh, which pins nothing), so the tower's attention keeps
+    its XLA form, which the partitioner can split and the chip's Pallas
+    kernel is not; ``mesh`` implies it.
     """
     b, t = pixel_values.shape[:2]
     pin = None
@@ -164,7 +170,7 @@ def encode_events_batch(params: Params, cfg: EventChatConfig,
             x, NamedSharding(mesh, batch_spec(x.ndim))
         )
     flat = pixel_values.reshape((b * t,) + pixel_values.shape[2:])
-    feats = _encode_feats(params, cfg, flat, pin=pin)
+    feats = _encode_feats(params, cfg, flat, pin=pin, sharded=sharded)
     feats = feats.reshape((b, t) + feats.shape[1:])
     return jax.vmap(lambda f: _encode_tail(params, cfg, f))(feats)
 
@@ -1018,7 +1024,8 @@ def generate(
         )
 
     event_tokens = encode_events_batch(
-        params, cfg, jnp.asarray(pixel_values_batch, dtype=compute_dtype)
+        params, cfg, jnp.asarray(pixel_values_batch, dtype=compute_dtype),
+        sharded=mesh is not None,
     )
     embeds = [
         splice_embeddings(params, cfg, split_at_event(ids), event_tokens[i], max_context)

@@ -2256,7 +2256,8 @@ class ContinuousBatcher:
         if self.mesh is not None:
             pv = self._serving.shard_batch_array(pv, self.mesh)
         jax.block_until_ready(
-            eventchat.encode_events_batch(self.params, self.cfg, pv)
+            eventchat.encode_events_batch(self.params, self.cfg, pv,
+                                          sharded=self.mesh is not None)
         )
         n += 1
         want_hidden = self.draft_head is not None
@@ -2493,7 +2494,8 @@ class ContinuousBatcher:
             pv = jnp.asarray(pixel_values, self._dtype)[None]
             if self.mesh is not None:
                 pv = self._serving.shard_batch_array(pv, self.mesh)
-            ev = eventchat.encode_events_batch(self.params, self.cfg, pv)
+            ev = eventchat.encode_events_batch(self.params, self.cfg, pv,
+                                               sharded=self.mesh is not None)
             embeds = [splice_embeddings(
                 self.params, self.cfg, split_at_event(ids), ev[0]
             )]
@@ -2645,7 +2647,8 @@ class ContinuousBatcher:
         else:
             pv = self._upload_pixels([pixel_values], [rid])
             with obs_trace.span("encode", "admit", n=1, rid=rid):
-                ev = eventchat.encode_events_batch(self.params, self.cfg, pv)
+                ev = eventchat.encode_events_batch(
+                    self.params, self.cfg, pv, sharded=self.mesh is not None)
                 emb = splice_embeddings(
                     self.params, self.cfg, split_at_event(suffix_ids), ev[0]
                 )[None]
@@ -5519,7 +5522,8 @@ class ContinuousBatcher:
 
         pv = self._upload_pixels([req.pixel_values], [req.rid])
         with obs_trace.span("encode", "admit", n=1, rid=req.rid):
-            ev = eventchat.encode_events_batch(self.params, self.cfg, pv)
+            ev = eventchat.encode_events_batch(self.params, self.cfg, pv,
+                                               sharded=self.mesh is not None)
             embeds = [splice_embeddings(
                 self.params, self.cfg, split_at_event(req.input_ids), ev[0]
             )]
@@ -5710,7 +5714,8 @@ class ContinuousBatcher:
         pv = self._upload_pixels([req.pixel_values for req, _ in wave], rids,
                                  pad_to=nb)
         with obs_trace.span("encode", "admit", n=n, rids=rids):
-            ev = eventchat.encode_events_batch(self.params, self.cfg, pv)
+            ev = eventchat.encode_events_batch(self.params, self.cfg, pv,
+                                               sharded=self.mesh is not None)
             embeds = [splice_embeddings(self.params, self.cfg,
                                         split_at_event(req.input_ids), ev[i])
                       for i, (req, _) in enumerate(wave)]

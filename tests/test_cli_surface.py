@@ -65,7 +65,7 @@ def test_run_script_flags_exist(script):
 # by hand under their ``__main__`` check.
 _WITH_PARSER = ["egpt_check", "gmm_timing", "medusa_acceptance",
                 "serve_demo", "spec_acceptance_sim", "stream_demo",
-                "trace_scopes", "train_medusa"]
+                "tower_timing", "trace_scopes", "train_medusa"]
 _BY_HAND = ["expert_flips", "lint_telemetry"]
 
 
